@@ -20,7 +20,7 @@ from dlrover_tpu.common.log import logger
 
 # Training-state bytes per parameter: fp32 master + adam mu/nu + bf16 grad.
 _BYTES_PER_PARAM = 16
-_DEFAULT_HBM = 16e9  # v5e-class chip; overridable via device memory stats
+_CPU_TEST_HBM = 16e9  # what the CPU backend (tests) pretends to have: one v5e
 
 
 @dataclass(frozen=True)
@@ -102,13 +102,18 @@ class AccelerateResult:
 
 
 def _device_hbm(devices) -> float:
-    try:
-        stats = devices[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return float(stats["bytes_limit"])
-    except Exception:
-        logger.debug("device memory_stats probe failed", exc_info=True)
-    return _DEFAULT_HBM
+    """Memory per device, from the runtime. Only the CPU backend reports
+    none; an accelerator that cannot say is an error, not 16 GB assumed."""
+    dev = devices[0]
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return float(stats["bytes_limit"])
+    if dev.platform != "cpu":
+        raise RuntimeError(
+            f"{dev.device_kind} reports no memory_stats()['bytes_limit']; "
+            "the strategy search cannot size a mesh without it"
+        )
+    return _CPU_TEST_HBM
 
 
 def _divisors_leq(n: int, cap: int) -> List[int]:
@@ -347,6 +352,7 @@ def auto_accelerate(
     config carries ``mlp_precision``.
     """
     import jax
+    import jax.numpy as jnp
 
     devices = list(devices if devices is not None else jax.devices())
     rng = rng if rng is not None else jax.random.PRNGKey(0)
@@ -375,6 +381,18 @@ def auto_accelerate(
         mod = mod if mod is not None else module
         if sp.total > n:
             raise ValueError(f"{sp} needs {sp.total} devices, have {n}")
+        from dlrover_tpu.optim.low_bit import FusedGradientTransformation
+
+        if sp.total > 1 and isinstance(
+            optimizer, FusedGradientTransformation
+        ):
+            raise ValueError(
+                f"adam8bit cannot run under {sp}: its int8 moments are "
+                "replicated and its Pallas kernel is not partitioned "
+                "over a mesh, so it is a one-device optimizer. Use "
+                "optax.adamw (fp32 state, sharded by fsdp) on more than "
+                "one device."
+            )
         mesh = create_mesh(
             sp.axes() or [("data", 1)], devices=devices[: sp.total]
         )
@@ -390,7 +408,11 @@ def auto_accelerate(
             return {
                 "params": params,
                 "opt": optimizer.init(params),
-                "step": 0,
+                # Strongly typed: a Python 0 is weakly typed, a restored
+                # step is not, and the two trace to different programs —
+                # the first step after every restore would then miss the
+                # compile cache.
+                "step": jnp.zeros((), jnp.int32),
             }
 
         abstract = jax.eval_shape(init_fn, rng)
@@ -551,7 +573,10 @@ def auto_accelerate(
             def init_fn(r):
                 variables = mod.init(r, sample_batch)
                 p = variables["params"]
-                return {"params": p, "opt": optimizer.init(p), "step": 0}
+                return {
+                    "params": p, "opt": optimizer.init(p),
+                    "step": jnp.zeros((), jnp.int32),
+                }
 
             _abstract_cache[key] = jax.eval_shape(init_fn, rng)
         return _abstract_cache[key]

@@ -90,8 +90,15 @@ def create_mesh(axes: Sequence[Tuple[str, int]],
         dev_array = mesh_utils.create_device_mesh(
             shape, devices=devices, allow_split_physical_axes=True
         )
-    except (ValueError, AssertionError):
-        # CPU/virtual or odd topologies: plain reshape is always valid.
+    except (ValueError, AssertionError) as e:
+        # A partial slice (survivors of a kill) has no torus assignment;
+        # enumeration order is always valid, but say so on a real chip:
+        # the axes may then not ride ICI neighbours.
+        if devices[0].platform == "tpu":
+            logger.warning(
+                "create_device_mesh%s failed (%s); using device "
+                "enumeration order", shape, e,
+            )
         dev_array = np.asarray(devices).reshape(shape)
     mesh = Mesh(dev_array, names)
     logger.info("created mesh %s", dict(zip(names, shape)))

@@ -40,7 +40,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 from dlrover_tpu.common.log import logger
 
 # Derate factor on peak FLOPs — CALIBRATED against measured single-chip
-# step times on TPU v5e (BENCH_r04, 2026-07-30, this repo's bench.py):
+# step times on TPU v5e (an earlier on-chip run, to be re-measured):
 # small 124M 40.6% MFU, medium 355M 43.0%, GPT-2-xl 1.5B 36.0%, LLaMA
 # 1.15B 51.6%. 0.42 is their geometric mean; every preset's measured
 # step time is then within +-30% of estimate().step_s, pinned by

@@ -28,6 +28,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from dlrover_tpu.agent import tpu_chips
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.chaos.sites import ChaosSite
 from dlrover_tpu.common import env_utils
@@ -415,15 +416,6 @@ class ElasticTrainingAgent:
                 env_utils.SPAWN_TS.name: repr(time.time()),
             }
         )
-        # One persistent compile cache per job: every incarnation of
-        # every worker on this host reuses compiled executables instead
-        # of replaying XLA compilation after a restart (goodput lever).
-        from dlrover_tpu.common.env_utils import default_compile_cache_dir
-
-        env.setdefault(
-            env_utils.COMPILE_CACHE.name,
-            default_compile_cache_dir(self._config.job_name),
-        )
         return env
 
     def _start_workers(self, outcome: RendezvousOutcome):
@@ -460,8 +452,20 @@ class ElasticTrainingAgent:
                     "subprocess spawn", e,
                 )
                 use_forkserver = False
-        for local_rank in range(self._config.nproc_per_node):
+        # Several workers on a TPU host: one chip each (tpu_chips.py).
+        # The libtpu mesh ports are drawn once so every worker gets the
+        # same address list.
+        chip_ports: List[int] = []
+        nproc = self._config.nproc_per_node
+        if nproc > 1 and tpu_chips.count_tpu_chips():
+            while len(chip_ports) < nproc:
+                port = find_free_port()
+                if port not in chip_ports:
+                    chip_ports.append(port)
+        for local_rank in range(nproc):
             env = self._worker_env(outcome, local_rank)
+            if chip_ports:
+                env.update(tpu_chips.worker_chip_env(local_rank, chip_ports))
             log_path = ""
             if self._config.log_dir:
                 os.makedirs(self._config.log_dir, exist_ok=True)

@@ -196,10 +196,9 @@ class LinkProbe:
     training hot path:
 
     - **H2D/D2H bandwidth proxy** — a small write+read through the shm
-      staging directory, the same path checkpoint snapshots take. With
-      ``DLROVER_TPU_PROBE_DEVICE=1`` it additionally times a real
-      ``jax`` host↔device round trip (off by default: the *workers* own
-      the TPU runtime; an agent-side client would steal the chips).
+      staging directory, the same path checkpoint snapshots take. The
+      agent never times a real host↔device transfer: the *workers* own
+      the TPU runtime, and an agent-side client would take their chips.
     - **master RPC round-trip** — a read-only kv-store get, the
       cross-host control-link microbenchmark every agent can run.
 
@@ -340,8 +339,6 @@ class LinkProbe:
                 )
             except Exception:  # dtlint: disable=DT001 -- master briefly down: the probe keeps sampling local links
                 pass
-        if env_utils.PROBE_DEVICE.get():
-            sample.update(self._measure_device())
         return sample
 
     def _measure_shm(self) -> Dict:
@@ -376,25 +373,3 @@ class LinkProbe:
             "h2d_mbps": round(mb / max(t1 - t0, 1e-9), 1),
             "d2h_mbps": round(mb / max(t2 - t1, 1e-9), 1),
         }
-
-    def _measure_device(self) -> Dict:
-        """True host↔device transfer timing; opt-in only (the agent
-        grabbing the TPU runtime would evict the workers)."""
-        try:
-            import jax
-            import numpy as np
-
-            host = np.zeros((self._mb, 1 << 20 >> 2), dtype=np.float32)
-            mb = host.nbytes / 1e6
-            t0 = time.perf_counter()
-            dev = jax.block_until_ready(jax.device_put(host))
-            t1 = time.perf_counter()
-            np.asarray(dev)
-            t2 = time.perf_counter()
-            return {
-                "dev_h2d_mbps": round(mb / max(t1 - t0, 1e-9), 1),
-                "dev_d2h_mbps": round(mb / max(t2 - t1, 1e-9), 1),
-            }
-        except Exception as e:  # dtlint: disable=DT001 -- no usable backend: device numbers are optional extras
-            logger.debug("link probe device sample unavailable: %s", e)
-            return {}

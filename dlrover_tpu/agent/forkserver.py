@@ -98,19 +98,29 @@ def _child_main(req: Dict):
     import runpy
 
     sys.argv = [req["entrypoint"], *req["args"]]
+    code = 0
     try:
         runpy.run_path(req["entrypoint"], run_name="__main__")
     except SystemExit as e:
         code = e.code if isinstance(e.code, int) else (
             0 if e.code is None else 1
         )
-        os._exit(code)
     except BaseException:
         import traceback
 
         traceback.print_exc()
-        os._exit(1)
-    os._exit(0)
+        code = 1
+    if code == 0:
+        # A clean exit runs the worker's exit handlers the way a spawned
+        # interpreter would. jax.distributed's shutdown barrier is one:
+        # without it the first process to finish takes the coordination
+        # service down under peers that are still closing, and they die.
+        # A failed worker skips them — its peers may be gone, and the
+        # barrier would wait for them.
+        import atexit
+
+        atexit._run_exitfuncs()
+    os._exit(code)
 
 
 def template_main():

@@ -21,6 +21,7 @@ import tempfile
 import time
 from typing import List, Optional, Tuple
 
+from dlrover_tpu.agent import tpu_chips
 from dlrover_tpu.agent.agent import ElasticLaunchConfig, launch_agent
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common.backoff import ExponentialBackoff
@@ -101,6 +102,12 @@ def run(args) -> int:
     min_nodes, max_nodes = parse_nnodes(args.nnodes)
     if args.standalone:
         min_nodes = max_nodes = 1
+    try:
+        tpu_chips.check_layout(
+            args.nproc_per_node, max_nodes, tpu_chips.count_tpu_chips()
+        )
+    except ValueError as e:
+        raise SystemExit(str(e))
 
     master_proc: Optional[subprocess.Popen] = None
     master_addr = args.master_addr
@@ -140,11 +147,21 @@ def run(args) -> int:
     script_args = [a for a in args.script_args if a != "--"]
     code = launch_agent(config, args.entrypoint, script_args)
 
-    client = MasterClient.singleton_instance()
-    try:
-        client.report_job_exit(success=(code == 0))
-    except Exception:
-        logger.warning("job-exit report to master failed", exc_info=True)
+    if master_proc is not None:
+        # The agent's final node status lets a local master finish on its
+        # own within a poll or two. Give it that: a job-exit report sent
+        # to a master that has just gone retries for the whole outage
+        # window (two minutes) before giving up.
+        try:
+            master_proc.wait(timeout=5)
+        except subprocess.TimeoutExpired:
+            pass
+    if master_proc is None or master_proc.poll() is None:
+        client = MasterClient.singleton_instance()
+        try:
+            client.report_job_exit(success=(code == 0))
+        except Exception:
+            logger.warning("job-exit report to master failed", exc_info=True)
     if master_proc is not None:
         try:
             master_proc.wait(timeout=10)

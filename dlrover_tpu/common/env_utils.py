@@ -172,10 +172,6 @@ SOCK_DIR = ENV.path(
 SHM_DIR = ENV.path(
     "DLROVER_TPU_SHM_DIR", "/dev/shm",
     "Backing directory for flash-checkpoint shared-memory segments.")
-COMPILE_CACHE = ENV.path(
-    "DLROVER_TPU_COMPILE_CACHE", "",
-    "Persistent XLA compile-cache dir shared by every incarnation of "
-    "every worker on a host (the restart-cheapness lever).")
 TRACE_FILE = ENV.path(
     "DLROVER_TPU_TRACE_FILE", "",
     "When set, the Tracer exports a Chrome trace here atomically at "
@@ -460,11 +456,6 @@ PROBE_MB = ENV.int(
     "DLROVER_TPU_PROBE_MB", 8,
     "Payload megabytes per link-probe bandwidth sample; small on "
     "purpose — the probe must stay off the hot path.")
-PROBE_DEVICE = ENV.bool(
-    "DLROVER_TPU_PROBE_DEVICE", False,
-    "Let the agent's link probe touch the accelerator runtime for true "
-    "D2H/H2D numbers. Off by default: workers own the TPU, so the agent "
-    "probes the shm staging path and master RTT instead.")
 STRAGGLER_PHASES = ENV.bool(
     "DLROVER_TPU_STRAGGLER_PHASES", True,
     "Emit per-step phase-breakdown events (step.phases) from the "
@@ -739,28 +730,3 @@ def get_job_name() -> str:
 
 def get_master_addr() -> str:
     return MASTER_ADDR.get()
-
-
-def default_compile_cache_dir(job_name: str = "") -> str:
-    """One persistent XLA compile-cache dir per (user, job): the agent
-    exports it (see ``COMPILE_CACHE``) and the worker bootstrap falls
-    back to it, so every incarnation of every worker on a host shares
-    one cache — the restart-cheapness lever. The root is per-uid:
-    compiled executables are code, and a world-shared /tmp path would
-    let another user pre-plant them."""
-    import stat
-    import tempfile
-
-    job = job_name or JOB_NAME.get()
-    uid = os.getuid() if hasattr(os, "getuid") else 0
-    root = os.path.join("/tmp", f"dlrover_tpu_cache-{uid}")
-    try:
-        os.makedirs(root, mode=0o700, exist_ok=True)
-        st = os.stat(root)
-        if st.st_uid != uid or st.st_mode & (stat.S_IWGRP | stat.S_IWOTH):
-            # Pre-existing dir we don't exclusively own (pre-planted or
-            # loosened): compiled executables must not load from it.
-            root = tempfile.mkdtemp(prefix="dlrover_tpu_cache-")
-    except OSError:
-        root = tempfile.mkdtemp(prefix="dlrover_tpu_cache-")
-    return os.path.join(root, job)

@@ -45,6 +45,10 @@ class SharedMemory:
                 cur = os.fstat(fd).st_size
                 if cur != size:
                     os.ftruncate(fd, size)
+                # Reserve the pages now: a tmpfs too small for the
+                # segment then fails here with ENOSPC, not with SIGBUS
+                # in the middle of a snapshot copy.
+                os.posix_fallocate(fd, 0, size)
                 self._mmap = mmap.mmap(fd, size)
             finally:
                 os.close(fd)

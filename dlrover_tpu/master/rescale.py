@@ -2,8 +2,8 @@
 
 Before this coordinator every membership change paid the full
 kill → rendezvous → restore cycle even when most workers never failed
-(BENCH_r05's ``restart_breakdown``: spawn+init+restore+recompile is pure
-downtime). The rescale plane instead treats a round bump with a
+(the ``restart_breakdown`` of an earlier on-chip run, to be re-measured:
+spawn+init+restore+recompile is pure downtime). The rescale plane instead treats a round bump with a
 surviving quorum as a *transition*: the coordinator journals and issues
 a :class:`~dlrover_tpu.common.messages.RescalePlan` — old world → new
 world plus the derived per-rank accumulation schedule preserving the

@@ -12,9 +12,11 @@ walks (bh, q_block, kv_block) with the kv dimension innermost so the f32
 accumulators live in VMEM scratch across kv steps (TPU grids execute
 sequentially — the canonical Pallas accumulation pattern).
 
-On non-TPU backends the kernel runs in interpreter mode (tests) — the
-public entry point auto-selects, so models can enable ``attn_impl="pallas"``
-unconditionally.
+Mosaic kernels cannot be partitioned by GSPMD, so over a mesh of more than
+one device the public entry point wraps the kernel in ``shard_map`` (batch
+over ``data``/``fsdp``, heads over ``tensor``); models can enable
+``attn_impl="pallas"`` under any ``ParallelSpec``. Interpreter mode is for
+the CPU tests only (``dlrover_tpu.ops.interpret``).
 """
 
 import functools
@@ -23,6 +25,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from dlrover_tpu.ops import interpret as interpret_mode
+from dlrover_tpu.ops.ring_attention import _ambient_mesh
 
 _NEG_INF = -1e30
 _LANES = 128  # scratch rows are padded to a full lane tile
@@ -43,10 +51,6 @@ def reference_attention(q, k, v, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pick_block(seq: int, want: int) -> int:
     """Largest block <= `want` that divides `seq` (power-of-two stepping)."""
     b = min(want, seq)
@@ -60,8 +64,6 @@ def _pick_block(seq: int, want: int) -> int:
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
                 scale, causal, block_q, block_k, nk):
-    from jax.experimental import pallas as pl
-
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -120,8 +122,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    from jax.experimental import pallas as pl
-
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_q = _pick_block(sq, block_q)
@@ -132,20 +132,11 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     vf = jnp.moveaxis(v, 2, 1).reshape(b * h, sk, d)
     nq, nk = sq // block_q, sk // block_k
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        scratch = [
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ]
-    except ImportError:  # pragma: no cover - non-TPU jax builds
-        scratch = [
-            pl.MemoryRef((block_q, d), jnp.float32),
-            pl.MemoryRef((block_q, _LANES), jnp.float32),
-            pl.MemoryRef((block_q, _LANES), jnp.float32),
-        ]
+    scratch = [
+        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+    ]
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
@@ -178,8 +169,6 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc, *, scale, causal, block_q, block_k, nk):
-    from jax.experimental import pallas as pl
-
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -230,8 +219,6 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
                     scale, causal, block_q, block_k, nq):
-    from jax.experimental import pallas as pl
-
     ki, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -289,8 +276,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(causal, block_q, block_k, interpret, res, g):
-    from jax.experimental import pallas as pl
-
     q, k, v, o, lse = res
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -307,13 +292,6 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     delta = jnp.sum(
         dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1
     )[:, None, :]  # [B*H, 1, S] — matches the lse layout
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        vmem = pltpu.VMEM
-    except ImportError:  # pragma: no cover
-        vmem = pl.MemoryRef
 
     dq = pl.pallas_call(
         functools.partial(
@@ -333,7 +311,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
             (1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[vmem((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
@@ -360,8 +338,8 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
         scratch_shapes=[
-            vmem((block_k, d), jnp.float32),
-            vmem((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
@@ -391,13 +369,49 @@ def _flash_attention_bwd(causal, block_q, block_k, interpret, res, g):
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
-                    block_k: int = 512, interpret: Optional[bool] = None):
-    """Flash attention over [B, S, H, D] inputs (differentiable).
+def flash_attention_shard(q, k, v, causal: bool = True,
+                          block_q: int = 512, block_k: int = 512,
+                          interpret: Optional[bool] = None):
+    """The kernel on device-local [B, S, H, D] blocks (differentiable).
 
-    ``interpret=None`` auto-selects: compiled Pallas on TPU, interpreter
-    elsewhere (so CPU tests validate the same kernel code path).
+    Call it directly on one device or inside a ``shard_map`` body;
+    ``interpret=None`` follows ``dlrover_tpu.ops.interpret``.
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = interpret_mode.use_interpret()
     return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
+
+
+def _shard_spec(mesh, shape) -> P:
+    """Batch over ``data``/``fsdp`` and heads over ``tensor``, each only
+    where it divides evenly (``shard_map`` cannot pad the way GSPMD
+    does); every device sees the whole sequence."""
+    batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
+    if shape[0] % int(np.prod([mesh.shape[a] for a in batch_axes])):
+        batch_axes = ()
+    heads = None
+    if "tensor" in mesh.axis_names and shape[2] % mesh.shape["tensor"] == 0:
+        heads = "tensor"
+    return P(batch_axes or None, None, heads, None)
+
+
+def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
+                    block_k: int = 512, interpret: Optional[bool] = None,
+                    mesh=None):
+    """Flash attention over GLOBAL [B, S, H, D] inputs (differentiable).
+
+    Over the ambient mesh (or ``mesh``) of more than one device the
+    kernel runs under ``shard_map``; on one device it is called bare.
+    """
+    kernel = functools.partial(
+        flash_attention_shard, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret,
+    )
+    mesh = mesh if mesh is not None else _ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v)
+    spec = _shard_spec(mesh, q.shape)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

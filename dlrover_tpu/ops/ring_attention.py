@@ -89,22 +89,10 @@ def ring_attention_shard(q, k, v, causal: bool = True,
 def _ambient_mesh():
     """The mesh active at trace time (set by ``with mesh:`` in the accel
     layer's train step), or None."""
-    try:
-        from jax._src import mesh as mesh_lib
+    from jax._src import mesh as mesh_lib
 
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        try:  # pre-0.8 fallback
-            from jax.interpreters import pxla
-
-            mesh = pxla.thread_resources.env.physical_mesh
-            if mesh is not None and not mesh.empty:
-                return mesh
-        except Exception:  # dtlint: disable=DT001 -- JAX-version API probe: no mesh found either way, caller falls back to SPMD axis env
-            pass
-    return None
+    mesh = mesh_lib.thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 def _attn_specs(mesh, axis_name: str):

@@ -57,9 +57,9 @@ def ulysses_attention_shard(q, k, v, causal: bool = True,
     vg = lax.all_to_all(v, axis_name, split_axis=2, concat_axis=1,
                         tiled=True)
     if inner == "pallas":
-        from dlrover_tpu.ops.attention import flash_attention
+        from dlrover_tpu.ops.attention import flash_attention_shard
 
-        out = flash_attention(qg, kg, vg, causal=causal)
+        out = flash_attention_shard(qg, kg, vg, causal=causal)
     else:
         from dlrover_tpu.ops.attention import reference_attention
 

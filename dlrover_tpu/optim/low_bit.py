@@ -16,7 +16,9 @@ case the CUDA kernels in the reference exist for, done the TPU way.
 Transient memory is bounded by the kernel's VMEM tile, so scanned
 48-layer stacks update without ever materializing a layer of fp32
 state — this is what lets a 1.5B model train on a single 16 GB chip.
-On non-TPU backends the kernel runs in interpreter mode (tests).
+The moments are replicated and the kernel is not mesh-partitioned, so
+``auto_accelerate`` refuses it on a mesh of more than one device.
+Interpreter mode is for the CPU tests only (``dlrover_tpu.ops.interpret``).
 """
 
 from functools import partial
@@ -26,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.experimental import pallas as pl
+
+from dlrover_tpu.ops import interpret as interpret_mode
 
 
 class _QTensor(NamedTuple):
@@ -279,12 +283,9 @@ def adam8bit(
         # scale. The moments are replicated instead: at ~2 bytes/param
         # that is the 8-bit optimizer's single-chip memory story; under
         # FSDP the fp32 master path is the sharded one.
-        try:
-            import flax.linen as nn
+        import flax.linen as nn
 
-            params = nn.meta.unbox(params)
-        except (ImportError, AttributeError):
-            pass  # flax absent or too old to have meta.unbox: params are plain
+        params = nn.meta.unbox(params)
 
         def qzero(p):
             z = jnp.zeros_like(p, jnp.float32)
@@ -305,7 +306,7 @@ def adam8bit(
         bc1 = 1 - b1 ** stepf
         bc2 = 1 - b2 ** stepf
         bc12 = jnp.stack([bc1, bc2]).reshape(1, 2)
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode.use_interpret()
 
         flat_g, treedef = jax.tree_util.tree_flatten(grads)
         flat_m = treedef.flatten_up_to(state.m)

@@ -15,34 +15,35 @@ _ENTRY_TS = _time.time()
 _INIT_DONE_TS: Optional[float] = None
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> str:
-    """Point JAX's persistent compilation cache at a job-stable dir.
+# The cache directory is part of the cache key, so it must not move
+# between runs: no job name, pid or temp dir in it.
+CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
+)
 
-    THE restart-cost lever (VERDICT r4 #1): a relaunched worker replays
-    every jit compile unless the executable cache survives the process
-    — the reference never pays this (torch has no compile step to
-    lose), so on TPU it must be amortized across restarts. Called by
-    ``init_training``; the agent exports ``DLROVER_TPU_COMPILE_CACHE``
-    per job so every incarnation (and every worker on the host) shares
-    one cache. Thresholds are zeroed: a 100 ms CPU-backend compile is
-    still worth caching when the goodput protocol pays it per restart.
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A relaunched worker replays every jit compile unless the executable
+    cache survives the process, so every incarnation of every worker
+    must land in one directory. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set the cache is placed from outside and no directory is set here;
+    otherwise it is the fixed ``.jax_cache`` beside the package. The
+    thresholds are zeroed so that even a short compile is cached.
     """
     import jax
 
-    from dlrover_tpu.common.env_utils import default_compile_cache_dir
-
-    cache_dir = (
-        cache_dir or env_utils.COMPILE_CACHE.get()
-        or default_compile_cache_dir()
-    )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not cache_dir:
+        cache_dir = CHECKOUT_COMPILE_CACHE
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        logger.info("persistent compile cache at %s", cache_dir)
-    except Exception as e:  # pragma: no cover - version drift
-        logger.warning("compile cache unavailable: %s", e)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    logger.info("persistent compile cache at %s", cache_dir)
     return cache_dir
 
 

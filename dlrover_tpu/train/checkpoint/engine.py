@@ -13,8 +13,8 @@ shm-handler half of ``dlrover/python/elastic_agent/torch/ckpt_saver.py:171-291``
   is marked for disk persist, so a sharded state stores each byte exactly
   once across processes and restore can re-assemble it for any new mesh;
 - **asynchronous saves are donation-safe**: ``save_to_memory_async``
-  dispatches engine-owned device→host copies (XLA host memory space when
-  available, on-device copy otherwise) and returns in milliseconds; the
+  dispatches engine-owned device→host copies into XLA's ``pinned_host``
+  memory space and returns in milliseconds; the
   runtime orders those copies before any later donated step reuses the
   buffers, so the background fetch never races training;
 - in **agent mode** (launched under `dlrover-tpu-run`) the engine registers a
@@ -227,8 +227,9 @@ class CheckpointEngine:
         )
         self._layout_version = 0
         self._cached_step = -1
-        # None = undecided; probed on the first snapshot.
-        self._host_memory_kind_ok: Optional[bool] = None
+        #: Memory space the last async snapshot's engine-owned copies
+        #: landed in, as the runtime reports it (None before the first).
+        self.staging_memory_kind: Optional[str] = None
         # Async staging: one background writer, at most one snapshot in
         # flight (a newer request while busy is skipped, not queued).
         import concurrent.futures
@@ -299,12 +300,11 @@ class CheckpointEngine:
         A GSPMD leaf contributes one block per unique addressable shard
         index; ``persist`` marks blocks whose replica-0 copy lives on this
         process. With ``own=True`` every device block is snapshotted into an
-        engine-owned array (host memory space when the backend supports it,
-        else an on-device copy): the XLA runtime orders those copies before
-        any later donated execution overwrites the source buffers, which is
-        what makes the async path safe against ``donate_argnums`` training
-        steps. ``own=False`` skips the copy for synchronous saves that fetch
-        before returning.
+        engine-owned array in the host memory space: the XLA runtime orders
+        those copies before any later donated execution overwrites the
+        source buffers, which is what makes the async path safe against
+        ``donate_argnums`` training steps. ``own=False`` skips the copy for
+        synchronous saves that fetch before returning.
         """
         import jax
 
@@ -357,46 +357,26 @@ class CheckpointEngine:
         return blocks, objects
 
     def _own_copies(self, arrs: List[Any]) -> List[Any]:
-        """Dispatch engine-owned copies of single-device arrays (async).
-
-        Preferred: one batched ``device_put`` into the host memory space
+        """Dispatch engine-owned copies of single-device arrays (async):
+        one batched ``device_put`` into the host memory space
         (``pinned_host``) — zero extra HBM, the D2H DMA overlaps whatever
-        runs next. Fallback: ``jnp.copy`` on device. Either way the result's
-        lifetime is independent of the caller's arrays, so later donation
-        cannot invalidate the snapshot.
+        runs next, and the result's lifetime is independent of the
+        caller's arrays, so later donation cannot invalidate the
+        snapshot. There is no on-device fallback: a second copy of the
+        state in HBM is exactly what a memory-filling job cannot afford,
+        so a backend that refuses the host space fails the save loudly.
         """
         import jax
 
-        if self._host_memory_kind_ok is not False:
-            try:
-                shardings = [
-                    jax.sharding.SingleDeviceSharding(
-                        list(a.devices())[0], memory_kind="pinned_host"
-                    )
-                    for a in arrs
-                ]
-                out = jax.device_put(arrs, shardings)
-                self._host_memory_kind_ok = True
-                return out
-            except (ValueError, NotImplementedError) as e:
-                # Memory kinds genuinely unsupported on this backend:
-                # remember and stop trying.
-                logger.info(
-                    "host memory space unavailable (%s); snapshotting via "
-                    "on-device copies", e,
-                )
-                self._host_memory_kind_ok = False
-            except Exception:
-                # Transient failure (e.g. allocation pressure): fall back
-                # for THIS snapshot only and say why — do not silently
-                # degrade every future save.
-                logger.exception(
-                    "pinned-host snapshot failed; falling back to "
-                    "on-device copies for this save"
-                )
-        import jax.numpy as jnp
-
-        return [jnp.copy(a) for a in arrs]
+        shardings = [
+            jax.sharding.SingleDeviceSharding(
+                list(a.devices())[0], memory_kind="pinned_host"
+            )
+            for a in arrs
+        ]
+        owned = jax.device_put(arrs, shardings)
+        self.staging_memory_kind = owned[0].sharding.memory_kind
+        return owned
 
     # Target bytes per device_get batch on the staging path. One giant
     # batched fetch serializes the whole D2H on a single transfer (BENCH_r06:

@@ -163,10 +163,7 @@ class Trainer:
         if report_metrics and env_utils.MASTER_ADDR.get():
             from dlrover_tpu.agent.master_client import MasterClient
 
-            try:
-                self._client = MasterClient.singleton_instance()
-            except Exception:
-                self._client = None
+            self._client = MasterClient.singleton_instance()
         from dlrover_tpu.train.elastic_trainer import StepProgressReporter
 
         self._progress = StepProgressReporter(
@@ -190,6 +187,11 @@ class Trainer:
         return self._phases
 
     @property
+    def checkpointer(self):
+        """The flash checkpointer (None without ``checkpoint_dir``)."""
+        return self._ckpt
+
+    @property
     def train_step(self):
         return self._result.train_step
 
@@ -200,10 +202,23 @@ class Trainer:
     def restore(self) -> int:
         """Resume from the newest checkpoint; returns the step to start
         from (0 when fresh)."""
+        import jax
+
         if self._ckpt is None:
             return 0
-        step, self.state = self._ckpt.load_checkpoint(self.state)
+        step, restored = self._ckpt.load_checkpoint(self.state)
         if step > 0:
+            # The freshly initialised state was only the template. Keep
+            # no reference to it, or it stays resident beside the
+            # restored one — on a memory-filling job that is an OOM on
+            # the first step after every restart.
+            self._result.state = self.state = None
+            # Commit host leaves to the step's shardings now. Left as
+            # host arrays they carry different avals than the state the
+            # step was traced for, the first step traces anew, and that
+            # trace misses the persistent compile cache: a full
+            # recompile on every first restart.
+            self.state = jax.device_put(restored, self._result.shardings)
             logger.info("trainer resumed from step %s", step)
         return max(0, step)
 

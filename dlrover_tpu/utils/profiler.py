@@ -41,6 +41,9 @@ _PEAK_FLOPS = (
 
 
 def device_peak_flops(device=None) -> float:
+    """Peak FLOP/s of ``device``. The CPU backend has none (0.0, so MFU
+    reads -1); a TPU whose kind is neither in the table nor overridden
+    is an error, not a silent MFU of -1."""
     import jax
 
     device = device or jax.devices()[0]
@@ -48,7 +51,14 @@ def device_peak_flops(device=None) -> float:
     for key, peak in _PEAK_FLOPS:
         if key in kind:
             return peak
-    return env_utils.PEAK_FLOPS.get()
+    peak = env_utils.PEAK_FLOPS.get()
+    if not peak and device.platform == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device kind "
+            f"{device.device_kind!r}: add it to _PEAK_FLOPS or set "
+            f"{env_utils.PEAK_FLOPS.name}"
+        )
+    return peak
 
 
 class StepStats:
@@ -263,8 +273,6 @@ class Profiler:
         flops-profile analog but from XLA itself."""
         lowered = jitted_fn.lower(*example_args)
         cost = lowered.compile().cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
         self._cost = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

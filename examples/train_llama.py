@@ -49,7 +49,7 @@ def main():
     cfg = LlamaConfig(
         vocab_size=2048, max_seq_len=args.seq, num_layers=4,
         num_heads=8, num_kv_heads=4, d_model=256,
-        attn_impl="pallas" if jax.default_backend() == "tpu" else "xla",
+        attn_impl="pallas",
     )
 
     def token_loss(module, params, batch):

@@ -1,0 +1,256 @@
+"""Guards for what only the chip can break, checked without a chip.
+
+The CPU mesh cannot catch a Mosaic kernel that GSPMD is asked to
+partition: in interpret mode a Pallas kernel lowers to ordinary HLO. So
+these tests compile ahead of time, interpret mode off, against libtpu's
+description of a four-chip v5e host (no device needed; it says what
+lowers, partitions and fits, nothing about runtime or speed).
+"""
+
+import json
+import os
+import subprocess
+import sys
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.experimental import topologies
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from dlrover_tpu import train as dtrain
+from dlrover_tpu.ops import interpret as interpret_mode
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    ).devices
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Interpret mode off, as on the chip."""
+    monkeypatch.setattr(interpret_mode, "use_interpret", lambda: False)
+
+
+class TestAotOnV5eTopology:
+    def test_flash_attention_fwd_bwd_at_xl_shape(
+        self, v5e_2x2, compiled_kernels
+    ):
+        from dlrover_tpu.ops.attention import flash_attention
+
+        x = jax.ShapeDtypeStruct(
+            (4, 1024, 25, 64), jnp.bfloat16,
+            sharding=SingleDeviceSharding(v5e_2x2[0]),
+        )
+
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, block_q=1024, block_k=1024)
+            return jnp.sum(out.astype(jnp.float32))
+
+        compiled = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2))
+        ).lower(x, x, x).compile()
+        assert _mosaic_calls(compiled) == 3  # fwd, dq, dkv
+
+    def test_fused_adam8bit_on_an_xl_leaf(self, v5e_2x2, compiled_kernels):
+        from dlrover_tpu.optim.low_bit import adam8bit
+
+        one = SingleDeviceSharding(v5e_2x2[0])
+        opt = adam8bit(2e-4)
+        # The embedding: its block count is odd, the padded-tile path.
+        leaf = {"wte": jax.ShapeDtypeStruct(
+            (50257, 1600), jnp.bfloat16, sharding=one
+        )}
+        state = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            jax.eval_shape(opt.init, leaf),
+        )
+        compiled = jax.jit(opt.update_and_apply).lower(
+            leaf, state, leaf
+        ).compile()
+        assert _mosaic_calls(compiled) == 1
+
+    def test_pallas_gpt_step_partitions_over_data_and_fsdp(
+        self, v5e_2x2, compiled_kernels
+    ):
+        """``attn_impl="pallas"`` under a mesh of more than one chip: dies
+        at lowering ("Mosaic kernels cannot be automatically
+        partitioned") unless the kernel is wrapped in shard_map."""
+        from dlrover_tpu.accel import ParallelSpec
+        from dlrover_tpu.accel.accelerate import make_train_step
+        from dlrover_tpu.accel.mesh import create_mesh
+        from dlrover_tpu.accel.sharding import state_shardings, unbox
+        from dlrover_tpu.models.gpt import GPT, GPTConfig, loss_fn
+
+        cfg = GPTConfig(
+            vocab_size=512, max_seq_len=256, num_layers=2, num_heads=2,
+            d_model=256, attn_impl="pallas", attn_block_q=128,
+            attn_block_k=128, remat=True, remat_policy="dots",
+        )
+        spec = ParallelSpec(data=2, fsdp=2)
+        mesh = create_mesh(spec.axes(), devices=v5e_2x2)
+        rules = spec.rules(vocab_size=cfg.vocab_size)
+        model, opt = GPT(cfg), optax.adamw(1e-3)
+        tokens = jnp.zeros((8, 256), jnp.int32)
+
+        def init_fn(rng):
+            params = model.init(rng, tokens)["params"]
+            return {"params": params, "opt": opt.init(params),
+                    "step": jnp.zeros((), jnp.int32)}
+
+        def token_loss(module, params, batch):
+            return loss_fn(module.apply({"params": params}, batch), batch)
+
+        abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+        shardings = state_shardings(mesh, abstract, rules)
+        batch_sharding = NamedSharding(mesh, P(dict(rules)["batch"], None))
+        step = make_train_step(
+            model, opt, token_loss, mesh, rules, shardings, batch_sharding
+        )
+        state = jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            unbox(abstract), shardings,
+        )
+        batch = jax.ShapeDtypeStruct(
+            tokens.shape, tokens.dtype, sharding=batch_sharding
+        )
+        compiled = step.lower(state, batch).compile()
+        assert _mosaic_calls(compiled) > 0
+        assert " all-gather(" in compiled.as_text()
+
+
+class TestInterpretIsADecision:
+    def test_only_a_named_cpu_platform_interprets(self, monkeypatch):
+        assert interpret_mode.use_interpret()  # conftest names the CPU
+        for platforms, want in (
+            ("", False), (None, False), ("tpu", False), ("tpu,cpu", False),
+            ("cpu", True), ("cpu,tpu", True),
+        ):
+            monkeypatch.setattr(interpret_mode, "jax", SimpleNamespace(
+                config=SimpleNamespace(jax_platforms=platforms)
+            ))
+            assert interpret_mode.use_interpret() is want, platforms
+
+    def test_adam8bit_refused_on_a_mesh_by_name(self):
+        from dlrover_tpu.accel import ParallelSpec, auto_accelerate
+        from dlrover_tpu.models.gpt import GPT, GPTConfig
+        from dlrover_tpu.optim.low_bit import adam8bit
+
+        tokens = jnp.zeros((2, 16), jnp.int32)
+        with pytest.raises(ValueError, match="adam8bit"):
+            auto_accelerate(
+                GPT(GPTConfig.tiny()), adam8bit(1e-3), tokens,
+                lambda m, p, b: 0.0, spec=ParallelSpec(data=2),
+            )
+
+
+class TestCompileCachePlacement:
+    @pytest.fixture
+    def cache_config(self):
+        """Restore whatever the session's cache configuration was."""
+        names = (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+        )
+        saved = {n: getattr(jax.config, n) for n in names}
+        yield
+        for n, v in saved.items():
+            jax.config.update(n, v)
+
+    def test_placed_from_outside_sets_no_directory(
+        self, monkeypatch, tmp_path, cache_config
+    ):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "left-alone")
+        assert dtrain.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "left-alone"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+    def test_unset_uses_the_one_checkout_path(
+        self, monkeypatch, cache_config
+    ):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        # Nothing about the job, the process or the clock may move it.
+        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", "some-other-job")
+        assert dtrain.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert dtrain.CHECKOUT_COMPILE_CACHE == want
+
+
+class TestOneChipPerWorker:
+    def test_layouts_the_assignment_cannot_serve_are_refused(self):
+        from dlrover_tpu.agent.tpu_chips import check_layout
+
+        check_layout(nproc_per_node=4, max_nodes=1, chips=0)  # CPU host
+        check_layout(nproc_per_node=1, max_nodes=8, chips=4)
+        check_layout(nproc_per_node=4, max_nodes=1, chips=4)
+        with pytest.raises(ValueError, match="nproc_per_node=2"):
+            check_layout(nproc_per_node=2, max_nodes=1, chips=4)
+        with pytest.raises(ValueError, match="nnodes"):
+            check_layout(nproc_per_node=4, max_nodes=2, chips=4)
+
+    def test_each_worker_gets_its_own_chip_and_the_same_address_list(self):
+        from dlrover_tpu.agent.tpu_chips import worker_chip_env
+
+        ports = [8476, 8477, 8478, 8479]
+        envs = [worker_chip_env(rank, ports) for rank in range(4)]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == list("0123")
+        assert [e["TPU_PROCESS_PORT"] for e in envs] == [
+            str(p) for p in ports
+        ]
+        assert len({e["TPU_PROCESS_ADDRESSES"] for e in envs}) == 1
+        assert envs[0]["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        assert envs[0]["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+
+class TestChipSmokeContract:
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+    def _run(self, *args, cwd=None, script=None, timeout=300):
+        from tests.conftest import cpu_subprocess_env
+
+        return subprocess.run(
+            [sys.executable, script or self.SMOKE, *args], cwd=cwd,
+            env=cpu_subprocess_env(), capture_output=True, text=True,
+            timeout=timeout,
+        )
+
+    def test_no_accelerator_is_a_failure_without_a_result(self):
+        r = self._run()
+        assert r.returncode != 0
+        assert "no TPU" in r.stderr
+        assert r.stdout.strip() == ""
+
+    def test_the_script_alone_fails(self, tmp_path):
+        import shutil
+
+        alone = shutil.copy(self.SMOKE, tmp_path)
+        r = self._run(cwd=tmp_path, script=alone)
+        assert r.returncode != 0
+        assert r.stdout.strip() == ""
+
+    @pytest.mark.slow
+    def test_cpu_rehearsal_of_the_one_chip_leg(self):
+        """The smoke's whole control flow — launcher, device check, fork
+        server, kill, flush, restart, restore, cache hit — at a toy width."""
+        r = self._run("--cpu-rehearsal", "--legs", "one")
+        assert r.returncode == 0, r.stderr[-3000:]
+        lines = r.stdout.strip().splitlines()
+        assert json.loads(lines[-1]) == {"rehearsal": "cpu", "passed": True}
+        leg = json.loads(lines[0])
+        assert leg["resumed_at_step"] == leg["killed_at_step"]
+        assert leg["restart"]["cache_misses"] == 0

@@ -407,8 +407,8 @@ class TestProfiledSearch:
 class TestCalibratedAgainstChip:
     """VERDICT r4 #7: the cost model's constants must rest on
     measurements, not spec-sheet priors. Measured step times below are
-    from bench.py on one real TPU v5e chip (BENCH_r04 + r5 probes,
-    2026-07-30); estimate() must predict each within +-30%. If a model
+    from an earlier on-chip run on one TPU v5e chip, to be re-measured;
+    estimate() must predict each within +-30%. If a model
     or kernel change moves the real numbers, re-measure and update —
     this test pins the calibration contract, not the hardware."""
 
@@ -449,7 +449,8 @@ class TestCalibratedAgainstChip:
         from dlrover_tpu.accel.search import estimate
         from dlrover_tpu.models.llama import LlamaConfig
 
-        # LLaMA 1.15B, B=4, S=2048: 12.7k tok/s (BENCH_r04)
+        # LLaMA 1.15B, B=4, S=2048: 12.7k tok/s (an earlier on-chip run, to be
+        # re-measured)
         cfg = LlamaConfig(
             vocab_size=32000, max_seq_len=2048, num_layers=18,
             num_heads=16, num_kv_heads=8, d_model=2048, remat=True,
