@@ -39,6 +39,7 @@ import glob
 import itertools
 import json
 import os
+import resource
 import shutil
 import signal
 import subprocess
@@ -156,8 +157,10 @@ def worker_main(args) -> int:
         rank=dtrain.global_rank(), incarnation=incarnation,
     )
     dev = jax.devices()[0]
+    fsize = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
     rec.write(
         "start", platform=dev.platform, device_kind=dev.device_kind,
+        file_size_limit=None if fsize == resource.RLIM_INFINITY else fsize,
         device_count=len(jax.devices()),
         local_device_count=len(jax.local_devices()),
         process_count=jax.process_count(),
@@ -282,13 +285,17 @@ def worker_main(args) -> int:
             list(s.data.shape) for s in wte.addressable_shards
         ]
         final["wte_shape"] = list(wte.shape)
+    cache_files = glob.glob(
+        os.path.join(jax.config.jax_compilation_cache_dir, "*")
+    )
     final.update(
         staging_memory_kind=engine.staging_memory_kind,
         staged_step=engine.cached_step,
         bytes_in_use=[m.get("bytes_in_use") for m in mem_stats],
         peak_bytes_in_use=[m.get("peak_bytes_in_use") for m in mem_stats],
         bytes_limit=[m.get("bytes_limit") for m in mem_stats],
-        cache_entries=len(os.listdir(jax.config.jax_compilation_cache_dir)),
+        cache_entries=len(cache_files),
+        cache_largest_bytes=max(map(os.path.getsize, cache_files), default=0),
         **compiles,
     )
     rec.write("done", **final)
@@ -523,6 +530,10 @@ def check_leg(leg: str, records: list, out: str, rehearsal: bool) -> dict:
         ],
         "cache_dir": starts[0]["cache_dir"],
         "cache_entries": d0["cache_entries"],
+        "cache_largest_mb": round(d0["cache_largest_bytes"] / 1e6, 1),
+        # Under a limit the snapshot segment and the persisted shard are
+        # kept as part files below it (dlrover_tpu/common/fsutil.py).
+        "file_size_limit": starts[0]["file_size_limit"],
         "master_steps_reported": goodput.get("steps_reported"),
     }
     if not rehearsal:

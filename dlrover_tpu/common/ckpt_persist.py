@@ -4,7 +4,10 @@ standalone (agent-less) trainer engines.
 Layout under ``checkpoint_dir`` (parity: reference done-file + tracker-file
 protocol, ``dlrover/python/elastic_agent/torch/ckpt_saver.py:747-785``)::
 
-    checkpoint-{step}/shard_{gid}.bin    raw shm buffer (used bytes only)
+    checkpoint-{step}/shard_{gid}.bin    raw shm buffer (used bytes only);
+                                         written under a file-size limit,
+                                         continued in ``.bin.part1``… by
+                                         the posix storage (``fsutil.py``)
     checkpoint-{step}/shard_{gid}.meta   pickled ShardMeta
     checkpoint-{step}/done_{gid}         commit vote of shard gid
     latest_checkpointed_iteration.txt    tracker: last fully-committed step

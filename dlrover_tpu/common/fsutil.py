@@ -13,9 +13,12 @@ is synced, which callers that need directory durability do themselves
 (the state store does; one-shot result files don't bother).
 """
 
+import mmap
 import os
+import resource
+import sys
 import tempfile
-from typing import Union
+from typing import List, Union
 
 
 def atomic_write_bytes(path: str, data: bytes, fsync: bool = True) -> None:
@@ -55,3 +58,57 @@ def write_or_none(path: str) -> Union[bytes, None]:
             return f.read()
     except (FileNotFoundError, IsADirectoryError):
         return None
+
+
+# -- files larger than the process may write -------------------------------
+#
+# Under a file-size limit (``RLIMIT_FSIZE``: ``ulimit -f``, a container's
+# rlimits) no single file may pass the limit: ``ftruncate`` and ``write``
+# fail with EFBIG. The two places that write files as large as the training
+# state — the flash-checkpoint shm segment and the persisted shard — then
+# store one logical file as parts: ``path``, ``path.part1``, ``path.part2``…
+# Every part but the last has the size of the first, so a reader needs no
+# other record, and a reader under another limit (or none) reads the same
+# bytes. Without a limit there is one part and the layout is what it
+# always was.
+
+
+def max_part_bytes() -> int:
+    """The most bytes one file may hold here: page-aligned and strictly
+    below the soft ``RLIMIT_FSIZE`` (Linux refuses a length above the
+    limit, gVisor one equal to it); ``sys.maxsize`` when unlimited."""
+    soft, _ = resource.getrlimit(resource.RLIMIT_FSIZE)
+    if soft == resource.RLIM_INFINITY:
+        return sys.maxsize
+    part = (soft - 1) // mmap.PAGESIZE * mmap.PAGESIZE
+    if part <= 0:
+        raise OSError(
+            f"RLIMIT_FSIZE is {soft} bytes: no file of a page can be written"
+        )
+    return part
+
+
+def part_path(path: str, index: int) -> str:
+    return path if index == 0 else f"{path}.part{index}"
+
+
+def existing_parts(path: str) -> List[str]:
+    """The part files of `path` that exist, in order; empty when `path`
+    itself does not."""
+    parts: List[str] = []
+    while os.path.exists(part_path(path, len(parts))):
+        parts.append(part_path(path, len(parts)))
+    return parts
+
+
+def remove_parts(path: str, start: int = 0) -> None:
+    """Remove the parts of `path` from index `start` up. A missing part 0
+    does not end the search: a torn removal may have left later ones."""
+    index = start
+    while True:
+        try:
+            os.unlink(part_path(path, index))
+        except FileNotFoundError:
+            if index > 0:
+                return
+        index += 1

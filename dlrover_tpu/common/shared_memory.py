@@ -7,13 +7,18 @@ same semantics more simply: a file under ``/dev/shm`` mapped with ``mmap``.
 The segment lives until `unlink()` (or host reboot), exactly what a
 flash-checkpoint buffer needs — the agent re-attaches to a dead trainer's
 buffer and persists it.
+
+A segment larger than the process's file-size limit is kept as part files
+(``common/fsutil.py``) mapped back to back, so `buf` is one contiguous
+view either way.
 """
 
+import ctypes
 import mmap
 import os
-from typing import Optional
+from typing import List, Optional
 
-from dlrover_tpu.common import env_utils
+from dlrover_tpu.common import env_utils, fsutil
 
 SHM_DIR = env_utils.SHM_DIR.get()
 
@@ -21,6 +26,41 @@ SHM_DIR = env_utils.SHM_DIR.get()
 def _path(name: str) -> str:
     safe = name.replace("/", "_")
     return os.path.join(SHM_DIR, safe)
+
+
+_MAP_FIXED = 0x10  # Linux; the mmap module does not export it
+
+
+def _map_parts(fds: List[int], sizes: List[int]) -> mmap.mmap:
+    """One contiguous shared mapping of the files `fds` (every size but
+    the last a multiple of the page size)."""
+    if len(fds) == 1:
+        return mmap.mmap(fds[0], sizes[0])
+    # Reserve the address range with an anonymous map, then map each file
+    # over its slice of it. The mmap object still owns the whole range:
+    # its close() unmaps the files, and refuses while views are exported.
+    libc = ctypes.CDLL(None, use_errno=True)
+    libc.mmap.restype = ctypes.c_void_p
+    libc.mmap.argtypes = [
+        ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.c_long,
+    ]
+    region = mmap.mmap(-1, sum(sizes), flags=mmap.MAP_PRIVATE)
+    anchor = ctypes.c_char.from_buffer(region)
+    base = ctypes.addressof(anchor)
+    del anchor  # release the export, or region.close() could never succeed
+    offset = 0
+    for fd, size in zip(fds, sizes):
+        got = libc.mmap(
+            base + offset, size, mmap.PROT_READ | mmap.PROT_WRITE,
+            mmap.MAP_SHARED | _MAP_FIXED, fd, 0,
+        )
+        if got != base + offset:
+            errno = ctypes.get_errno()
+            region.close()
+            raise OSError(errno, f"mmap of a shm part: {os.strerror(errno)}")
+        offset += size
+    return region
 
 
 class SharedMemory:
@@ -36,32 +76,43 @@ class SharedMemory:
         self._file_path = _path(name)
         self._mmap: Optional[mmap.mmap] = None
         self._buf: Optional[memoryview] = None
-        if create:
-            if size <= 0:
-                raise ValueError("size must be > 0 when creating")
-            flags = os.O_CREAT | os.O_RDWR
-            fd = os.open(self._file_path, flags, 0o600)
-            try:
-                cur = os.fstat(fd).st_size
-                if cur != size:
-                    os.ftruncate(fd, size)
-                # Reserve the pages now: a tmpfs too small for the
-                # segment then fails here with ENOSPC, not with SIGBUS
-                # in the middle of a snapshot copy.
-                os.posix_fallocate(fd, 0, size)
-                self._mmap = mmap.mmap(fd, size)
-            finally:
-                os.close(fd)
-            self._size = size
-        else:
-            fd = os.open(self._file_path, os.O_RDWR)
-            try:
-                self._size = os.fstat(fd).st_size
-                if self._size == 0:
+        fds: List[int] = []
+        try:
+            if create:
+                if size <= 0:
+                    raise ValueError("size must be > 0 when creating")
+                part = fsutil.max_part_bytes()
+                sizes = [min(part, size - o) for o in range(0, size, part)]
+                # A larger earlier segment of this name may have had more.
+                fsutil.remove_parts(self._file_path, len(sizes))
+                for i, n in enumerate(sizes):
+                    fd = os.open(
+                        fsutil.part_path(self._file_path, i),
+                        os.O_CREAT | os.O_RDWR, 0o600,
+                    )
+                    fds.append(fd)
+                    if os.fstat(fd).st_size != n:
+                        os.ftruncate(fd, n)
+                    # Reserve the pages now: a tmpfs too small for the
+                    # segment then fails here with ENOSPC, not with SIGBUS
+                    # in the middle of a snapshot copy.
+                    os.posix_fallocate(fd, 0, n)
+            else:
+                fds.append(os.open(self._file_path, os.O_RDWR))
+                for path in fsutil.existing_parts(self._file_path)[1:]:
+                    fds.append(os.open(path, os.O_RDWR))
+                sizes = [os.fstat(fd).st_size for fd in fds]
+                if sizes[0] == 0:
                     raise ValueError(f"shared memory {name} is empty")
-                self._mmap = mmap.mmap(fd, self._size)
-            finally:
+            self._mmap = _map_parts(fds, sizes)
+        except OSError:
+            if create:  # leave no half-made segment holding tmpfs pages
+                fsutil.remove_parts(self._file_path)
+            raise
+        finally:
+            for fd in fds:
                 os.close(fd)
+        self._size = sum(sizes)
         self._buf = memoryview(self._mmap)
 
     @property
@@ -97,10 +148,7 @@ class SharedMemory:
 
     def unlink(self):
         self.close()
-        try:
-            os.unlink(self._file_path)
-        except FileNotFoundError:
-            pass
+        fsutil.remove_parts(self._file_path)
 
     @staticmethod
     def exists(name: str) -> bool:
@@ -108,10 +156,7 @@ class SharedMemory:
 
     @staticmethod
     def remove(name: str):
-        try:
-            os.unlink(_path(name))
-        except FileNotFoundError:
-            pass
+        fsutil.remove_parts(_path(name))
 
     def __del__(self):  # close the map, never unlink implicitly
         try:

@@ -23,9 +23,12 @@ storage through two capability handles:
 
 import os
 import shutil
+import sys
 import threading
 from abc import ABC, abstractmethod
-from typing import List, Optional
+from typing import Dict, List, Optional
+
+from dlrover_tpu.common import fsutil
 
 # os.pwritev takes at most IOV_MAX buffers per call; chunk conservatively.
 _IOV_MAX = min(getattr(os, "IOV_MAX", 1024), 1024)
@@ -90,55 +93,81 @@ class _PosixStripeWriter(StripeWriter):
     """pwrite/pwritev into a preallocated ``.tmp``, one fsync, atomic
     rename — the stripe pipeline's write side. Preallocation means
     positional writes never extend the file, so out-of-order stripes
-    don't create sparse-then-filled metadata churn."""
+    don't create sparse-then-filled metadata churn.
+
+    Under a file-size limit the bytes go to part files of at most that
+    size (``common/fsutil.py``); without one there is a single part."""
 
     def __init__(self, path: str, size: Optional[int] = None):
         self._path = path
-        self._tmp = path + ".tmp"
-        self._fd: Optional[int] = os.open(
-            self._tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644
-        )
-        if size:
-            os.ftruncate(self._fd, size)
+        self._part = fsutil.max_part_bytes()
+        self._fds: Dict[int, int] = {}
+        try:
+            for i, off in enumerate(range(0, size or 1, self._part)):
+                fd = self._fd_of(i)
+                if size:
+                    os.ftruncate(fd, min(self._part, size - off))
+        except OSError:
+            self.abort()
+            raise
+
+    def _fd_of(self, index: int) -> int:
+        if index not in self._fds:
+            self._fds[index] = os.open(
+                fsutil.part_path(self._path, index) + ".tmp",
+                os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644,
+            )
+        return self._fds[index]
 
     def write_at(self, offset: int, data) -> None:
-        mv = _as_u8(data)
-        while mv.nbytes:
-            n = os.pwrite(self._fd, mv, offset)
-            offset += n
-            mv = mv[n:]
+        self.writev_at(offset, [data])
 
     def writev_at(self, offset: int, views: List[memoryview]) -> None:
-        iov = [_as_u8(v) for v in views if _as_u8(v).nbytes]
+        iov = [mv for mv in map(_as_u8, views) if mv.nbytes]
         while iov:
-            batch = iov[:_IOV_MAX]
-            n = os.pwritev(self._fd, batch, offset)
+            index, at = divmod(offset, self._part)
+            # As many whole buffers as the call and this part will take,
+            # or the head of one that crosses into the next part.
+            room = self._part - at
+            batch: List[memoryview] = []
+            for mv in iov[:_IOV_MAX]:
+                if mv.nbytes > room:
+                    break
+                batch.append(mv)
+                room -= mv.nbytes
+            if not batch:
+                batch = [iov[0][:room]]
+            n = os.pwritev(self._fd_of(index), batch, at)
             offset += n
             # Drop fully-written buffers; trim a partially-written head.
-            while n and batch:
-                head = batch[0]
-                if n >= head.nbytes:
-                    n -= head.nbytes
-                    batch.pop(0)
+            while n:
+                if n >= iov[0].nbytes:
+                    n -= iov.pop(0).nbytes
                 else:
-                    batch[0] = head[n:]
+                    iov[0] = iov[0][n:]
                     n = 0
-            iov = batch + iov[_IOV_MAX:]
 
     def commit(self) -> None:
-        os.fsync(self._fd)
-        os.close(self._fd)
-        self._fd = None
-        os.replace(self._tmp, self._path)
+        for fd in self._fds.values():
+            os.fsync(fd)
+            os.close(fd)
+        # Part 0 names the file, so it lands last; parts a larger earlier
+        # file of this name had beyond ours go.
+        indices = sorted(self._fds, reverse=True)
+        self._fds = {}
+        for i in indices:
+            tmp = fsutil.part_path(self._path, i) + ".tmp"
+            os.replace(tmp, fsutil.part_path(self._path, i))
+        fsutil.remove_parts(self._path, indices[0] + 1)
 
     def abort(self) -> None:
-        if self._fd is not None:
-            os.close(self._fd)
-            self._fd = None
-        try:
-            os.remove(self._tmp)
-        except OSError:
-            pass
+        for i, fd in self._fds.items():
+            os.close(fd)
+            try:
+                os.remove(fsutil.part_path(self._path, i) + ".tmp")
+            except OSError:
+                pass
+        self._fds = {}
 
 
 class RangeReader:
@@ -182,18 +211,48 @@ class RangeReader:
 
 
 class _PosixRangeReader(RangeReader):
+    """Shared-fd pread over the file's parts (one, unless it was written
+    under a file-size limit: ``common/fsutil.py``)."""
+
     def __init__(self, path: str):
-        self._fd = os.open(path, os.O_RDONLY)
-        self._size = os.fstat(self._fd).st_size
+        self._fds = [os.open(path, os.O_RDONLY)]
+        try:
+            for part in fsutil.existing_parts(path)[1:]:
+                self._fds.append(os.open(part, os.O_RDONLY))
+            sizes = [os.fstat(fd).st_size for fd in self._fds]
+        except OSError:
+            self.close()
+            raise
+        self._size = sum(sizes)
+        # Every part but the last has the first one's size.
+        self._part = sizes[0] if len(sizes) > 1 else sys.maxsize
 
     def read(self, offset: int, nbytes: int) -> bytes:
-        return os.pread(self._fd, nbytes, offset)
+        pieces = []
+        while nbytes > 0:
+            index, at = divmod(offset, self._part)
+            if index >= len(self._fds):
+                break
+            data = os.pread(
+                self._fds[index], min(nbytes, self._part - at), at
+            )
+            if not data:
+                break
+            pieces.append(data)
+            offset += len(data)
+            nbytes -= len(data)
+        return b"".join(pieces)
 
     def read_into(self, offset: int, view) -> int:
         mv = _as_u8(memoryview(view))
         total = 0
         while mv.nbytes:
-            n = os.preadv(self._fd, [mv], offset)
+            index, at = divmod(offset, self._part)
+            if index >= len(self._fds):
+                break
+            n = os.preadv(
+                self._fds[index], [mv[:self._part - at]], at
+            )
             if n == 0:
                 break
             total += n
@@ -205,9 +264,9 @@ class _PosixRangeReader(RangeReader):
         return self._size
 
     def close(self) -> None:
-        if self._fd is not None:
-            os.close(self._fd)
-            self._fd = None
+        for fd in self._fds:
+            os.close(fd)
+        self._fds = []
 
 
 class CheckpointStorage(ABC):
@@ -306,8 +365,11 @@ class PosixDiskStorage(CheckpointStorage):
             os.fsync(f.fileno())
         os.replace(tmp, path)
 
+    # Binary files go through the positional writer and reader, which
+    # keep one larger than the file-size limit as parts.
     def write_bytes(self, data: bytes, path: str):
-        self.write(data, path)
+        with self.open_writer(path, len(data)) as w:
+            w.write_at(0, data)
 
     # read/read_range open and catch instead of pre-checking existence:
     # the exists() probe was both an extra syscall per block and a TOCTOU
@@ -320,15 +382,18 @@ class PosixDiskStorage(CheckpointStorage):
             return None
 
     def read_bytes(self, path: str) -> Optional[bytes]:
-        return self.read(path, "rb")
+        reader = self.open_reader(path)
+        if reader is None:
+            return None
+        with reader:
+            return reader.read(0, reader.size())
 
     def read_range(self, path: str, offset: int, nbytes: int):
-        try:
-            with open(path, "rb") as f:
-                f.seek(offset)
-                return f.read(nbytes)
-        except (FileNotFoundError, NotADirectoryError):
+        reader = self.open_reader(path)
+        if reader is None:
             return None
+        with reader:
+            return reader.read(offset, nbytes)
 
     def open_writer(self, path: str, size: Optional[int] = None) -> StripeWriter:
         return _PosixStripeWriter(path, size)
@@ -340,7 +405,10 @@ class PosixDiskStorage(CheckpointStorage):
             return None
 
     def safe_rename(self, src: str, dst: str):
+        parts = fsutil.existing_parts(src)[1:]
         os.replace(src, dst)
+        for i, part in enumerate(parts, start=1):
+            os.replace(part, fsutil.part_path(dst, i))
 
     def safe_makedirs(self, path: str):
         os.makedirs(path, exist_ok=True)
@@ -350,7 +418,7 @@ class PosixDiskStorage(CheckpointStorage):
             shutil.rmtree(path, ignore_errors=True)
         elif os.path.exists(path):
             try:
-                os.remove(path)
+                fsutil.remove_parts(path)
             except OSError:
                 pass
 

@@ -26,6 +26,8 @@ _JAX_CACHE = tempfile.mkdtemp(prefix="dlrover_tpu_test_jax_cache_")
 os.environ["JAX_COMPILATION_CACHE_DIR"] = _JAX_CACHE
 atexit.register(shutil.rmtree, _JAX_CACHE, ignore_errors=True)
 
+import contextlib
+import resource
 import uuid
 
 import pytest
@@ -53,3 +55,20 @@ def job_name(monkeypatch):
     name = f"test-{uuid.uuid4().hex[:8]}"
     monkeypatch.setenv("DLROVER_TPU_JOB_NAME", name)
     return name
+
+
+@pytest.fixture
+def file_size_limit():
+    """``with file_size_limit(n):`` runs under a soft RLIMIT_FSIZE of `n`
+    bytes, as a container with a file-size limit would (Python ignores
+    SIGXFSZ, so a write past it fails with EFBIG)."""
+    @contextlib.contextmanager
+    def limited(nbytes):
+        soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+        resource.setrlimit(resource.RLIMIT_FSIZE, (nbytes, hard))
+        try:
+            yield
+        finally:
+            resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+
+    return limited

@@ -220,13 +220,18 @@ class TestChipSmokeContract:
     REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     SMOKE = os.path.join(REPO, "chip_smoke.py")
 
-    def _run(self, *args, cwd=None, script=None, timeout=300):
+    def _run(self, *args, cwd=None, script=None, timeout=300, fsize=None):
+        import resource
+
         from tests.conftest import cpu_subprocess_env
+
+        def limit():  # hard and soft, as `ulimit -f` sets them
+            resource.setrlimit(resource.RLIMIT_FSIZE, (fsize, fsize))
 
         return subprocess.run(
             [sys.executable, script or self.SMOKE, *args], cwd=cwd,
             env=cpu_subprocess_env(), capture_output=True, text=True,
-            timeout=timeout,
+            timeout=timeout, preexec_fn=limit if fsize else None,
         )
 
     def test_no_accelerator_is_a_failure_without_a_result(self):
@@ -246,11 +251,16 @@ class TestChipSmokeContract:
     @pytest.mark.slow
     def test_cpu_rehearsal_of_the_one_chip_leg(self):
         """The smoke's whole control flow — launcher, device check, fork
-        server, kill, flush, restart, restore, cache hit — at a toy width."""
-        r = self._run("--cpu-rehearsal", "--legs", "one")
+        server, kill, flush, restart, restore, cache hit — at a toy width,
+        and under a file-size limit smaller than the toy state (574 kB):
+        the driver's chip machine has one smaller than the real state, and
+        the first smoke died there with EFBIG."""
+        fsize = 384 << 10
+        r = self._run("--cpu-rehearsal", "--legs", "one", fsize=fsize)
         assert r.returncode == 0, r.stderr[-3000:]
         lines = r.stdout.strip().splitlines()
         assert json.loads(lines[-1]) == {"rehearsal": "cpu", "passed": True}
         leg = json.loads(lines[0])
+        assert leg["file_size_limit"] == fsize
         assert leg["resumed_at_step"] == leg["killed_at_step"]
         assert leg["restart"]["cache_misses"] == 0

@@ -1,6 +1,7 @@
 """Unit tests for the common substrate: shm, shared objects, storage, rpc."""
 
 import multiprocessing as mp
+import os
 import queue
 import uuid
 
@@ -50,6 +51,45 @@ class TestSharedMemory:
         s = SharedMemory(name)
         assert bytes(s.buf[:4]) == b"abcd"
         s.unlink()
+
+
+    def test_segment_above_the_file_size_limit_is_kept_as_parts(
+        self, file_size_limit
+    ):
+        """The refusal on the driver's chip machine: a 6.3 GB snapshot
+        segment under an RLIMIT_FSIZE below that died in ftruncate with
+        EFBIG. Here: 1 MiB limit, 3 MiB + 5 byte segment."""
+        name = f"shm-{uuid.uuid4().hex[:8]}"
+        size = 3 * (1 << 20) + 5
+        want = (np.arange(size) % 251).astype(np.uint8)
+        with file_size_limit(1 << 20):
+            shm = SharedMemory(name, create=True, size=size)
+        try:
+            files = sorted(
+                f for f in os.listdir("/dev/shm") if f.startswith(name)
+            )
+            assert files == [name] + [f"{name}.part{i}" for i in (1, 2, 3)]
+            assert all(
+                os.path.getsize(f"/dev/shm/{f}") < (1 << 20) for f in files
+            )
+            assert shm.size == size
+            np.frombuffer(shm.buf, dtype=np.uint8)[:] = want
+            # Another process (the agent) attaches under no limit at all
+            # and sees one contiguous buffer.
+            other = SharedMemory(name)
+            assert other.size == size
+            np.testing.assert_array_equal(
+                np.frombuffer(other.buf, dtype=np.uint8), want
+            )
+            other.close()
+            # A smaller segment of the same name leaves no stale part.
+            shm.close()
+            with file_size_limit(1 << 20):
+                shm = SharedMemory(name, create=True, size=(1 << 20) + 7)
+            assert SharedMemory(name).size == (1 << 20) + 7
+        finally:
+            shm.unlink()
+        assert not [f for f in os.listdir("/dev/shm") if f.startswith(name)]
 
 
 class TestSharedObjects:
@@ -117,6 +157,52 @@ class TestStorage:
         assert st.listdir(str(tmp_path / "d")) == ["e"]
         st.safe_remove(str(tmp_path / "d"))
         assert not st.exists(str(tmp_path / "d"))
+
+
+    def test_file_above_the_file_size_limit_is_kept_as_parts(
+        self, tmp_path, file_size_limit
+    ):
+        st = PosixDiskStorage()
+        p = str(tmp_path / "shard_0.bin")
+        data = (np.arange(3 * (1 << 20) + 5) % 251).astype(np.uint8).tobytes()
+        mv = memoryview(data)
+        with file_size_limit(1 << 20):
+            # buffers that end on, straddle and skip part boundaries
+            st.write_chunks(
+                [mv[:100], mv[100:(1 << 20) + 50], mv[(1 << 20) + 50:]], p
+            )
+        names = ["shard_0.bin"] + [f"shard_0.bin.part{i}" for i in (1, 2, 3)]
+        assert sorted(os.listdir(tmp_path)) == names
+        assert all(
+            os.path.getsize(tmp_path / n) < (1 << 20) for n in names
+        )
+        # Readers need no limit and no record of the part size.
+        assert st.read_bytes(p) == data
+        lo = (1 << 20) - 5000
+        assert st.read_range(p, lo, 10000) == data[lo:lo + 10000]
+        with st.open_reader(p) as r:
+            assert r.size() == len(data)
+            into = bytearray(2 * (1 << 20))
+            assert r.read_into(1000, into) == len(into)
+            assert bytes(into) == data[1000:1000 + len(into)]
+            assert r.read(len(data) - 10, 100) == data[-10:]
+        # Out-of-order positional writes into a preallocated file.
+        with file_size_limit(1 << 20):
+            with st.open_writer(p, len(data)) as w:
+                w.write_at(2 << 20, mv[2 << 20:])
+                w.writev_at(0, [mv[:7], mv[7:2 << 20]])
+        assert st.read_bytes(p) == data
+        st.safe_rename(p, str(tmp_path / "moved.bin"))
+        assert st.read_bytes(str(tmp_path / "moved.bin")) == data
+        st.safe_rename(str(tmp_path / "moved.bin"), p)
+        # Rewritten under no limit it is one file again, no stale part.
+        st.write_bytes(data[:10], p)
+        assert os.listdir(tmp_path) == ["shard_0.bin"]
+        assert st.read_bytes(p) == data[:10]
+        with file_size_limit(1 << 20):
+            st.write_bytes(data, p)
+        st.safe_remove(p)
+        assert os.listdir(tmp_path) == []
 
 
 class TestRpc:

@@ -75,6 +75,41 @@ class TestStandaloneEngine:
             engine.close()
             SharedMemory.remove(ckpt_shm_name(job_name, 0, 0))
 
+    def test_roundtrip_under_a_file_size_limit(
+        self, job_name, tmp_path, file_size_limit
+    ):
+        """A state larger than RLIMIT_FSIZE snapshots, persists and
+        restores from memory and from storage (shm segment and shard
+        file kept as parts, ``common/fsutil.py``)."""
+        ckpt_dir = str(tmp_path / "ckpts")
+        state = make_state(3)
+        state["params"]["big"] = jnp.arange(
+            3 << 18, dtype=jnp.float32
+        ).reshape(3, -1)  # 3 MiB
+        template = dict(make_state(0), params=dict(
+            make_state(0)["params"], big=jnp.zeros_like(state["params"]["big"])
+        ))
+        engine = CheckpointEngine(ckpt_dir)
+        try:
+            with file_size_limit(1 << 20):
+                assert engine.save_to_storage(7, state)
+                step, restored = engine.load(template)
+            assert step == 7
+            assert engine.last_restore_stats["source"] == "memory"
+            assert_state_equal(restored, state)
+            step_dir = os.path.join(ckpt_dir, "checkpoint-7")
+            assert "shard_0.bin.part3" in os.listdir(step_dir)
+            SharedMemory.remove(ckpt_shm_name(job_name, 0, 0))
+            fresh = CheckpointEngine(ckpt_dir)
+            step, restored = fresh.load(template)
+            fresh.close()
+            assert step == 7
+            assert fresh.last_restore_stats["source"] == "storage"
+            assert_state_equal(restored, state)
+        finally:
+            engine.close()
+            SharedMemory.remove(ckpt_shm_name(job_name, 0, 0))
+
     def test_restore_phase_attribution(self, job_name, tmp_path):
         """VERDICT r4 #9: every load reports a read/assemble/device_put
         breakdown so slow restores are attributable (vs the reference's
@@ -294,7 +329,11 @@ class TestFlashCheckpointerAPI:
                     assert ok
                 if ok:
                     last_memory = s
-            assert ckpt.engine.wait_staged()
+            # Join only: the result is False when the async snapshot of
+            # step 3 was still in flight at step 4's DISK save, which
+            # supersedes it (seen once in a loaded tier-1 run). What must
+            # hold is the restored step below.
+            ckpt.engine.wait_staged()
             assert ckpt.wait_persisted(4, timeout=90.0)
             # The newest staged snapshot wins on restore.
             step, restored = FlashCheckpointer(saver_env).load_checkpoint(
