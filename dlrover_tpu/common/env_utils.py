@@ -174,8 +174,10 @@ SHM_DIR = ENV.path(
     "Backing directory for flash-checkpoint shared-memory segments.")
 TRACE_FILE = ENV.path(
     "DLROVER_TPU_TRACE_FILE", "",
-    "When set, the Tracer exports a Chrome trace here atomically at "
-    "exit (and on demand).")
+    "When set, the launcher/agent's Tracer exports a Chrome trace here "
+    "atomically at exit (and on demand), and each worker appends its "
+    "events, a step's at a time, to <path minus .json>"
+    ".worker<local_rank>.<restart_count>.jsonl.")
 GOODPUT_JSON = ENV.path(
     "DLROVER_TPU_GOODPUT_JSON", "",
     "When set, the master writes its goodput-ledger summary JSON here "

@@ -15,7 +15,8 @@ Usage::
 
     python -m dlrover_tpu.cli timeline --state-dir /tmp/job-state
     python -m dlrover_tpu.cli timeline --goodput-json GOODPUT_r04.json \
-        --trace /tmp/agent-trace.json --chrome-out merged.json
+        --trace /tmp/agent-trace.json \
+        --trace /tmp/agent-trace.worker0.0.jsonl --chrome-out merged.json
 """
 
 import argparse
@@ -28,6 +29,7 @@ from typing import List, Optional
 from dlrover_tpu.common import env_utils
 from dlrover_tpu.observability.events import JobEvent
 from dlrover_tpu.observability.goodput import GoodputLedger
+from dlrover_tpu.utils.tracing import read_events
 
 
 def load_events_from_state_dir(state_dir: str) -> List[JobEvent]:
@@ -150,8 +152,7 @@ def write_chrome_trace(events: List[JobEvent], trace_files: List[str],
     merged = to_chrome_trace(events)
     for path in trace_files:
         try:
-            with open(path) as f:
-                merged.extend(json.load(f).get("traceEvents", ()))
+            merged.extend(read_events(path))
         except Exception as e:
             print(f"skipping unreadable trace {path}: {e}",
                   file=sys.stderr)
@@ -171,7 +172,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--goodput-json", default="",
                    help="a goodput artifact (ObservabilityPlane dump)")
     p.add_argument("--trace", action="append", default=[],
-                   help="Chrome trace JSON to merge (repeatable)")
+                   help="trace file to merge: the agent's Chrome trace JSON "
+                   "or a worker's .jsonl (repeatable)")
     p.add_argument("--chrome-out", default="",
                    help="write the merged Chrome trace JSON here")
     p.add_argument("--no-text", action="store_true",

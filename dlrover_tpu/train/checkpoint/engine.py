@@ -58,6 +58,7 @@ from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.shared_memory import SharedMemory
 from dlrover_tpu.common.storage import CheckpointStorage, get_checkpoint_storage
 from dlrover_tpu.observability.events import EventKind, emit
+from dlrover_tpu.utils.tracing import get_tracer
 
 _ALIGN = 128  # bytes; keeps row-major copies cache-line aligned
 
@@ -294,7 +295,8 @@ class CheckpointEngine:
         )
 
     # ------------- staging -------------
-    def _snapshot(self, state, own: bool) -> Tuple[List[_Block], Dict]:
+    def _snapshot(self, state, own: bool,
+                  step: int = -1) -> Tuple[List[_Block], Dict]:
         """Decompose `state` into staged blocks (dispatch-only, no host sync).
 
         A GSPMD leaf contributes one block per unique addressable shard
@@ -306,6 +308,10 @@ class CheckpointEngine:
         ``donate_argnums`` training steps. ``own=False`` skips the copy for
         synchronous saves that fetch before returning.
         """
+        with get_tracer().span("ckpt.snapshot", step=step):
+            return self._snapshot_blocks(state, own)
+
+    def _snapshot_blocks(self, state, own: bool) -> Tuple[List[_Block], Dict]:
         import jax
 
         arrays, objects = _flatten_state(state)
@@ -368,13 +374,16 @@ class CheckpointEngine:
         """
         import jax
 
-        shardings = [
-            jax.sharding.SingleDeviceSharding(
-                list(a.devices())[0], memory_kind="pinned_host"
-            )
-            for a in arrs
-        ]
-        owned = jax.device_put(arrs, shardings)
+        with get_tracer().span(
+            "ckpt.own_copies", bytes=sum(int(a.nbytes) for a in arrs)
+        ):
+            shardings = [
+                jax.sharding.SingleDeviceSharding(
+                    list(a.devices())[0], memory_kind="pinned_host"
+                )
+                for a in arrs
+            ]
+            owned = jax.device_put(arrs, shardings)
         self.staging_memory_kind = owned[0].sharding.memory_kind
         return owned
 
@@ -393,7 +402,24 @@ class CheckpointEngine:
         the shared fastcopy pool so independent transfers overlap instead of
         riding one serialized ``device_get``; every staging emits a
         ``ckpt.io`` event with ``op="staging"`` so D2H throughput is
-        attributable per save."""
+        attributable per save (its time is the ``ckpt.fetch`` span's)."""
+        with get_tracer().span("ckpt.fetch", step=step) as span:
+            out, staged_bytes, chunks = self._fetch_blocks(blocks)
+            span.args.update(bytes=staged_bytes, chunks=chunks)
+        if staged_bytes:
+            wall = span.duration_s
+            emit(
+                EventKind.CKPT_IO, op="staging", step=step,
+                bytes=staged_bytes,
+                mbps=round(staged_bytes / max(wall, 1e-9) / 1e6, 1),
+                duration_s=round(wall, 4), chunks=chunks,
+            )
+        return out
+
+    def _fetch_blocks(
+        self, blocks: List[_Block]
+    ) -> Tuple[List[np.ndarray], int, int]:
+        """(host arrays, bytes fetched from device blocks, chunks)."""
         import jax
 
         device_idx = [
@@ -410,8 +436,6 @@ class CheckpointEngine:
                 cur, cur_bytes = [], 0
         if cur:
             groups.append(cur)
-
-        t0 = time.perf_counter()
 
         def _get(idxs: List[int]):
             return idxs, jax.device_get([blocks[i].handle for i in idxs])
@@ -431,15 +455,7 @@ class CheckpointEngine:
             if i in by_slot:
                 staged_bytes += host.nbytes
             b.handle = None  # free the device/host-space copy eagerly
-        if staged_bytes:
-            wall = time.perf_counter() - t0
-            emit(
-                EventKind.CKPT_IO, op="staging", step=step,
-                bytes=int(staged_bytes),
-                mbps=round(staged_bytes / max(wall, 1e-9) / 1e6, 1),
-                duration_s=round(wall, 4), chunks=len(groups),
-            )
-        return out
+        return out, int(staged_bytes), len(groups)
 
     def _layout(
         self, blocks: List[_Block], host_arrays: List[np.ndarray]
@@ -490,7 +506,7 @@ class CheckpointEngine:
         ``engine.py:272``). DISK saves pass ``block=True`` so a requested
         persist is never lost to brief lock contention."""
         gen = self._take_gen()
-        blocks, objects = self._snapshot(state, own=False)
+        blocks, objects = self._snapshot(state, own=False, step=step)
         host_arrays = self._fetch(blocks, step)
         return self._write_snapshot(
             step, blocks, host_arrays, objects, block, gen
@@ -515,15 +531,17 @@ class CheckpointEngine:
         ``ckpt.io`` event with ``op="staging-defer"``.
         """
         if self._staging is not None and not self._staging.done():
+            get_tracer().count("ckpt.skipped", reason="staging_in_flight")
             return False
         from dlrover_tpu.train.comms import get_governor
 
         governor = get_governor()
         if governor is not None and not governor.allow_staging(step):
+            get_tracer().count("ckpt.skipped", reason="governor")
             emit(EventKind.CKPT_IO, op="staging-defer", step=step, bytes=0)
             return False
         gen = self._take_gen()
-        blocks, objects = self._snapshot(state, own=True)
+        blocks, objects = self._snapshot(state, own=True, step=step)
         self._staging = self._stage_pool.submit(
             self._stage_async, step, blocks, objects, gen
         )
@@ -531,10 +549,12 @@ class CheckpointEngine:
 
     def _stage_async(self, step, blocks, objects, gen):
         try:
-            host_arrays = self._fetch(blocks, step)
-            ok = self._write_snapshot(
-                step, blocks, host_arrays, objects, True, gen
-            )
+            with get_tracer().span("ckpt.stage", step=step) as span:
+                host_arrays = self._fetch(blocks, step)
+                span.args["bytes"] = sum(int(a.nbytes) for a in host_arrays)
+                ok = self._write_snapshot(
+                    step, blocks, host_arrays, objects, True, gen
+                )
         except Exception:
             # The future is often never awaited — a silent raise here would
             # turn every crash-restore guarantee into a lie. Log loudly.
@@ -573,24 +593,25 @@ class CheckpointEngine:
                         block: bool, gen: Optional[int] = None) -> bool:
         if gen is None:
             gen = self._take_gen()
-        # Serialize buffer writers; a request that lost the race to a newer
-        # one is dropped instead of landing stale data over it.
-        with self._write_mutex:
-            if self._superseded(gen):
+        tracer = get_tracer()
+        with tracer.span("ckpt.lock_wait", step=step):
+            dropped = self._take_write_locks(gen, block)
+        if dropped:
+            tracer.count("ckpt.skipped", reason=dropped)
+            if dropped == "superseded":
                 logger.info(
                     "memory snapshot of step %s superseded; dropped", step
                 )
-                return False
-            if self._lock is not None and not self._lock.acquire(
-                blocking=block, timeout=30.0 if block else -1
-            ):
+            else:
                 logger.warning(
                     "skip memory save at step %s: saver holds the shard "
                     "lock", step,
                 )
-                return False
-            try:
+            return False
+        try:
+            with tracer.span("ckpt.shm_copy", step=step) as span:
                 metas, used = self._layout(blocks, host_arrays)
+                span.args["bytes"] = used
                 self._ensure_shm(used)
                 buf = self._shm.buf
                 pairs = []
@@ -601,7 +622,9 @@ class CheckpointEngine:
                     )
                     pairs.append((dst, fastcopy.as_bytes_view(arr)))
                 fastcopy.copy_many(pairs)
+            with tracer.span("ckpt.shm_flush", step=step):
                 self._shm.flush()
+            with tracer.span("ckpt.publish", step=step):
                 shard_meta = ShardMeta(
                     step=step,
                     shm_name=self._shm_name,
@@ -621,12 +644,35 @@ class CheckpointEngine:
                 )
                 self._publish_meta(shard_meta)
                 self._cached_step = step
-                with self._gen_lock:
-                    self._done_gen = max(self._done_gen, gen)
-                return True
-            finally:
-                if self._lock is not None:
-                    self._lock.release()
+            with self._gen_lock:
+                self._done_gen = max(self._done_gen, gen)
+            return True
+        finally:
+            if self._lock is not None:
+                self._lock.release()
+            self._write_mutex.release()
+
+    def _take_write_locks(self, gen: int, block: bool) -> str:
+        """Serialize buffer writers: take the write mutex, then the shard
+        lock shared with the agent's saver. Returns "" with both held, or
+        — holding neither — why the snapshot is dropped: ``superseded`` (it
+        lost the race to a newer request and must not land stale data
+        over it) or ``lock`` (the saver is persisting this buffer)."""
+        self._write_mutex.acquire()
+        try:
+            if self._superseded(gen):
+                dropped = "superseded"
+            elif self._lock is not None and not self._lock.acquire(
+                blocking=block, timeout=30.0 if block else -1
+            ):
+                dropped = "lock"
+            else:
+                return ""
+        except BaseException:
+            self._write_mutex.release()
+            raise
+        self._write_mutex.release()
+        return dropped
 
     def _publish_meta(self, shard_meta: ShardMeta):
         raw = pickle.dumps(shard_meta)

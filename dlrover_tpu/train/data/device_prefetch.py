@@ -31,6 +31,7 @@ import collections
 from typing import Any, Iterable, Iterator, Optional
 
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.utils.tracing import get_tracer
 
 
 class DevicePrefetchIterator:
@@ -63,13 +64,22 @@ class DevicePrefetchIterator:
 
     def _fill(self):
         """Dispatch transfers until ``depth`` batches are in flight."""
+        import jax
+
+        tracer = get_tracer()
         while not self._exhausted and len(self._buf) < self.depth:
             try:
-                host = next(self._it)
+                with tracer.span("input.host_next"):
+                    host = next(self._it)
             except StopIteration:
                 self._exhausted = True
                 return
-            self._buf.append(self._put(host))
+            nbytes = sum(
+                getattr(leaf, "nbytes", 0)
+                for leaf in jax.tree_util.tree_leaves(host)
+            )
+            with tracer.span("input.device_put", bytes=nbytes):
+                self._buf.append(self._put(host))
 
     # ------------- iterator protocol -------------
     def __iter__(self) -> "DevicePrefetchIterator":

@@ -35,6 +35,7 @@ from dlrover_tpu.chaos.sites import ChaosSite
 from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.observability.events import EventKind, emit
+from dlrover_tpu.utils.tracing import get_tracer
 
 
 class TrainerCallback:
@@ -170,8 +171,9 @@ class Trainer:
             every=env_utils.PROGRESS_EVERY.get()
         )
         # Per-step phase breakdown (host-input / compute / collective /
-        # readback) feeding the master's straggler detector. Pure
-        # perf_counter bookkeeping around fences the loop takes anyway —
+        # readback) feeding the master's straggler detector, from the
+        # durations of the loop's own spans (trainer.input, .dispatch,
+        # .fence, .readback) around fences the loop takes anyway —
         # never an extra sync on the run-ahead step.
         self._phases = None
         if env_utils.STRAGGLER_PHASES.get():
@@ -361,171 +363,181 @@ class Trainer:
         done = start
         self.should_stop = False  # a previous fit's stop must not leak
         self._fire("on_train_begin", start)
+        tracer = get_tracer()
         t_mark = time.perf_counter()
         for step in range(start, steps):
-            if rescale_engine is not None:
-                transition = rescale_engine.maybe_rescale(
-                    self.state, prefetch=it if pipeline else None
+            # One span an iteration, parent of the rest (SPANS in
+            # utils/tracing.py has the table); each carries the number
+            # of the step it computes, as reports and snapshots do.
+            with tracer.span("trainer.step", step=step + 1):
+                if rescale_engine is not None:
+                    transition = rescale_engine.maybe_rescale(
+                        self.state, prefetch=it if pipeline else None
+                    )
+                    if transition is not None and transition.ok:
+                        # Adopt the new world: transferred state, rebuilt
+                        # step/shardings; the eval step is lazily rebuilt.
+                        self.state = transition.state
+                        self._result = transition.result
+                        self._eval_step = None
+                        if not pipeline and transition.batches is not None:
+                            it = iter(transition.batches)
+                try:
+                    with tracer.span("trainer.input") as input_span:
+                        batch = next(it)
+                except StopIteration:
+                    logger.info("data exhausted at step %s", step)
+                    break
+                ctx = (
+                    self._profiler.step() if self._profiler is not None
+                    else contextlib.nullcontext()
                 )
-                if transition is not None and transition.ok:
-                    # Adopt the new world: transferred state, rebuilt
-                    # step/shardings; the eval step is lazily rebuilt.
-                    self.state = transition.state
-                    self._result = transition.result
-                    self._eval_step = None
-                    if not pipeline and transition.batches is not None:
-                        it = iter(transition.batches)
-            t_in0 = time.perf_counter()
-            try:
-                batch = next(it)
-            except StopIteration:
-                logger.info("data exhausted at step %s", step)
-                break
-            ctx = (
-                self._profiler.step() if self._profiler is not None
-                else contextlib.nullcontext()
-            )
-            t_step0 = time.perf_counter()
-            input_s = t_step0 - t_in0
-            chaos = fault_hit(ChaosSite.TRAINER_STEP, detail=str(step))
-            if chaos is not None and chaos.kind in ("straggle", "delay"):
-                # Scripted straggler: the sleep lands inside the step's
-                # wall time (after t_step0), so the slowdown is visible
-                # to the same step-rate reporting the master's speed
-                # monitor reads.
-                time.sleep(chaos.delay_s)  # dtlint: disable=DT003 -- scripted chaos straggle, not a poll
-            with ctx:
-                if not pipeline:
-                    batch = jax.device_put(batch, self.batch_sharding)
-                self.state, metrics = self.train_step(self.state, batch)
-                if self._profiler is not None:
-                    # Honored only when the profiler runs in sync mode;
-                    # otherwise it records async-dispatch time and says so.
-                    self._profiler.fence(metrics["loss"])
-            # Host dispatch segment: chaos straggle sleep + device_put +
-            # the jitted step's (async) dispatch. An injected host-side
-            # straggle lands here, never in the collective estimate.
-            t_disp1 = time.perf_counter()
-            dispatch_s = t_disp1 - t_step0
-            done = step + 1
-            if self._ckpt is not None:
-                if self._persist_every and done % self._persist_every == 0:
+                # Host dispatch segment: chaos straggle sleep + device_put
+                # + the jitted step's (async) dispatch. An injected
+                # host-side straggle lands here, never in the collective
+                # estimate.
+                with tracer.span("trainer.dispatch") as dispatch:
+                    chaos = fault_hit(
+                        ChaosSite.TRAINER_STEP, detail=str(step)
+                    )
+                    if chaos is not None and chaos.kind in (
+                        "straggle", "delay"
+                    ):
+                        # Scripted straggler: the sleep lands inside the
+                        # step's wall time, so the slowdown is visible to
+                        # the same step-rate reporting the master's speed
+                        # monitor reads.
+                        time.sleep(chaos.delay_s)  # dtlint: disable=DT003 -- scripted chaos straggle, not a poll
+                    with ctx:
+                        if not pipeline:
+                            batch = jax.device_put(
+                                batch, self.batch_sharding
+                            )
+                        self.state, metrics = self.train_step(
+                            self.state, batch
+                        )
+                        if self._profiler is not None:
+                            # Honored only when the profiler runs in sync
+                            # mode; otherwise it records async-dispatch
+                            # time and says so.
+                            self._profiler.fence(metrics["loss"])
+                done = step + 1
+                if self._ckpt is not None:
                     # DISK persist: an explicit boundary — the engine
                     # fetches the (dispatched) state; the runtime orders
                     # those reads after the step that produced it.
-                    self._ckpt.save_checkpoint(
-                        done, self.state, StorageType.DISK
-                    )
-                    self._fire("on_save", done, "disk")
-                else:
                     # MEMORY snapshot: dispatch-only (~ms). The engine
                     # device_puts engine-owned copies of the new state
                     # *before* this thread dispatches step N+1, so a
                     # later donated step can never invalidate the
                     # snapshot even with the loop running ahead.
-                    self._ckpt.save_checkpoint(
-                        done, self.state, StorageType.MEMORY
+                    disk = bool(
+                        self._persist_every
+                        and done % self._persist_every == 0
                     )
-            if self._report:
-                if self._client is not None and dtrain.global_rank() == 0:
-                    try:
-                        self._client.report_global_step(done, time.time())
-                    except Exception:
-                        # Step reporting is best-effort but a broken
-                        # link should be visible once per occurrence.
-                        logger.debug("step report failed", exc_info=True)
-                    self._progress.note(done)
-                report_training_metrics(done)
-            last_loss = metrics["loss"]
-            phases = None
-            governed = False
-            if pipeline:
-                # Lag-1 fence: block on step N-1 (already finished or
-                # finishing while step N runs), never on step N. This
-                # paces the host to the device rate, which also makes
-                # the inter-fence wall time an honest step time. Under a
-                # saturated link the governor skips the fence AND the
-                # readback for the step (bounded by its defer cap): the
-                # device queue runs ahead instead of draining its D2H
-                # through a congested transfer; the pending slot is
-                # picked up by the next un-governed step's push.
-                governed = (
-                    governor is not None
-                    and not governor.allow_readback(done)
-                )
-                if self._phases is not None:
-                    # Split the lag-1 wait into the device fence (block
+                    with tracer.span("trainer.save"):
+                        self._ckpt.save_checkpoint(
+                            done, self.state,
+                            StorageType.DISK if disk else StorageType.MEMORY,
+                        )
+                    if disk:
+                        self._fire("on_save", done, "disk")
+                if self._report:
+                    with tracer.span("trainer.report"):
+                        if (
+                            self._client is not None
+                            and dtrain.global_rank() == 0
+                        ):
+                            try:
+                                self._client.report_global_step(
+                                    done, time.time()
+                                )
+                            except Exception:
+                                # Step reporting is best-effort but a
+                                # broken link should be visible once per
+                                # occurrence.
+                                logger.debug(
+                                    "step report failed", exc_info=True
+                                )
+                            self._progress.note(done)
+                        report_training_metrics(done)
+                last_loss = metrics["loss"]
+                governed = False
+                if pipeline:
+                    # Lag-1 fence: block on step N-1 (already finished or
+                    # finishing while step N runs), never on step N. This
+                    # paces the host to the device rate, which also makes
+                    # the inter-fence wall time an honest step time. Under
+                    # a saturated link the governor skips the fence AND
+                    # the readback for the step (bounded by its defer
+                    # cap): the device queue runs ahead instead of
+                    # draining its D2H through a congested transfer; the
+                    # pending slot is picked up by the next un-governed
+                    # step's push.
+                    governed = (
+                        governor is not None
+                        and not governor.allow_readback(done)
+                    )
+                    # The lag-1 wait, split into the device fence (block
                     # until step N-1's metrics exist) and the host
                     # readback (D2H transfer + float conversion) — the
                     # readback is exactly what a degraded D2H link
                     # inflates. Still lag-1: never a sync on step N.
-                    t_f0 = time.perf_counter()
-                    if not governed:
-                        deferred.fence()
-                    t_f1 = time.perf_counter()
-                    prev = (
-                        None if governed
-                        else deferred.push(done, {"loss": last_loss})
-                    )
-                    t_f2 = time.perf_counter()
-                    phases = self._phases.split(
-                        input_s, dispatch_s, t_f1 - t_f0, t_f2 - t_f1
-                    )
-                elif governed:
-                    prev = None
+                    with tracer.span("trainer.fence") as fence:
+                        if not governed:
+                            deferred.fence()
+                    with tracer.span("trainer.readback") as readback:
+                        prev = (
+                            None if governed
+                            else deferred.push(done, {"loss": last_loss})
+                        )
+                    now = time.perf_counter()
+                    step_metrics = {
+                        "loss": last_loss,  # device array: sync if read
+                        "loss_lag1": prev[1]["loss"] if prev else None,
+                        "step_time_s": now - t_mark,
+                    }
+                    t_mark = now
                 else:
-                    prev = deferred.push(done, {"loss": last_loss})
-                now = time.perf_counter()
-                step_metrics = {
-                    "loss": last_loss,  # device array: sync if read
-                    "loss_lag1": prev[1]["loss"] if prev else None,
-                    "step_time_s": now - t_mark,
-                }
-                t_mark = now
-            else:
+                    with tracer.span("trainer.fence") as fence:
+                        jax.block_until_ready(last_loss)
+                    with tracer.span("trainer.readback") as readback:
+                        loss_host = float(last_loss)
+                    step_metrics = {
+                        "loss": loss_host,
+                        "step_time_s": time.perf_counter() - dispatch.start,
+                    }
                 if self._phases is not None:
-                    t_f0 = time.perf_counter()
-                    jax.block_until_ready(last_loss)
-                    t_f1 = time.perf_counter()
-                    loss_host = float(last_loss)
-                    t_f2 = time.perf_counter()
                     phases = self._phases.split(
-                        input_s, dispatch_s, t_f1 - t_f0, t_f2 - t_f1
+                        input_span.duration_s, dispatch.duration_s,
+                        fence.duration_s, readback.duration_s,
                     )
-                else:
-                    loss_host = float(last_loss)
-                step_metrics = {
-                    "loss": loss_host,
-                    "step_time_s": time.perf_counter() - t_step0,
-                }
-            if (
-                phases is not None and self._report
-                and done % self._phase_every == 0
-            ):
-                emit(
-                    EventKind.STEP_PHASES, step=done,
-                    step_s=step_metrics["step_time_s"],
-                    **({"governed": True} if governed else {}),
-                    **phases,
-                )
-            tokens = batch_token_count(batch)
-            if tokens:
-                step_metrics["tokens_per_s"] = (
-                    tokens / step_metrics["step_time_s"]
-                )
-            if self._lr_schedule is not None:
-                step_metrics["lr"] = float(self._lr_schedule(done))
-            self._fire("on_step_end", done, step_metrics)
-            if (eval_batches is not None and eval_every
-                    and done % eval_every == 0):
-                last_eval = self.evaluate(
-                    eval_batches(), max_batches=eval_max_batches
-                )
-                evaluated_at = done
-                self._fire("on_evaluate", done, last_eval)
-            if self.should_stop:
-                logger.info("callback requested stop at step %s", done)
-                break
+                    if self._report and done % self._phase_every == 0:
+                        emit(
+                            EventKind.STEP_PHASES, step=done,
+                            step_s=step_metrics["step_time_s"],
+                            **({"governed": True} if governed else {}),
+                            **phases,
+                        )
+                tokens = batch_token_count(batch)
+                if tokens:
+                    step_metrics["tokens_per_s"] = (
+                        tokens / step_metrics["step_time_s"]
+                    )
+                if self._lr_schedule is not None:
+                    step_metrics["lr"] = float(self._lr_schedule(done))
+                with tracer.span("trainer.callbacks"):
+                    self._fire("on_step_end", done, step_metrics)
+                if (eval_batches is not None and eval_every
+                        and done % eval_every == 0):
+                    last_eval = self.evaluate(
+                        eval_batches(), max_batches=eval_max_batches
+                    )
+                    evaluated_at = done
+                    self._fire("on_evaluate", done, last_eval)
+                if self.should_stop:
+                    logger.info("callback requested stop at step %s", done)
+                    break
         deferred.flush()  # drain the lag-1 slot before the boundary work
         self._progress.flush(done if done > start else None)
         if eval_batches is not None and evaluated_at != done:
