@@ -16,9 +16,18 @@ Family-defining pieces, implemented TPU-first:
 - grouped-query attention: ``num_kv_heads <= num_heads`` with kv heads
   repeated to query heads before the kernel (static-shape repeat — the
   MXU sees full-width matmuls; HBM holds only the small kv projection).
+
+The block's mixer is chosen by the configuration: ``mixer="full"`` is
+causal softmax attention over every earlier key; ``mixer="eva"`` is
+window-local causal attention joined in one softmax with learned chunk
+summaries of the earlier windows (``ops/eva.py``). The variants an
+architecture may also ask for — RMSNorm with a unit offset, a float32
+residual stream, several next-token heads with a float32 output — are
+fields whose defaults leave the plain LLaMA program as it was.
 """
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -33,7 +42,8 @@ from dlrover_tpu.models.gpt import (  # shared kernel + remat paths
     moe_loss_fn,
 )
 
-__all__ = ["LlamaConfig", "Llama", "loss_fn", "moe_loss_fn"]
+__all__ = ["LlamaConfig", "Llama", "loss_fn", "moe_loss_fn",
+           "multibyte_loss_fn"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +80,31 @@ class LlamaConfig:
     pipeline_stages: int = 0
     pipeline_microbatches: int = 0  # 0 -> = pipeline_stages
     pipeline_repeats: int = 1
+    # The block's mixer. "full": causal attention over all earlier keys.
+    # "eva": causal attention inside aligned windows of ``attn_window``
+    # positions, in one softmax with one learned summary per
+    # ``attn_chunk`` positions of every earlier window (ops/eva.py);
+    # sequences must be whole windows.
+    mixer: str = "full"
+    attn_window: int = 0
+    attn_chunk: int = 0
+    # RMSNorm as x * rsqrt(mean(x^2) + eps) * (1 + g), g stored (zeros
+    # at init), instead of a stored scale (ones at init).
+    norm_unit_offset: bool = False
+    # Keep the residual stream in float32 between blocks (the branches
+    # still compute in ``dtype``).
+    fp32_residual: bool = False
+    # Next-token heads: head m at position t predicts token t + 1 + m;
+    # the output is [B, S, pred_heads * vocab] (see multibyte_loss_fn).
+    pred_heads: int = 1
+    fp32_logits: bool = False  # the head's matmul accumulates and gives f32
+    # Standard deviation every matrix, the embedding and the summaries'
+    # vectors are drawn with. Not a free choice of the caller's: where an
+    # architecture publishes its own (EvaByte: 0.01275) the first steps
+    # and the comparison with the plain reference read differently at
+    # the default (PERF.md section 6, PR 30: first loss 6.54 against
+    # 6.03, gradient 1 - cosine 1.7e-5 against 1.1e-5).
+    init_std: float = 0.02
 
     def __post_init__(self):
         if self.kv_heads > self.num_heads or self.num_heads % self.kv_heads:
@@ -77,6 +112,20 @@ class LlamaConfig:
                 f"num_kv_heads {self.kv_heads} must divide num_heads "
                 f"{self.num_heads}"
             )
+        if self.mixer not in ("full", "eva"):
+            raise ValueError(f"unknown mixer {self.mixer!r}")
+        if self.mixer == "eva":
+            if not (self.attn_chunk > 0 and self.attn_window > 0
+                    and self.attn_window % self.attn_chunk == 0):
+                raise ValueError(
+                    f"the eva mixer needs a window ({self.attn_window}) "
+                    f"that is a multiple of its chunk ({self.attn_chunk})"
+                )
+            if self.attn_impl not in ("xla", "pallas"):
+                raise ValueError(
+                    "the eva mixer runs under attn_impl xla or pallas, "
+                    f"not {self.attn_impl!r}"
+                )
         if self.pipeline_stages > 1:
             chunks = self.pipeline_stages * max(self.pipeline_repeats, 1)
             if self.num_layers % chunks:
@@ -101,18 +150,39 @@ class LlamaConfig:
         return self.d_model // self.num_heads
 
     def param_count(self) -> int:
-        d, f, v, l = self.d_model, self.ff_dim, self.vocab_size, self.num_layers
+        d, f, l = self.d_model, self.ff_dim, self.num_layers
         kv = self.kv_heads * self.head_dim
         per_layer = d * d + 2 * d * kv + d * d + 3 * d * f + 2 * d
-        return 2 * v * d + l * per_layer + d
+        if self.mixer == "eva":     # the summaries' two vectors a head
+            per_layer += 2 * self.num_heads * self.head_dim
+        return self.vocab_param_count() + l * per_layer + d
 
     def vocab_param_count(self) -> int:
-        """Embedding + *untied* LM head (LLaMA convention): the params
-        outside the layer stack for the pipeline cost model."""
-        return 2 * self.vocab_size * self.d_model
+        """Embedding + *untied* LM head (LLaMA convention), one head's
+        width for each next-token head: the params outside the layer
+        stack for the pipeline cost model."""
+        return (1 + self.pred_heads) * self.vocab_size * self.d_model
+
+    def attention_pairs(self) -> int:
+        """Query-key pairs of one head over ``max_seq_len`` positions, as
+        ``flops_per_token`` counts them: the whole square for the full
+        mixer (twice what its causal mask allows — the count the cost
+        models were calibrated on, kept), the allowed pairs exactly for
+        the eva mixer (local windows and the summaries seen)."""
+        s = self.max_seq_len
+        if self.mixer != "eva":
+            return s * s
+        from dlrover_tpu.ops.eva import eva_mask
+
+        mask = eva_mask(s, self.attn_window, self.attn_chunk)
+        return mask.pairs(s, s + mask.summaries)
 
     def flops_per_token(self) -> float:
-        attn = 12 * self.num_layers * self.d_model * self.max_seq_len
+        """Approx training FLOPs/token: 6 a parameter plus attention, 12
+        a pair and unit of model width (QK^T and PV, forward and twice
+        backward); which pairs, see ``attention_pairs``."""
+        pairs_per_token = self.attention_pairs() / self.max_seq_len
+        attn = 12 * self.num_layers * self.d_model * pairs_per_token
         return 6 * self.param_count() + attn
 
     @staticmethod
@@ -121,7 +191,32 @@ class LlamaConfig:
                            num_heads=4, num_kv_heads=2, d_model=32)
 
 
+class _UnitOffsetRMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + scale)``: the stored parameter
+    is the offset from one. Statistics in float32, result in ``dtype``."""
+
+    cfg: "LlamaConfig"
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        scale = self.param(
+            "scale",
+            nn.with_logical_partitioning(
+                nn.initializers.zeros_init(), ("embed",)
+            ),
+            (x.shape[-1],), cfg.param_dtype,
+        )
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + 1e-5
+        )
+        return (y * (1.0 + scale.astype(jnp.float32))).astype(cfg.dtype)
+
+
 def _rms_norm(name: str, cfg: LlamaConfig):
+    if cfg.norm_unit_offset:
+        return _UnitOffsetRMSNorm(cfg, name=name)
     return nn.RMSNorm(
         epsilon=1e-5,
         dtype=cfg.dtype,
@@ -134,9 +229,9 @@ def _rms_norm(name: str, cfg: LlamaConfig):
 
 
 def _dense(features, name, kernel_axes, cfg: LlamaConfig,
-           quant: bool = False):
+           quant: bool = False, **kwargs):
     kernel_init = nn.with_logical_partitioning(
-        nn.initializers.normal(0.02), kernel_axes
+        nn.initializers.normal(cfg.init_std), kernel_axes
     )
     if quant and cfg.mlp_precision == "int8":
         from dlrover_tpu.ops.quantized import Int8Dense
@@ -153,6 +248,7 @@ def _dense(features, name, kernel_axes, cfg: LlamaConfig,
         param_dtype=cfg.param_dtype,
         kernel_init=kernel_init,
         name=name,
+        **kwargs,
     )
 
 
@@ -200,7 +296,7 @@ class LlamaBlock(nn.Module):
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
-        attn = _attention(q, k, v, cfg).reshape(b, s, d)
+        attn = self._mix(q, k, v).reshape(b, s, d)
         from jax.ad_checkpoint import checkpoint_name
         attn = checkpoint_name(attn, "attn_out")
         x = x + _dense(d, "o_proj", ("heads", "embed"), cfg)(attn)
@@ -233,6 +329,29 @@ class LlamaBlock(nn.Module):
                        quant=True)(y)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         return x, None
+
+    def _mix(self, q, k, v):
+        """The block's mixer over rotated q, k and v ``[B, S, H, D]``."""
+        cfg = self.cfg
+        if cfg.mixer != "eva":
+            return _attention(q, k, v, cfg)
+        from dlrover_tpu.ops.eva import eva_attention
+
+        phi, mu = (
+            self.param(
+                name,
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(cfg.init_std), ("heads", "kv")
+                ),
+                (cfg.num_heads, cfg.head_dim), cfg.param_dtype,
+            )
+            for name in ("summary_phi", "summary_mu")
+        )
+        return eva_attention(
+            q, k, v, phi, mu, window=cfg.attn_window, chunk=cfg.attn_chunk,
+            impl=cfg.attn_impl, block_q=cfg.attn_block_q,
+            block_k=cfg.attn_block_k,
+        )
 
 
 class _LlamaStage(nn.Module):
@@ -286,11 +405,14 @@ class Llama(nn.Module):
             cfg.vocab_size, cfg.d_model,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             embedding_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("vocab", "embed")
+                nn.initializers.normal(cfg.init_std), ("vocab", "embed")
             ),
             name="embed",
         )
         x = embed(tokens)
+        if cfg.fp32_residual:
+            # Each block adds its branches (in ``dtype``) onto this.
+            x = x.astype(jnp.float32)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         if cfg.pipeline_stages > 1:
@@ -320,13 +442,7 @@ class Llama(nn.Module):
                 x, aux_total = out
             else:
                 x = out
-            x = _rms_norm("final_norm", cfg)(x)
-            logits = _dense(
-                cfg.vocab_size, "lm_head", ("embed", "vocab"), cfg
-            )(x)
-            logits = nn.with_logical_constraint(
-                logits, ("batch", "seq", "vocab")
-            )
+            logits = self._head(x)
             if cfg.num_experts > 0:
                 return logits, aux_total
             return logits
@@ -353,14 +469,45 @@ class Llama(nn.Module):
                     auxes.append(aux)
             aux_total = jnp.mean(jnp.stack(auxes)) if auxes else None
 
-        x = _rms_norm("final_norm", cfg)(x)
-        # Untied LM head (LLaMA convention).
-        logits = _dense(
-            cfg.vocab_size, "lm_head", ("embed", "vocab"), cfg
-        )(x)
-        logits = nn.with_logical_constraint(
-            logits, ("batch", "seq", "vocab")
-        )
+        logits = self._head(x)
         if cfg.num_experts > 0:
             return logits, aux_total
         return logits
+
+    def _head(self, x):
+        """Final norm and the untied LM head (LLaMA convention):
+        ``[B, S, pred_heads * vocab]``, head m in columns ``[m * vocab,
+        (m + 1) * vocab)``."""
+        cfg = self.cfg
+        x = _rms_norm("final_norm", cfg)(x)
+        more = {}
+        if cfg.fp32_logits:
+            # Inputs in ``dtype`` at the MXU's full rate; the sums and
+            # the result in float32.
+            more["dot_general"] = functools.partial(
+                jax.lax.dot_general, preferred_element_type=jnp.float32
+            )
+        logits = _dense(
+            cfg.pred_heads * cfg.vocab_size, "lm_head", ("embed", "vocab"),
+            cfg, **more,
+        )(x)
+        return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
+
+
+def multibyte_loss_fn(logits, tokens, pred_heads: int):
+    """Cross entropy of ``pred_heads`` next-token heads: ``logits``
+    ``[B, S, pred_heads * V]``, head m at position t predicts token ``t +
+    1 + m``. The mean over the heads of each head's mean over the
+    positions that have a target (``S - 1 - m`` of them). With one head
+    this is ``loss_fn``."""
+    b, s, _ = logits.shape
+    logits = logits.reshape(b, s, pred_heads, -1).astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)      # [B, S, M]
+    ahead = np.arange(s)[:, None] + 1 + np.arange(pred_heads)[None, :]
+    valid = ahead < s                                       # [S, M]
+    targets = jnp.asarray(tokens)[:, np.minimum(ahead, s - 1)]  # [B, S, M]
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    per_head = jnp.sum(
+        jnp.where(valid, lse - tgt, 0.0), axis=(0, 1)
+    ) / (b * valid.sum(axis=0))
+    return jnp.mean(per_head)

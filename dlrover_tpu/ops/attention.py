@@ -12,6 +12,14 @@ walks (bh, q_block, kv_block) with the kv dimension innermost so the f32
 accumulators live in VMEM scratch across kv steps (TPU grids execute
 sequentially — the canonical Pallas accumulation pattern).
 
+What a query may see is a static :class:`AttentionMask`, from which the
+block-skip rule and the in-block mask are both derived. The plain causal
+mask walks the rectangular grid and skips the blocks above the diagonal;
+a windowed mask (aligned causal windows, optionally joined in the same
+softmax with leading rows of chunk summaries, ``docs/attention_masks.md``)
+walks a list of the non-empty blocks only, handed to the kernel as
+prefetched scalars.
+
 Mosaic kernels cannot be partitioned by GSPMD, so over a mesh of more than
 one device the public entry point wraps the kernel in ``shard_map`` (batch
 over ``data``/``fsdp``, heads over ``tensor``); models can enable
@@ -19,6 +27,7 @@ over ``data``/``fsdp``, heads over ``tensor``); models can enable
 the CPU tests only (``dlrover_tpu.ops.interpret``).
 """
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -31,22 +40,143 @@ from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.ops import interpret as interpret_mode
 from dlrover_tpu.ops.ring_attention import _ambient_mesh
+from dlrover_tpu.utils.tracing import get_tracer
 
 _NEG_INF = -1e30
 _LANES = 128  # scratch rows are padded to a full lane tile
 
 
-def reference_attention(q, k, v, causal: bool = True):
+@dataclasses.dataclass(frozen=True)
+class AttentionMask:
+    """Which keys a query sees. Static and hashable: the kernels' block
+    skipping and in-block mask, the dense oracle and the pair counts are
+    all derived from this one description.
+
+    - ``causal`` alone: query ``i`` sees keys ``j <= i`` (aligned at the
+      end where there are more keys than queries).
+    - ``window`` > 0 (causal): query ``i`` sees ``j <= i`` of its own
+      aligned window ``i // window`` only.
+    - ``summaries`` > 0 (with ``window`` and ``chunk``): the first
+      ``summaries`` rows of k/v are no positions but one summary per
+      ``chunk`` positions, in order (rows past ``positions / chunk`` are
+      padding, seen by no query); position ``j`` is row ``summaries +
+      j``. Query ``i`` sees the summaries of every chunk of every earlier
+      window and none of its own. Both sets share one softmax.
+    """
+
+    causal: bool = True
+    window: int = 0
+    summaries: int = 0
+    chunk: int = 0
+
+    def __post_init__(self):
+        if self.window and not self.causal:
+            raise ValueError("a windowed mask is causal inside its windows")
+        if self.summaries and not (
+            self.window and self.chunk and self.window % self.chunk == 0
+        ):
+            raise ValueError(
+                f"summary rows need a window ({self.window}) that is a "
+                f"multiple of their chunk ({self.chunk})"
+            )
+
+    def bounds(self, rows, s_q: int, s_k: int):
+        """``(lo, hi, n)``: query ``rows`` sees the key rows ``lo <= col
+        <= hi`` (positions) and ``col < n`` (summaries). Works alike on
+        numpy and on traced integers."""
+        if not self.window:
+            return 0 * rows, rows + (s_k - s_q), 0 * rows
+        first = rows // self.window
+        per_window = self.window // self.chunk if self.summaries else 0
+        return (first * self.window + self.summaries, rows + self.summaries,
+                first * per_window)
+
+    def dense(self, s_q: int, s_k: int):
+        """The whole mask ``[s_q, s_k]`` as booleans (the oracle's)."""
+        if not self.causal:
+            return jnp.ones((s_q, s_k), dtype=bool)
+        rows, cols = jnp.arange(s_q)[:, None], jnp.arange(s_k)[None, :]
+        lo, hi, n = self.bounds(rows, s_q, s_k)
+        return ((cols >= lo) & (cols <= hi)) | (cols < n)
+
+    def in_block(self, qi, ki, block_q: int, block_k: int):
+        """The mask of block ``(qi, ki)`` inside a kernel. A windowed
+        mask's block lies whole among the summaries or whole among the
+        positions (``block_k`` divides ``summaries``), so one pair of
+        bounds a row decides it."""
+        if not self.window:
+            rows = jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            ) + qi * block_q
+            cols = jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            ) + ki * block_k
+            return rows >= cols
+        rows = jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0
+        ) + qi * block_q
+        cols = jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1
+        ) + ki * block_k
+        lo, hi, n = self.bounds(rows, 0, 0)
+        if self.summaries:
+            among = ki * block_k < self.summaries
+            lo, hi = jnp.where(among, 0, lo), jnp.where(among, n - 1, hi)
+        return (cols >= lo) & (cols <= hi)
+
+    def live_blocks(self, s_q: int, s_k: int, block_q: int, block_k: int):
+        """``[nq, nk]`` numpy booleans: the blocks in which some query
+        sees some key. The kernels run exactly these."""
+        nq, nk = s_q // block_q, s_k // block_k
+        if not self.causal:
+            return np.ones((nq, nk), dtype=bool)
+        lo, hi, n = self.bounds(
+            np.arange(s_q).reshape(nq, block_q), s_q, s_k
+        )
+        first = np.arange(nk)[None, :] * block_k       # a block's first row
+        positions = (first <= hi.max(1)[:, None]) & (
+            first + block_k - 1 >= lo.min(1)[:, None]
+        )
+        return positions | (first < n.max(1)[:, None])
+
+    def pairs(self, s_q: int, s_k: int) -> int:
+        """Query-key pairs the mask allows (one head of one sequence)."""
+        if not self.causal:
+            return s_q * s_k
+        lo, hi, n = self.bounds(np.arange(s_q, dtype=np.int64), s_q, s_k)
+        return int(np.sum(np.maximum(hi - lo + 1, 0) + n))
+
+
+def _as_mask(causal: Optional[bool],
+             mask: Optional[AttentionMask]) -> AttentionMask:
+    """The one description a public call works with: ``mask``, or the
+    plain mask that the bool ``causal`` names (causal where neither is
+    given). Both at once say one thing twice, or two things: refused."""
+    if mask is None:
+        return AttentionMask(causal=causal is None or bool(causal))
+    if causal is not None:
+        raise ValueError(
+            f"give causal={causal!r} or mask={mask!r}, not both: the "
+            "mask says whether it is causal"
+        )
+    return mask
+
+
+def reference_attention(q, k, v, causal: Optional[bool] = None,
+                        mask: Optional[AttentionMask] = None):
     """Einsum softmax attention — the numerics oracle for the kernels.
 
-    q, k, v: [B, S, H, D]; returns [B, S, H, D].
+    q, k, v: [B, S, H, D]; returns [B, S, H, D]. What a query sees is
+    ``mask`` (an :class:`AttentionMask`) or the bool ``causal`` (default:
+    causal), never both.
     """
+    mask = _as_mask(causal, mask)
     scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
-        s_q, s_k = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), s_k - s_q)
-        logits = jnp.where(mask, logits, _NEG_INF)
+    if mask.causal:
+        logits = jnp.where(
+            mask.dense(q.shape[1], k.shape[1]), logits, _NEG_INF
+        )
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
 
@@ -59,26 +189,170 @@ def _pick_block(seq: int, want: int) -> int:
     return max(b, 1)
 
 
+def pick_blocks(mask: AttentionMask, s_q: int, s_k: int,
+                block_q: int, block_k: int) -> tuple:
+    """The blocks a call runs with. A block that does not divide the
+    sequence is halved until it does; one that the mask needs whole
+    inside a window is refused instead (a block that divides the window
+    and the key rows divides the summary rows too: a key block lies whole
+    among the summaries or whole among the positions)."""
+    block_q, block_k = _pick_block(s_q, block_q), _pick_block(s_k, block_k)
+    if mask.causal and not mask.window and s_q != s_k:
+        # The kernels' causal rule, in a block and between blocks, counts
+        # rows and columns from the start; the description (`bounds`: the
+        # oracle, `live_blocks`, the pair counts) aligns them at the end.
+        # They are one rule only where there is a key row a query.
+        raise ValueError(
+            f"the causal kernels want one key row a query, not {s_q} "
+            f"queries and {s_k} keys"
+        )
+    if mask.window:
+        if s_q % mask.window or s_k != mask.summaries + s_q:
+            raise ValueError(
+                f"{s_q} queries are not whole windows of {mask.window}, or "
+                f"{s_k} key rows are not {mask.summaries} summaries and "
+                "one row a query"
+            )
+        for what, block in (("block_q", block_q), ("block_k", block_k)):
+            if mask.window % block:
+                raise ValueError(
+                    f"{what} {block} does not divide the attention window "
+                    f"{mask.window}: choose a block that does"
+                )
+    return block_q, block_k
+
+
+# ------------------------------------------------- where a grid step is
+
+
+def _schedule(mask, s_q, s_k, block_q, block_k, kv_major: bool) -> tuple:
+    """The non-empty blocks of a windowed mask in the order a kernel
+    walks them, as three int32 vectors: the query block, the key block,
+    and flags (1: first of its row of blocks, 2: last of it, 4: compute).
+    ``kv_major``: a row of blocks is a key block's (dkv), else a query
+    block's. A row with no live block still gets one step, without
+    compute, so that its result is written (zeros)."""
+    live = mask.live_blocks(s_q, s_k, block_q, block_k)
+    if kv_major:
+        live = live.T
+    outer, inner, flags = [], [], []
+    for o, row in enumerate(live):
+        found = np.flatnonzero(row)
+        steps = found if found.size else [0]
+        for n, i in enumerate(steps):
+            outer.append(o)
+            inner.append(i)
+            flags.append((n == 0) + 2 * (n == len(steps) - 1)
+                         + 4 * bool(found.size))
+    qs, ks = (inner, outer) if kv_major else (outer, inner)
+    return tuple(np.asarray(x, dtype=np.int32) for x in (qs, ks, flags))
+
+
+class _Step:
+    """Where a grid step is: its query block and key block, whether it is
+    the first or the last of its row of blocks, and whether it computes.
+    The rectangular grid of a plain mask reads the program ids and
+    derives the rest; the scheduled grid of a windowed mask reads the
+    prefetched vectors of :func:`_schedule`."""
+
+    def __init__(self, mask, sched, block_q, block_k, n_inner,
+                 kv_major=False):
+        self.mask, self.sched = mask, sched
+        self.block_q, self.block_k, self.n_inner = block_q, block_k, n_inner
+        if sched is None:
+            outer, self.inner = pl.program_id(1), pl.program_id(2)
+            self.qi, self.ki = (
+                (self.inner, outer) if kv_major else (outer, self.inner)
+            )
+        else:
+            t = pl.program_id(1)
+            self.qi, self.ki, self.flags = (ref[t] for ref in sched)
+
+    def first(self):
+        if self.sched is None:
+            return self.inner == 0
+        return (self.flags & 1) != 0
+
+    def last(self):
+        if self.sched is None:
+            return self.inner == self.n_inner - 1
+        return (self.flags & 2) != 0
+
+    def run(self):
+        if self.sched is not None:
+            return (self.flags & 4) != 0
+        if self.mask.causal:
+            # A kv block strictly above the diagonal contributes nothing:
+            # `live_blocks` for one key row a query (`pick_blocks` refuses
+            # another shape), as a comparison of the program ids.
+            return (self.ki * self.block_k
+                    <= self.qi * self.block_q + self.block_q - 1)
+        return self.ki >= 0  # traced always-true (pl.when needs a traced pred)
+
+
+def _call(mask, kernel, dims, kv_major, ins, outs, out_shape, scratch,
+          interpret, operands):
+    """One ``pallas_call``: the rectangular grid ``(bh, outer, inner)`` for
+    a plain mask; for a windowed one the scheduled grid ``(bh, steps)``
+    with the schedule as prefetched scalars. ``ins``/``outs`` give each
+    operand's block shape and what indexes it: the query block (``"q"``),
+    the key block (``"k"``) or nothing (``"row"``: a whole lse row)."""
+    bh, s_q, s_k, block_q, block_k = dims
+    nq, nk = s_q // block_q, s_k // block_k
+    kernel = functools.partial(kernel, n_inner=nq if kv_major else nk)
+    if mask.window:
+        sched = _schedule(mask, s_q, s_k, block_q, block_k, kv_major)
+        index = {"q": lambda bh, t, qs, ks, fl: (bh, qs[t], 0),
+                 "k": lambda bh, t, qs, ks, fl: (bh, ks[t], 0),
+                 "row": lambda bh, t, qs, ks, fl: (bh, 0, 0)}
+    elif kv_major:
+        index = {"q": lambda bh, ki, qi: (bh, qi, 0),
+                 "k": lambda bh, ki, qi: (bh, ki, 0),
+                 "row": lambda bh, ki, qi: (bh, 0, 0)}
+    else:
+        index = {"q": lambda bh, qi, ki: (bh, qi, 0),
+                 "k": lambda bh, qi, ki: (bh, ki, 0),
+                 "row": lambda bh, qi, ki: (bh, 0, 0)}
+
+    def spec(shape_by):
+        return pl.BlockSpec(shape_by[0], index[shape_by[1]])
+
+    in_specs = [spec(x) for x in ins]
+    out_specs = [spec(x) for x in outs] if isinstance(outs, list) else (
+        spec(outs)
+    )
+    if not mask.window:
+        return pl.pallas_call(
+            functools.partial(kernel, None),
+            grid=(bh, nk, nq) if kv_major else (bh, nq, nk),
+            in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=scratch, interpret=interpret,
+        )(*operands)
+    return pl.pallas_call(
+        lambda qs, ks, fl, *refs: kernel((qs, ks, fl), *refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bh, len(sched[0])),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch,
+        ),
+        out_shape=out_shape, interpret=interpret,
+    )(*sched, *operands)
+
+
 # ---------------------------------------------------------------- forward
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
-                scale, causal, block_q, block_k, nk):
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _fwd_kernel(sched, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
+                *, scale, mask, block_q, block_k, n_inner):
+    step = _Step(mask, sched, block_q, block_k, n_inner)
+    qi, ki = step.qi, step.ki
 
-    @pl.when(ki == 0)
+    @pl.when(step.first())
     def _():
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    # Causal: a kv block strictly above the diagonal contributes nothing.
-    if causal:
-        run = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        run = ki >= 0  # traced always-true (pl.when needs a traced pred)
-
-    @pl.when(run)
+    @pl.when(step.run())
     def _():
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
@@ -86,19 +360,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            ) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            ) + ki * block_k
-            logits = jnp.where(rows >= cols, logits, _NEG_INF)
+        if mask.causal:
+            logits = jnp.where(
+                mask.in_block(qi, ki, block_q, block_k), logits, _NEG_INF
+            )
         m_prev = m_s[:, 0]
         chunk_m = jnp.max(logits, axis=-1)
         m_new = jnp.maximum(m_prev, chunk_m)
         p = jnp.exp(logits - m_new[:, None])
-        if causal:
+        if mask.causal:
             p = jnp.where(logits <= _NEG_INF / 2, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
         l_s[:, 0] = l_s[:, 0] * corr + jnp.sum(p, axis=-1)
@@ -109,7 +379,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
         )
         acc[:] = acc[:] * corr[:, None] + pv
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step.last())
     def _():
         l = l_s[:, 0]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -121,16 +391,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
         )
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, mask, block_q, block_k, interpret):
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
+    block_q, block_k = pick_blocks(mask, sq, sk, block_q, block_k)
     scale = 1.0 / np.sqrt(d)
     qf = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, d)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, d)
     vf = jnp.moveaxis(v, 2, 1).reshape(b * h, sk, d)
-    nq, nk = sq // block_q, sk // block_k
 
     scratch = [
         pltpu.VMEM((block_q, d), jnp.float32),
@@ -139,48 +407,36 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     ]
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, nk=nk,
+        _fwd_kernel, scale=scale, mask=mask,
+        block_q=block_q, block_k=block_k,
     )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, sq), lambda bh, qi, ki: (bh, 0, 0)),
-        ],
-        out_shape=[
+    q_block, k_block = ((1, block_q, d), "q"), ((1, block_k, d), "k")
+    o, lse = _call(
+        mask, kernel, (b * h, sq, sk, block_q, block_k), False,
+        [q_block, k_block, k_block],
+        [q_block, ((1, 1, sq), "row")],
+        [
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(qf, kf, vf)
+        scratch, interpret, (qf, kf, vf),
+    )
     return jnp.moveaxis(o.reshape(b, h, sq, d), 1, 2), lse
 
 
 # ---------------------------------------------------------------- backward
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc, *, scale, causal, block_q, block_k, nk):
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _bwd_dq_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, acc, *, scale, mask, block_q, block_k, n_inner):
+    step = _Step(mask, sched, block_q, block_k, n_inner)
+    qi, ki = step.qi, step.ki
 
-    @pl.when(ki == 0)
+    @pl.when(step.first())
     def _():
         acc[:] = jnp.zeros_like(acc)
 
-    if causal:
-        run = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        run = ki >= 0  # traced always-true (pl.when needs a traced pred)
-
-    @pl.when(run)
+    @pl.when(step.run())
     def _():
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
@@ -188,17 +444,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            ) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            ) + ki * block_k
-            logits = jnp.where(rows >= cols, logits, _NEG_INF)
+        if mask.causal:
+            logits = jnp.where(
+                mask.in_block(qi, ki, block_q, block_k), logits, _NEG_INF
+            )
         lse = lse_ref[0, 0, pl.dslice(qi * block_q, block_q)]
         p = jnp.exp(logits - lse[:, None])
-        if causal:
+        if mask.causal:
             p = jnp.where(logits <= _NEG_INF / 2, 0.0, p)
         dp = jax.lax.dot_general(
             do_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
@@ -211,27 +463,23 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32,
         ) * scale
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step.last())
     def _():
         dq_ref[0] = acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, block_q, block_k, nq):
-    ki, qi = pl.program_id(1), pl.program_id(2)
+                    scale, mask, block_q, block_k, n_inner):
+    step = _Step(mask, sched, block_q, block_k, n_inner, kv_major=True)
+    qi, ki = step.qi, step.ki
 
-    @pl.when(qi == 0)
+    @pl.when(step.first())
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        run = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        run = ki >= 0  # traced always-true (pl.when needs a traced pred)
-
-    @pl.when(run)
+    @pl.when(step.run())
     def _():
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
@@ -239,17 +487,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            ) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            ) + ki * block_k
-            logits = jnp.where(rows >= cols, logits, _NEG_INF)
+        if mask.causal:
+            logits = jnp.where(
+                mask.in_block(qi, ki, block_q, block_k), logits, _NEG_INF
+            )
         lse = lse_ref[0, 0, pl.dslice(qi * block_q, block_q)]
         p = jnp.exp(logits - lse[:, None])
-        if causal:
+        if mask.causal:
             p = jnp.where(logits <= _NEG_INF / 2, 0.0, p)
         do = do_ref[0].astype(jnp.float32)
         # dv += p^T @ do
@@ -269,80 +513,61 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step.last())
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(mask, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
+    block_q, block_k = pick_blocks(mask, sq, sk, block_q, block_k)
     scale = 1.0 / np.sqrt(d)
     qf = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, d)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, d)
     vf = jnp.moveaxis(v, 2, 1).reshape(b * h, sk, d)
     dof = jnp.moveaxis(g, 2, 1).reshape(b * h, sq, d)
     of = jnp.moveaxis(o, 2, 1).reshape(b * h, sq, d)
-    nq, nk = sq // block_q, sk // block_k
     # delta = rowsum(do * o): cheap elementwise — XLA fuses it fine.
     delta = jnp.sum(
         dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1
     )[:, None, :]  # [B*H, 1, S] — matches the lse layout
 
-    dq = pl.pallas_call(
+    dims = (b * h, sq, sk, block_q, block_k)
+    q_block, k_block = ((1, block_q, d), "q"), ((1, block_k, d), "k")
+    row = ((1, 1, sq), "row")
+    ins = [q_block, k_block, k_block, q_block, row, row]
+    operands = (qf, kf, vf, dof, lse, delta)
+    dq = _call(
+        mask,
         functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, nk=nk,
+            _bwd_dq_kernel, scale=scale, mask=mask,
+            block_q=block_q, block_k=block_k,
         ),
-        grid=(b * h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, sq), lambda bh, qi, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, sq), lambda bh, qi, ki: (bh, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
+        dims, False, ins, q_block,
+        jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        [pltpu.VMEM((block_q, d), jnp.float32)], interpret, operands,
+    )
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _call(
+        mask,
         functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, nq=nq,
+            _bwd_dkv_kernel, scale=scale, mask=mask,
+            block_q=block_q, block_k=block_k,
         ),
-        grid=(b * h, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, sq), lambda bh, ki, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, sq), lambda bh, ki, qi: (bh, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
-        out_shape=[
+        dims, True, ins, [k_block, k_block],
+        [
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
+        interpret, operands,
+    )
 
     unfold = lambda x, s: jnp.moveaxis(x.reshape(b, h, s, d), 1, 2)
     return unfold(dq, sq), unfold(dk, sk), unfold(dv, sk)
@@ -352,34 +577,38 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, block_q, block_k, interpret):
-    o, _ = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
+def _flash_attention(q, k, v, mask, block_q, block_k, interpret):
+    o, _ = _flash_fwd(q, k, v, mask, block_q, block_k, interpret)
     return o
 
 
-def _flash_attention_fwd(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
+def _flash_attention_fwd(q, k, v, mask, block_q, block_k, interpret):
+    o, lse = _flash_fwd(q, k, v, mask, block_q, block_k, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_attention_bwd(causal, block_q, block_k, interpret, res, g):
-    return _flash_bwd(causal, block_q, block_k, interpret, res, g)
+def _flash_attention_bwd(mask, block_q, block_k, interpret, res, g):
+    return _flash_bwd(mask, block_q, block_k, interpret, res, g)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-def flash_attention_shard(q, k, v, causal: bool = True,
+def flash_attention_shard(q, k, v, causal: Optional[bool] = None,
                           block_q: int = 512, block_k: int = 512,
-                          interpret: Optional[bool] = None):
+                          interpret: Optional[bool] = None,
+                          mask: Optional[AttentionMask] = None):
     """The kernel on device-local [B, S, H, D] blocks (differentiable).
 
     Call it directly on one device or inside a ``shard_map`` body;
-    ``interpret=None`` follows ``dlrover_tpu.ops.interpret``.
+    ``interpret=None`` follows ``dlrover_tpu.ops.interpret``. ``causal``
+    or ``mask`` as for :func:`reference_attention`.
     """
     if interpret is None:
         interpret = interpret_mode.use_interpret()
-    return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_attention(
+        q, k, v, _as_mask(causal, mask), block_q, block_k, interpret
+    )
 
 
 def _shard_spec(mesh, shape) -> P:
@@ -395,17 +624,39 @@ def _shard_spec(mesh, shape) -> P:
     return P(batch_axes or None, None, heads, None)
 
 
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
-                    block_k: int = 512, interpret: Optional[bool] = None,
-                    mesh=None):
+def count_pairs(mask: AttentionMask, batch_heads: int, s_q: int, s_k: int,
+                block_q: int, block_k: int):
+    """Raise the program's ``attn.pairs`` counter by what one attention
+    call of this shape is asked for (``kind=allowed``: the pairs the mask
+    allows) and what its grid runs (``kind=computed``: every pair of every
+    block it does not skip). Called where the call is built, so once a
+    trace, not once a step; ``seq`` keeps calls of different lengths
+    apart."""
+    block_q, block_k = pick_blocks(mask, s_q, s_k, block_q, block_k)
+    live = int(mask.live_blocks(s_q, s_k, block_q, block_k).sum())
+    tracer = get_tracer()
+    tracer.count("attn.pairs", batch_heads * mask.pairs(s_q, s_k),
+                 kind="allowed", seq=s_q)
+    tracer.count("attn.pairs", batch_heads * live * block_q * block_k,
+                 kind="computed", seq=s_q)
+
+
+def flash_attention(q, k, v, causal: Optional[bool] = None,
+                    block_q: int = 512, block_k: int = 512,
+                    interpret: Optional[bool] = None,
+                    mesh=None, mask: Optional[AttentionMask] = None):
     """Flash attention over GLOBAL [B, S, H, D] inputs (differentiable).
 
     Over the ambient mesh (or ``mesh``) of more than one device the
     kernel runs under ``shard_map``; on one device it is called bare.
+    ``causal`` or ``mask`` as for :func:`reference_attention`.
     """
+    mask = _as_mask(causal, mask)
+    count_pairs(mask, q.shape[0] * q.shape[2], q.shape[1], k.shape[1],
+                block_q, block_k)
     kernel = functools.partial(
-        flash_attention_shard, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+        flash_attention_shard, block_q=block_q,
+        block_k=block_k, interpret=interpret, mask=mask,
     )
     mesh = mesh if mesh is not None else _ambient_mesh()
     if mesh is None or mesh.size == 1:

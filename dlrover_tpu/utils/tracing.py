@@ -109,6 +109,11 @@ SPANS: Dict[str, tuple] = {
                          "the dead worker's snapshot written to disk before "
                          "the restart"),
     "rendezvous": ("agent", "agent", "one rendezvous round with the master"),
+    "attn.pairs": ("kernels", "whichever traces the step",
+                   "counter, by kind and seq, raised where a flash-attention "
+                   "call is built (once a trace, not once a step): "
+                   "kind=allowed the query-key pairs its mask allows, "
+                   "kind=computed every pair of every block its grid runs"),
 }
 
 #: Lines a thread may hold back under a span that stays open.
