@@ -304,12 +304,15 @@ def state_bytes_per_device(abstract_state, spec) -> int:
 def _act_floats_per_token_layer(p: ModelProfile) -> float:
     """Saved-activation floats per token per layer under the remat
     policy. Rough by design — the constant only needs to rank policies
-    and scale with d_model/ff (flash attention: no [S,S] term)."""
+    and scale with d_model/ff (flash attention: no [S,S] term; under
+    "dots" its output is kept beside the matmuls' — qkv 3d, attention d,
+    proj d, up f, down d — and its log-sum-exp, a float a head, is left
+    out)."""
     d, f = max(p.d_model, 1), max(p.ff_dim, 4 * max(p.d_model, 1))
     if p.remat and p.remat_policy == "nothing":
         return 2.0 * d                    # residual-stream boundary
     if p.remat:                           # "dots": matmul outputs saved
-        return 5.0 * d + f
+        return 6.0 * d + f
     return 10.0 * d + 2.0 * f             # no remat: everything
 
 

@@ -179,6 +179,7 @@ def _attention(q, k, v, cfg: GPTConfig):
     if cfg.attn_impl == "pallas":
         from dlrover_tpu.ops.attention import flash_attention
 
+        _count_residuals(cfg, q)
         return flash_attention(
             q, k, v, causal=True,
             block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
@@ -252,33 +253,67 @@ class Block(nn.Module):
 
 
 
-def _remat_policy(cfg):
-    """Shared by GPT and Llama (duck-typed on ``remat_policy``).
+#: The policies under which a remat'ed block keeps what its flash
+#: forward kernel wrote (``ops.attention.RESIDUAL_NAMES``).
+_KEEPS_KERNEL_RESIDUALS = ("dots", "dots_lite")
 
-    - "nothing": recompute everything (min HBM);
-    - "dots": save matmul outputs (usual throughput/memory sweet spot);
-    - "dots_lite": save ONLY the two expensive tensors per block — the
+
+def _remat_policy(cfg):
+    """Shared by GPT and Llama (duck-typed on ``remat_policy`` and
+    ``attn_impl``). What a remat'ed block keeps for its backward pass,
+    and how often the flash kernel's forward (``attn_impl="pallas"``)
+    then runs a layer a step:
+
+    - "nothing": keep nothing, recompute everything (min HBM); the
+      kernel's forward runs twice;
+    - "dots": keep matmul outputs (usual throughput/memory sweet spot).
+      The kernel is the block's largest pair of matmuls but a
+      ``pallas_call`` and no ``dot_general``, so its two outputs, the
+      attention output and the rows' log-sum-exp, are kept by name
+      (``ops.attention.RESIDUAL_NAMES``): the forward runs once;
+    - "dots_lite": keep ONLY the two expensive tensors per block — the
       attention output and the post-activation FFN tensor (named via
       ``checkpoint_name``) — and recompute the cheap qkv projections.
+      Under the kernel the attention output is kept under the kernel's
+      own names with its log-sum-exp (in place of ``attn_out``, the same
+      tensor reshaped), so the forward runs once.
       ~55% of "dots"' activation bytes at a few percent recompute: the
       policy that buys batch 8 for the 1.5B single-chip preset
       (measured in bench.py's large section);
-    - "offload": save matmul outputs to *host* memory — activations
+    - "offload": keep matmul outputs in *host* memory — activations
       leave HBM between fwd and bwd (parity: the reference's
       ``selective_offloading_checkpoint.py``); XLA streams them back
-      over DMA during the backward pass.
+      over DMA during the backward pass. The kernel's outputs are not
+      among them: the forward runs twice.
     """
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint_policies.checkpoint_dots
-    if cfg.remat_policy == "dots_lite":
-        return jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "ffn_act"
-        )
+    policies = jax.checkpoint_policies
     if cfg.remat_policy == "offload":
-        return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+        return policies.offload_dot_with_no_batch_dims(
             "device", "pinned_host"
         )
-    return jax.checkpoint_policies.nothing_saveable
+    if cfg.remat_policy not in _KEEPS_KERNEL_RESIDUALS:
+        return policies.nothing_saveable
+    from dlrover_tpu.ops.attention import RESIDUAL_NAMES
+
+    if cfg.remat_policy == "dots":
+        return policies.save_from_both_policies(
+            policies.checkpoint_dots,
+            policies.save_only_these_names(*RESIDUAL_NAMES),
+        )
+    attn = RESIDUAL_NAMES if cfg.attn_impl == "pallas" else ("attn_out",)
+    return policies.save_only_these_names(*attn, "ffn_act")
+
+
+def _count_residuals(cfg, q):
+    """Where a block builds its attention over queries ``q``
+    ``[B, S, H, D]``: if it is remat'ed and runs the flash kernel, raise
+    the program's ``attn.residuals`` counter by the bytes of what the
+    forward kernel writes for the backward ones, under whether the
+    block's policy keeps them."""
+    if cfg.remat and cfg.attn_impl == "pallas":
+        from dlrover_tpu.ops.attention import count_residuals
+
+        count_residuals(q, cfg.remat_policy in _KEEPS_KERNEL_RESIDUALS)
 
 
 class _GPTStage(nn.Module):

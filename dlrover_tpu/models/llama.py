@@ -37,6 +37,7 @@ import numpy as np
 
 from dlrover_tpu.models.gpt import (  # shared kernel + remat paths
     _attention,
+    _count_residuals,
     _remat_policy,
     loss_fn,
     moe_loss_fn,
@@ -347,6 +348,7 @@ class LlamaBlock(nn.Module):
             )
             for name in ("summary_phi", "summary_mu")
         )
+        _count_residuals(cfg, q)
         return eva_attention(
             q, k, v, phi, mu, window=cfg.attn_window, chunk=cfg.attn_chunk,
             impl=cfg.attn_impl, block_q=cfg.attn_block_q,

@@ -34,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -582,8 +583,20 @@ def _flash_attention(q, k, v, mask, block_q, block_k, interpret):
     return o
 
 
+#: The names (``jax.ad_checkpoint.checkpoint_name``) of what the forward
+#: kernel writes and the backward kernels read: the output and the rows'
+#: log-sum-exp. The kernel is a ``pallas_call`` and no ``dot_general``, so
+#: a remat policy keeps them only by these names (``models/gpt.py``,
+#: ``_remat_policy``); a block that does not runs the forward kernel
+#: again in its backward pass. Outside ``jax.checkpoint`` a name is an
+#: identity.
+RESIDUAL_NAMES = ("flash_attn_out", "flash_attn_lse")
+
+
 def _flash_attention_fwd(q, k, v, mask, block_q, block_k, interpret):
     o, lse = _flash_fwd(q, k, v, mask, block_q, block_k, interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
@@ -639,6 +652,21 @@ def count_pairs(mask: AttentionMask, batch_heads: int, s_q: int, s_k: int,
                  kind="allowed", seq=s_q)
     tracer.count("attn.pairs", batch_heads * live * block_q * block_k,
                  kind="computed", seq=s_q)
+
+
+def count_residuals(q, saved: bool):
+    """Raise the program's ``attn.residuals`` counter by the bytes of what
+    the forward kernel of one call over queries ``q`` ``[B, S, H, D]``
+    writes for the backward kernels (``RESIDUAL_NAMES``: the output, and
+    one float32 a row of log-sum-exp). Called by a model where a remat'ed
+    block builds the call, so once a trace: ``kind=saved`` where the
+    block's policy keeps them, ``kind=recomputed`` where its backward
+    pass runs the forward kernel again."""
+    b, s, h, d = q.shape
+    get_tracer().count(
+        "attn.residuals", b * s * h * (d * q.dtype.itemsize + 4),
+        kind="saved" if saved else "recomputed",
+    )
 
 
 def flash_attention(q, k, v, causal: Optional[bool] = None,

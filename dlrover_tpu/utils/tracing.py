@@ -114,6 +114,16 @@ SPANS: Dict[str, tuple] = {
                    "call is built (once a trace, not once a step): "
                    "kind=allowed the query-key pairs its mask allows, "
                    "kind=computed every pair of every block its grid runs"),
+    "attn.residuals": ("kernels", "whichever traces the step",
+                       "counter, by kind, raised where a model builds the "
+                       "flash-attention call of a remat'ed block (once a "
+                       "trace, not once a step), by the bytes the forward "
+                       "kernel writes for the backward ones (the output and "
+                       "a float32 a row of log-sum-exp): kind=saved the "
+                       "block's policy keeps them (dots, dots_lite) and the "
+                       "forward kernel runs once a layer, kind=recomputed "
+                       "its backward pass runs the forward kernel again "
+                       "(nothing, offload)"),
 }
 
 #: Lines a thread may hold back under a span that stays open.

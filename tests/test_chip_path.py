@@ -125,7 +125,10 @@ class TestAotOnV5eTopology:
             tokens.shape, tokens.dtype, sharding=batch_sharding
         )
         compiled = step.lower(state, batch).compile()
-        assert _mosaic_calls(compiled) > 0
+        # fwd, dq, dkv in the scanned layer: under ``dots`` the block keeps
+        # what the forward kernel wrote, so the chip's compiler is handed
+        # no second forward (tests/test_attention_residuals.py).
+        assert _mosaic_calls(compiled) == 3
         assert " all-gather(" in compiled.as_text()
 
 
