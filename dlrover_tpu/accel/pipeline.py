@@ -180,8 +180,8 @@ class Pipeline(nn.Module):
     has_aux: bool = False   # stage returns (y, aux) — e.g. MoE stages
     # Constrain finished-microbatch outputs to their final placement per
     # tick so the stage-boundary transfers interleave with compute (see
-    # _PipeTick). Bit-identical either way; off = the serialized
-    # baseline bench.py's comms section measures against.
+    # _PipeTick). Bit-identical either way (tests/test_comms.py,
+    # TestOverlapBitIdentity); off = the serialized baseline.
     overlap_collectives: bool = True
 
     @nn.compact

@@ -20,12 +20,20 @@ Logical axis names used: batch, seq, embed, heads, kv, mlp, vocab.
 """
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-import numpy as np
+
+from dlrover_tpu.models.stack import (
+    attention,
+    loss_fn,
+    moe_loss_fn,
+    run_blocks,
+    run_pipeline,
+)
+
+__all__ = ["GPTConfig", "GPT", "Block", "loss_fn", "moe_loss_fn"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,11 +52,6 @@ class GPTConfig:
     # throughput/memory point when activations almost fit).
     remat_policy: str = "nothing"
     scan_layers: bool = True
-    # Layers per unrolled scan iteration: >1 cuts the XLA while-loop's
-    # per-layer control overhead and widens the scheduler's window at
-    # the cost of a proportionally larger program. Must divide
-    # num_layers.
-    scan_unroll: int = 1
     attn_impl: str = "xla"  # "xla" | "pallas" | "ring" | "ulysses"
     attn_block_q: int = 512  # pallas kernel tile sizes
     attn_block_k: int = 512
@@ -66,7 +69,6 @@ class GPTConfig:
     # an expert-parallel MoEMLP and __call__ returns (logits, aux_loss).
     num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
     # Pipeline parallelism (0 = off). With pipeline_stages > 1 the blocks
     # are split into equal stages run as a GPipe schedule
     # (dlrover_tpu.accel.pipeline); pair with ParallelSpec(pipe=stages).
@@ -174,34 +176,6 @@ def _layernorm(name, cfg: GPTConfig):
     )
 
 
-def _attention(q, k, v, cfg: GPTConfig):
-    """Causal attention. q,k,v: [B, S, H, D]."""
-    if cfg.attn_impl == "pallas":
-        from dlrover_tpu.ops.attention import flash_attention
-
-        _count_residuals(cfg, q)
-        return flash_attention(
-            q, k, v, causal=True,
-            block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-        )
-    if cfg.attn_impl == "ring":
-        from dlrover_tpu.ops.ring_attention import ring_attention
-
-        return ring_attention(q, k, v, causal=True, axis_name="seq")
-    if cfg.attn_impl == "ulysses":
-        from dlrover_tpu.ops.ulysses import ulysses_attention
-
-        return ulysses_attention(q, k, v, causal=True, axis_name="seq")
-    scale = 1.0 / np.sqrt(cfg.head_dim)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    s = q.shape[1]
-    mask = jnp.tril(jnp.ones((s, s), dtype=bool))
-    logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    probs = probs.astype(cfg.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-
-
 class Block(nn.Module):
     """Pre-LN transformer block with TP-ready logical axes."""
 
@@ -222,7 +196,7 @@ class Block(nn.Module):
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
-        attn = _attention(q, k, v, cfg).reshape(b, s, d)
+        attn = attention(q, k, v, cfg).reshape(b, s, d)
         from jax.ad_checkpoint import checkpoint_name
         attn = checkpoint_name(attn, "attn_out")
         x = x + _dense(d, "proj", ("heads", "embed"), cfg)(attn)
@@ -235,7 +209,6 @@ class Block(nn.Module):
                 num_experts=cfg.num_experts,
                 ff_dim=cfg.ff_dim,
                 top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="moe",
@@ -250,111 +223,6 @@ class Block(nn.Module):
         x = x + _dense(d, "down", ("mlp", "embed"), cfg, quant=True)(y)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         return x, None
-
-
-
-#: The policies under which a remat'ed block keeps what its flash
-#: forward kernel wrote (``ops.attention.RESIDUAL_NAMES``).
-_KEEPS_KERNEL_RESIDUALS = ("dots", "dots_lite")
-
-
-def _remat_policy(cfg):
-    """Shared by GPT and Llama (duck-typed on ``remat_policy`` and
-    ``attn_impl``). What a remat'ed block keeps for its backward pass,
-    and how often the flash kernel's forward (``attn_impl="pallas"``)
-    then runs a layer a step:
-
-    - "nothing": keep nothing, recompute everything (min HBM); the
-      kernel's forward runs twice;
-    - "dots": keep matmul outputs (usual throughput/memory sweet spot).
-      The kernel is the block's largest pair of matmuls but a
-      ``pallas_call`` and no ``dot_general``, so its two outputs, the
-      attention output and the rows' log-sum-exp, are kept by name
-      (``ops.attention.RESIDUAL_NAMES``): the forward runs once;
-    - "dots_lite": keep ONLY the two expensive tensors per block — the
-      attention output and the post-activation FFN tensor (named via
-      ``checkpoint_name``) — and recompute the cheap qkv projections.
-      Under the kernel the attention output is kept under the kernel's
-      own names with its log-sum-exp (in place of ``attn_out``, the same
-      tensor reshaped), so the forward runs once.
-      ~55% of "dots"' activation bytes at a few percent recompute: the
-      policy that buys batch 8 for the 1.5B single-chip preset
-      (measured in bench.py's large section);
-    - "offload": keep matmul outputs in *host* memory — activations
-      leave HBM between fwd and bwd (parity: the reference's
-      ``selective_offloading_checkpoint.py``); XLA streams them back
-      over DMA during the backward pass. The kernel's outputs are not
-      among them: the forward runs twice.
-    """
-    policies = jax.checkpoint_policies
-    if cfg.remat_policy == "offload":
-        return policies.offload_dot_with_no_batch_dims(
-            "device", "pinned_host"
-        )
-    if cfg.remat_policy not in _KEEPS_KERNEL_RESIDUALS:
-        return policies.nothing_saveable
-    from dlrover_tpu.ops.attention import RESIDUAL_NAMES
-
-    if cfg.remat_policy == "dots":
-        return policies.save_from_both_policies(
-            policies.checkpoint_dots,
-            policies.save_only_these_names(*RESIDUAL_NAMES),
-        )
-    attn = RESIDUAL_NAMES if cfg.attn_impl == "pallas" else ("attn_out",)
-    return policies.save_only_these_names(*attn, "ffn_act")
-
-
-def _count_residuals(cfg, q):
-    """Where a block builds its attention over queries ``q``
-    ``[B, S, H, D]``: if it is remat'ed and runs the flash kernel, raise
-    the program's ``attn.residuals`` counter by the bytes of what the
-    forward kernel writes for the backward ones, under whether the
-    block's policy keeps them."""
-    if cfg.remat and cfg.attn_impl == "pallas":
-        from dlrover_tpu.ops.attention import count_residuals
-
-        count_residuals(q, cfg.remat_policy in _KEEPS_KERNEL_RESIDUALS)
-
-
-class _GPTStage(nn.Module):
-    """One pipeline chunk: ``num_layers / (stages * repeats)`` blocks.
-    Used as the ``make_stage`` body of ``accel.pipeline.Pipeline`` /
-    ``CircularPipeline``. MoE chunks return ``(x, aux_mean)`` so the
-    load-balance loss rides the pipeline carry."""
-
-    cfg: GPTConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        per_stage = cfg.num_layers // (
-            cfg.pipeline_stages * max(cfg.pipeline_repeats, 1)
-        )
-        block = Block
-        if cfg.remat:
-            block = nn.remat(
-                Block, prevent_cse=False,
-                policy=_remat_policy(cfg),
-            )
-        if cfg.scan_layers:
-            x, aux = nn.scan(
-                block,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                length=per_stage,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, name="blocks")(x)
-            aux_mean = jnp.mean(aux) if aux is not None else None
-        else:
-            auxes = []
-            for i in range(per_stage):
-                x, aux = block(cfg, name=f"block_{i}")(x)
-                if aux is not None:
-                    auxes.append(aux)
-            aux_mean = jnp.mean(jnp.stack(auxes)) if auxes else None
-        if cfg.num_experts > 0:
-            return x, aux_mean
-        return x
 
 
 class GPT(nn.Module):
@@ -386,66 +254,12 @@ class GPT(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         if cfg.pipeline_stages > 1:
-            from dlrover_tpu.accel.pipeline import (
-                CircularPipeline,
-                Pipeline,
-            )
-
-            if cfg.pipeline_repeats > 1:
-                out = CircularPipeline(
-                    make_stage=lambda: _GPTStage(cfg, name="stage"),
-                    num_stages=cfg.pipeline_stages,
-                    num_repeats=cfg.pipeline_repeats,
-                    num_microbatches=cfg.pipeline_microbatches,
-                    carry_axes=("batch", "seq", "embed"),
-                    name="pipeline",
-                )(x)
-            else:
-                out = Pipeline(
-                    make_stage=lambda: _GPTStage(cfg, name="stage"),
-                    num_stages=cfg.pipeline_stages,
-                    num_microbatches=cfg.pipeline_microbatches,
-                    carry_axes=("batch", "seq", "embed"),
-                    has_aux=cfg.num_experts > 0,
-                    name="pipeline",
-                )(x)
-            aux_total = None
-            if cfg.num_experts > 0:
-                x, aux_total = out
-            else:
-                x = out
-            x = _layernorm("ln_f", cfg)(x)
-            logits = embed.attend(x)  # module dtype (bf16): full MXU rate
-            logits = nn.with_logical_constraint(
-                logits, ("batch", "seq", "vocab")
-            )
-            if cfg.num_experts > 0:
-                return logits, aux_total
-            return logits
-
-        block = Block
-        if cfg.remat:
-            block = nn.remat(
-                Block, prevent_cse=False,
-                policy=_remat_policy(cfg),
-            )
-        if cfg.scan_layers:
-            x, aux = nn.scan(
-                block,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-                unroll=max(cfg.scan_unroll, 1),
-            )(cfg, name="blocks")(x)
-            aux_total = jnp.mean(aux) if aux is not None else None
+            x, aux = run_pipeline(Block, cfg, x)
         else:
-            auxes = []
-            for i in range(cfg.num_layers):
-                x, aux = block(cfg, name=f"block_{i}")(x)
-                if aux is not None:
-                    auxes.append(aux)
-            aux_total = jnp.mean(jnp.stack(auxes)) if auxes else None
+            x, aux = run_blocks(
+                Block, cfg, x, cfg.num_layers,
+                scanned_name="blocks", unrolled_prefix="block_",
+            )
 
         x = _layernorm("ln_f", cfg)(x)
         # Tied output head: logits via the embedding table (GPT-2 style).
@@ -454,27 +268,5 @@ class GPT(nn.Module):
             logits, ("batch", "seq", "vocab")
         )
         if cfg.num_experts > 0:
-            return logits, aux_total
+            return logits, aux
         return logits
-
-
-def loss_fn(logits, tokens, ignore_first: bool = True):
-    """Next-token cross entropy; logits[B,S,V], tokens[B,S].
-
-    Computed as logsumexp - target_logit so no [B,S,V] f32 log-prob
-    tensor is materialized (the logsumexp reduction streams over the
-    vocab axis — at GPT-2 vocab size the full logp would be the largest
-    activation in the model)."""
-    targets = tokens[:, 1:]
-    logits = logits[:, :-1].astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt)
-
-
-def moe_loss_fn(out, tokens, aux_weight: float = 1e-2):
-    """Loss for MoE models: ``out`` is ``(logits, aux)`` from a GPT with
-    ``num_experts > 0``; adds the load-balance aux loss (Switch's 1e-2
-    default weight)."""
-    logits, aux = out
-    return loss_fn(logits, tokens) + aux_weight * aux

@@ -35,12 +35,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dlrover_tpu.models.gpt import (  # shared kernel + remat paths
-    _attention,
-    _count_residuals,
-    _remat_policy,
+from dlrover_tpu.models.stack import (
+    attention,
+    count_residuals,
     loss_fn,
     moe_loss_fn,
+    run_blocks,
+    run_pipeline,
 )
 
 __all__ = ["LlamaConfig", "Llama", "loss_fn", "moe_loss_fn",
@@ -70,7 +71,6 @@ class LlamaConfig:
     # returns (logits, aux_loss); pair with ParallelSpec(expert=K).
     num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
     # "bf16" | "int8": AQT-style dynamic-quantized int8 MLP matmuls
     # (ops/quantized.py, same contract + measured caveats as
     # GPTConfig.mlp_precision).
@@ -310,7 +310,6 @@ class LlamaBlock(nn.Module):
                 num_experts=cfg.num_experts,
                 ff_dim=cfg.ff_dim,
                 top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 mlp_type="swiglu",
@@ -335,7 +334,7 @@ class LlamaBlock(nn.Module):
         """The block's mixer over rotated q, k and v ``[B, S, H, D]``."""
         cfg = self.cfg
         if cfg.mixer != "eva":
-            return _attention(q, k, v, cfg)
+            return attention(q, k, v, cfg)
         from dlrover_tpu.ops.eva import eva_attention
 
         phi, mu = (
@@ -348,50 +347,12 @@ class LlamaBlock(nn.Module):
             )
             for name in ("summary_phi", "summary_mu")
         )
-        _count_residuals(cfg, q)
+        count_residuals(cfg, q)
         return eva_attention(
             q, k, v, phi, mu, window=cfg.attn_window, chunk=cfg.attn_chunk,
             impl=cfg.attn_impl, block_q=cfg.attn_block_q,
             block_k=cfg.attn_block_k,
         )
-
-
-class _LlamaStage(nn.Module):
-    """One pipeline chunk: ``num_layers / (stages * repeats)`` blocks
-    (same contract as ``gpt._GPTStage``)."""
-
-    cfg: LlamaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        per_stage = cfg.num_layers // (
-            cfg.pipeline_stages * max(cfg.pipeline_repeats, 1)
-        )
-        block = LlamaBlock
-        if cfg.remat:
-            block = nn.remat(
-                LlamaBlock, prevent_cse=False, policy=_remat_policy(cfg)
-            )
-        if cfg.scan_layers:
-            x, aux = nn.scan(
-                block,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                length=per_stage,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, name="blocks")(x)
-            aux_mean = jnp.mean(aux) if aux is not None else None
-        else:
-            auxes = []
-            for i in range(per_stage):
-                x, aux = block(cfg, name=f"block_{i}")(x)
-                if aux is not None:
-                    auxes.append(aux)
-            aux_mean = jnp.mean(jnp.stack(auxes)) if auxes else None
-        if cfg.num_experts > 0:
-            return x, aux_mean
-        return x
 
 
 class Llama(nn.Module):
@@ -418,62 +379,16 @@ class Llama(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         if cfg.pipeline_stages > 1:
-            from dlrover_tpu.accel.pipeline import (
-                CircularPipeline,
-                Pipeline,
-            )
-
-            pipe_cls = (
-                CircularPipeline if cfg.pipeline_repeats > 1 else Pipeline
-            )
-            kw = (
-                {"num_repeats": cfg.pipeline_repeats}
-                if cfg.pipeline_repeats > 1
-                else {"has_aux": cfg.num_experts > 0}
-            )
-            out = pipe_cls(
-                make_stage=lambda: _LlamaStage(cfg, name="stage"),
-                num_stages=cfg.pipeline_stages,
-                num_microbatches=cfg.pipeline_microbatches,
-                carry_axes=("batch", "seq", "embed"),
-                name="pipeline",
-                **kw,
-            )(x)
-            aux_total = None
-            if cfg.num_experts > 0:
-                x, aux_total = out
-            else:
-                x = out
-            logits = self._head(x)
-            if cfg.num_experts > 0:
-                return logits, aux_total
-            return logits
-
-        block = LlamaBlock
-        if cfg.remat:
-            block = nn.remat(
-                LlamaBlock, prevent_cse=False, policy=_remat_policy(cfg)
-            )
-        if cfg.scan_layers:
-            x, aux = nn.scan(
-                block,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, name="layers")(x)
-            aux_total = jnp.mean(aux) if aux is not None else None
+            x, aux = run_pipeline(LlamaBlock, cfg, x)
         else:
-            auxes = []
-            for i in range(cfg.num_layers):
-                x, aux = block(cfg, name=f"layer_{i}")(x)
-                if aux is not None:
-                    auxes.append(aux)
-            aux_total = jnp.mean(jnp.stack(auxes)) if auxes else None
+            x, aux = run_blocks(
+                LlamaBlock, cfg, x, cfg.num_layers,
+                scanned_name="layers", unrolled_prefix="layer_",
+            )
 
         logits = self._head(x)
         if cfg.num_experts > 0:
-            return logits, aux_total
+            return logits, aux
         return logits
 
     def _head(self, x):

@@ -1,7 +1,8 @@
 """``dlrover-tpu timeline`` — render the merged job event log.
 
 Reads events from a master state dir (snapshot + WAL, the durable form
-of the EventLog) and/or a goodput JSON artifact (``ObservabilityPlane.
+of the EventLog) and/or the goodput JSON a master writes on stop where
+``DLROVER_TPU_GOODPUT_JSON`` names a path (``ObservabilityPlane.
 dump_json``), merges them with any per-process Chrome trace files, and
 renders:
 
@@ -14,7 +15,7 @@ renders:
 Usage::
 
     python -m dlrover_tpu.cli timeline --state-dir /tmp/job-state
-    python -m dlrover_tpu.cli timeline --goodput-json GOODPUT_r04.json \
+    python -m dlrover_tpu.cli timeline --goodput-json goodput.json \
         --trace /tmp/agent-trace.json \
         --trace /tmp/agent-trace.worker0.0.jsonl --chrome-out merged.json
 """

@@ -586,8 +586,8 @@ def _flash_attention(q, k, v, mask, block_q, block_k, interpret):
 #: The names (``jax.ad_checkpoint.checkpoint_name``) of what the forward
 #: kernel writes and the backward kernels read: the output and the rows'
 #: log-sum-exp. The kernel is a ``pallas_call`` and no ``dot_general``, so
-#: a remat policy keeps them only by these names (``models/gpt.py``,
-#: ``_remat_policy``); a block that does not runs the forward kernel
+#: a remat policy keeps them only by these names (``models/stack.py``,
+#: ``remat_policy``); a block that does not runs the forward kernel
 #: again in its backward pass. Outside ``jax.checkpoint`` a name is an
 #: identity.
 RESIDUAL_NAMES = ("flash_attn_out", "flash_attn_lse")

@@ -27,8 +27,9 @@ at the same rate as the bf16 dot (34.7 TOPS vs 36.2 TFLOP/s), i.e.
 this XLA build does not engage the double-rate int8 MXU mode, and the
 quantize chain + int32 output traffic add ~5%. The capability is kept
 correct and opt-in: where the int8 MXU rate is exposed (other
-XLA builds / TPU generations), the same code path is the 2x lever;
-bench.py's medium section re-measures the ratio every run.
+XLA builds / TPU generations), the same code path is the 2x lever.
+No cell of the benchmark runs it; ``benchmark/tools/precision_probe.py``
+reads its gradients against a cell's reference.
 """
 
 from typing import Any, Callable, NamedTuple, Optional

@@ -388,9 +388,10 @@ class CheckpointEngine:
         return owned
 
     # Target bytes per device_get batch on the staging path. One giant
-    # batched fetch serializes the whole D2H on a single transfer (BENCH_r06:
-    # ckpt_staging_mbps 2.0 vs d2h_probe_mbps 96.5); chunking lets the
-    # fastcopy pool overlap transfers and bounds peak scratch-host memory.
+    # batched fetch serializes the whole D2H on a single transfer;
+    # chunking lets the fastcopy pool overlap transfers and bounds peak
+    # scratch-host memory. The benchmark reads the rate as
+    # ``ckpt.stage_gbps`` and the fetch's time as ``ckpt.fetch_s``.
     _STAGE_CHUNK_BYTES = 32 << 20
 
     def _fetch(self, blocks: List[_Block],
@@ -817,8 +818,9 @@ class CheckpointEngine:
 
         Per-phase wall times land in ``last_restore_stats``
         (read/assemble/device_put seconds + source + bytes) so slow
-        restores are attributable (VERDICT r4 #9 — the reference claims
-        seconds-from-shm, ``docs/blogs/flash_checkpoint.md:311``).
+        restores are attributable (the reference claims
+        seconds-from-shm, ``/root/reference/docs/blogs/flash_checkpoint.md:311``;
+        the benchmark reads them as ``ckpt.restore_s`` and its parts).
         """
         self.wait_staged(60.0)
         # Stats cover the restore itself — staging waits and (on

@@ -10,9 +10,9 @@ node and applies it to a LIVE training loop —
 1. **retune** — the host trainer re-derives its accumulation schedule
    for the new world (``host.retune(world, rank)``; see
    :func:`dlrover_tpu.common.batching.derive_accum_schedule`) and
-   rebuilds the jitted train step (the recompile is the dominant cost
-   and is what ``bench.py --section rescale`` measures against a full
-   restart).
+   rebuilds the jitted train step (the recompile is the dominant
+   cost; the benchmark's ``accel.rebuild_s`` times the same rebuild in
+   a restarted worker).
 2. **transfer** — the live train state moves onto the new result's
    shardings via :func:`dlrover_tpu.accel.accelerate.transfer_state`
    (device-to-device where placements overlap; bitwise-preserving).

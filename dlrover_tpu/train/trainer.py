@@ -302,7 +302,8 @@ class Trainer:
         values are read back changes (see :class:`TrainerCallback`).
         ``pipeline=False`` is the reference synchronous loop:
         ``device_put`` inside the step context and a full device sync
-        per step — the A/B baseline (bench.py measures both).
+        per step — the baseline ``tests/test_trainer.py`` compares
+        the pipelined loop's losses against.
 
         ``rescale_engine`` (a
         :class:`~dlrover_tpu.train.rescale.RescaleEngine` whose host

@@ -1,6 +1,6 @@
 """What a remat'ed block keeps of its flash-attention call: the forward
 kernel's two outputs carry names (``ops.attention.RESIDUAL_NAMES``) that
-the ``dots`` and ``dots_lite`` policies of ``models/gpt._remat_policy``
+the ``dots`` and ``dots_lite`` policies of ``models/stack.remat_policy``
 save, so the forward kernel runs once a layer; ``nothing`` and
 ``offload`` run it again in the backward pass, as they did. Counted as
 ``pallas_call``s in the gradient's jaxpr; the gradients are the same
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.models import gpt, llama
+from dlrover_tpu.models import gpt, llama, stack
 from dlrover_tpu.ops.attention import (
     RESIDUAL_NAMES,
     AttentionMask,
@@ -126,11 +126,11 @@ class TestHowOftenTheForwardKernelRuns:
             jax.checkpoint_policies, "save_only_these_names",
             lambda *names: asked.append(names),
         )
-        gpt._remat_policy(_model("gpt", "dots_lite", attn_impl=impl).cfg)
+        stack.remat_policy(_model("gpt", "dots_lite", attn_impl=impl).cfg)
         assert asked == [names]
 
     def test_nothing_is_jaxs_nothing(self):
-        policy = gpt._remat_policy(_model("llama", "nothing").cfg)
+        policy = stack.remat_policy(_model("llama", "nothing").cfg)
         assert policy is jax.checkpoint_policies.nothing_saveable
 
     @pytest.mark.parametrize("policy", ["dots", "dots_lite", "nothing"])

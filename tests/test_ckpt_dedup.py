@@ -696,22 +696,3 @@ class TestStagingAndGauges:
         for labels, val in by_name["dlrover_tpu_ckpt_io_written_bytes"]:
             wrote[labels["op"]] = val
         assert wrote["persist"] == float(8 * MB)
-
-
-# ---------------------------------------------------------------------------
-# bench_delta direction contracts for the new metrics
-# ---------------------------------------------------------------------------
-
-
-class TestBenchDeltaDirections:
-    def test_dedup_metric_directions(self):
-        from tools.bench_delta import _INTERESTING, _LOWER_BETTER
-
-        # Volumes shrink with dedup/incremental: lower is better.
-        assert _LOWER_BETTER.search("ckpt_dedup.persist_bytes_per_replica")
-        assert _LOWER_BETTER.search("ckpt_dedup.incremental_bytes")
-        # The cut ratio grows with dedup: must NOT be lower-better, and
-        # must make the table.
-        assert not _LOWER_BETTER.search("ckpt_dedup.dedup_cut_x")
-        assert _INTERESTING.search("ckpt_dedup.dedup_cut_x")
-        assert _INTERESTING.search("ckpt_dedup.persist_bytes_per_replica")

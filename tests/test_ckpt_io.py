@@ -5,7 +5,6 @@ compatibility. Plus the bench-delta comparison tool.
 """
 
 import dataclasses
-import json
 import os
 import pickle
 
@@ -139,6 +138,34 @@ class TestStripedPersist:
         assert len(meta_b.stripes) == 4  # ceil((3M+17)/1M)
         assert all(t.crc is None for t in meta_b.tensors)
         assert meta_b.stripe_bytes == 1 << 20
+
+    @pytest.mark.parametrize("stripe_mb", [0, 1], ids=["serial", "striped"])
+    def test_both_read_paths_give_back_the_source(
+        self, tmp_path, monkeypatch, stripe_mb
+    ):
+        """What restore reads is what was persisted, block for block, on
+        both of the engine's read paths: one shared reader after stripe
+        verification, and open-per-block ``read_block`` with its
+        per-block checksum."""
+        total = 2 * (1 << 20) + 5
+        meta, buf = _shard(total, [1 << 20, (1 << 20) - 3, 8])
+        st, d = PosixDiskStorage(), str(tmp_path / "ckpts")
+        self._persist(st, d, meta, buf, stripe_mb, monkeypatch)
+        smeta = ckpt_persist.load_step_metas(st, d, 1)[0]
+        reader = ckpt_persist.open_shard_reader(st, d, 1, 0)
+        assert reader is not None
+        try:
+            ckpt_persist.verify_stripes(reader, smeta, 1, 0)
+            for t in smeta.tensors:
+                want = bytes(buf[t.offset:t.offset + t.nbytes])
+                dst = np.empty(t.nbytes, dtype=np.uint8)
+                assert reader.read_into(t.offset, memoryview(dst)) == t.nbytes
+                assert dst.tobytes() == want
+                assert ckpt_persist.read_block(
+                    st, d, 1, 0, t, getattr(smeta, "crc_algo", "")
+                ) == want
+        finally:
+            reader.close()
 
     def test_verify_step_ok_both_formats(self, tmp_path, monkeypatch):
         meta, buf = _shard(1 << 20, [1 << 20])
@@ -367,49 +394,3 @@ class TestStorageCapabilities:
         assert st.read(missing) is None
         assert st.read_bytes(missing) is None
         assert st.read_range(missing, 0, 10) is None
-
-
-class TestBenchDelta:
-    def _doc(self, **extra):
-        return {"metric": "m", "value": 1.0, "extra": extra}
-
-    def test_regression_flagging_is_direction_aware(self):
-        from tools.bench_delta import delta_rows
-
-        old = self._doc(tokens_per_s=1000, step_time_ms=100,
-                        goodput_flash_pct=90.0)
-        new = self._doc(tokens_per_s=900, step_time_ms=108,
-                        goodput_flash_pct=94.0)
-        rows = {r[0]: r for r in delta_rows(old, new)}
-        # Throughput down >5% -> regression; latency up >5% ->
-        # regression; goodput up -> fine.
-        assert rows["extra.tokens_per_s"][4] == "REGRESSION"
-        assert rows["extra.step_time_ms"][4] == "REGRESSION"
-        assert rows["extra.goodput_flash_pct"][4] == ""
-
-    def test_extract_from_artifact_tail(self):
-        from tools.bench_delta import extract_result
-
-        line = json.dumps(self._doc(tokens_per_s=5))
-        doc = {"tail": f"noise\nbench: stuff\n{line}\n"}
-        got = extract_result(doc)
-        assert got and got["extra"]["tokens_per_s"] == 5
-
-    def test_recovers_sections_from_truncated_tail(self):
-        from tools.bench_delta import extract_result
-
-        full = json.dumps(self._doc(
-            ckpt_io={"persist_speedup": 1.9}, medium={"mfu_pct": 44.0}
-        ))
-        doc = {"tail": full[len(full) // 2:]}  # head chopped mid-JSON
-        got = extract_result(doc)
-        assert got is not None
-        assert got["extra"]["medium"]["mfu_pct"] == 44.0
-
-    def test_format_table_counts_regressions(self):
-        from tools.bench_delta import delta_rows, format_table
-
-        old = self._doc(tokens_per_s=1000)
-        new = self._doc(tokens_per_s=800)
-        out = format_table(delta_rows(old, new), "old.json", "new.json")
-        assert "REGRESSION" in out and "1 regression(s)" in out

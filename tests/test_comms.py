@@ -414,6 +414,39 @@ class TestCommsGovernor:
         [defer] = log.events(kinds=[EventKind.COMMS_DEFER])
         assert defer.args["what"] == "staging"
 
+    def test_staging_lands_only_outside_the_saturated_window(
+        self, tmp_path, job_name
+    ):
+        """A save offered every step across a saturated window: no
+        staging bytes land for a step inside it, each such offer is a
+        ``staging-defer``, and the snapshots land again once it clears."""
+        import jax.numpy as jnp
+
+        from dlrover_tpu.train.checkpoint import CheckpointEngine
+
+        log = EventLog()
+        events_mod.install_sink(log.append)
+        gov = CommsGovernor(client=None, max_defer_steps=8)
+        install_governor(gov)
+        state = {"w": jnp.arange(1 << 10, dtype=jnp.float32)}
+        window = range(3, 6)
+        engine = CheckpointEngine(str(tmp_path / "ckpts"))
+        try:
+            for step in range(1, 9):
+                gov.note_saturated(step in window)
+                landed = engine.save_to_memory_async(step, state)
+                assert landed is (step not in window)
+                if landed:
+                    assert engine.wait_staged(timeout=30.0)
+        finally:
+            engine.close()
+        io = log.events(kinds=[EventKind.CKPT_IO])
+        staged = {e.args["step"] for e in io if e.args["op"] == "staging"}
+        deferred = [e.args["step"] for e in io
+                    if e.args["op"] == "staging-defer"]
+        assert staged == set(range(1, 9)) - set(window)
+        assert deferred == list(window)
+
     def test_chaos_degraded_probe_drives_deferral(self, monkeypatch):
         """End-to-end: injected link degrade → aggregator flags → kv
         profile → governor defers the hot-path I/O."""

@@ -1,4 +1,4 @@
-"""Coworker data-service tests (VERDICT r3 missing #6).
+"""Coworker data-service tests.
 
 Parity: the reference's shm ring + gRPC data service
 (``atorch/atorch/data/shm_context.py``, ``coworker_dataset.py``,
@@ -169,7 +169,7 @@ def _remote_poison_proc(host, port):
 
 
 class TestRemoteCoworkers:
-    """Cross-host data service (VERDICT r4 #5, parity:
+    """Cross-host data service (parity:
     atorch coworker_dataset.py + data_info_service.py): batch payloads
     cross a TCP socket as length-prefixed tensor frames; the consumer
     API is identical to the local-shm path."""

@@ -111,7 +111,7 @@ class TestStandaloneEngine:
             SharedMemory.remove(ckpt_shm_name(job_name, 0, 0))
 
     def test_restore_phase_attribution(self, job_name, tmp_path):
-        """VERDICT r4 #9: every load reports a read/assemble/device_put
+        """Every load reports a read/assemble/device_put
         breakdown so slow restores are attributable (vs the reference's
         unquantified seconds-from-shm claim)."""
         ckpt_dir = str(tmp_path / "ckpts")

@@ -1,4 +1,4 @@
-"""Host-offload training tests (VERDICT r3 #6).
+"""Host-offload training tests.
 
 Parity: the reference's CPU-offloaded Adam
 (``atorch/atorch/optimizers/adam_offload.py``) and selective activation

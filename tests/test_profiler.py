@@ -677,7 +677,7 @@ class TestSnapshotSpans:
 
 
 class TestModuleCosts:
-    """Per-module attribution (VERDICT r3 #9, parity with AProfiler's
+    """Per-module attribution (parity with AProfiler's
     module table ``atorch/atorch/utils/prof.py:39-464``)."""
 
     def test_ranks_transformer_blocks_dominant(self):

@@ -680,6 +680,25 @@ class TestReshapeGoodputEvidence:
             "reshape tensor 2->1: d2d 4096B, snapshot 512B"
         )
 
+    def test_plan_closed_by_the_next_step_books_under_rescale(self):
+        """An in-place transition is its own cause: the time from the
+        plan to the next step is booked under ``rescale``, not as a
+        worker failure."""
+        from dlrover_tpu.observability.events import EventKind, JobEvent
+        from dlrover_tpu.observability.goodput import GoodputLedger
+
+        led = GoodputLedger(now=0.0)
+        led.note_step(5, ts=0.5)
+        led.ingest(JobEvent(
+            kind=EventKind.RESCALE_PLAN, ts=1.0,
+            args={"plan_id": 1, "new_world": 3},
+        ))
+        led.note_step(6, ts=1.25)
+        s = led.summary(now=1.25)
+        assert s["incidents_by_cause"] == {"rescale": 1}
+        assert s["downtime_by_cause_s"]["rescale"] == pytest.approx(0.25)
+        assert s["open_incidents"] == 0
+
     def test_abort_folds_decline_reason(self):
         from dlrover_tpu.observability.events import EventKind, JobEvent
         from dlrover_tpu.observability.goodput import GoodputLedger

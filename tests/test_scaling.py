@@ -184,7 +184,7 @@ class TestElasticJobScaler:
 
 class TestScalePlanReconciler:
     def test_round_trip_autoscaler_to_new_process(self):
-        """VERDICT r3 #7 done-criterion: auto-scaler -> ScalePlan CRD ->
+        """Auto-scaler -> ScalePlan CRD ->
         reconciler -> the platform actually launches the node (the same
         watch->realize->status flow elasticjob_controller.go runs)."""
         from dlrover_tpu.master.crd import (

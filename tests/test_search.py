@@ -1,4 +1,4 @@
-"""Strategy-search engine tests (VERDICT r3 #1 done-criteria).
+"""Strategy-search engine tests.
 
 The search must pick each parallelism family on its own, given only a
 model + device count: fsdp for a too-big dense model, ``expert`` for an
@@ -147,7 +147,7 @@ class TestChoices:
         assert ranked[0][0].pipe == 1
 
     def test_pipe_priced_by_weight_traffic_floor(self):
-        """VERDICT r4 #4: pipeline ticks re-read resident stage weights,
+        """Pipeline ticks re-read resident stage weights,
         so at tiny batch (memory-bound) a pipelined step is floored by
         HBM traffic, not the bubble-adjusted compute. The estimate must
         carry that floor and it must grow with the tick count."""
@@ -405,14 +405,14 @@ class TestProfiledSearch:
 
 
 class TestCalibratedAgainstChip:
-    """VERDICT r4 #7: the cost model's constants must rest on
+    """The cost model's constants must rest on
     measurements, not spec-sheet priors. Measured step times below are
     from an earlier on-chip run on one TPU v5e chip, to be re-measured;
     estimate() must predict each within +-30%. If a model
     or kernel change moves the real numbers, re-measure and update —
     this test pins the calibration contract, not the hardware."""
 
-    PEAK = 197e12  # v5e bf16, same constant bench.py uses
+    PEAK = 197e12  # v5e bf16 (benchmark/peaks.json)
 
     # (config ctor kwargs, batch, measured step seconds)
     MEASURED = [

@@ -1,6 +1,6 @@
 """Sharded + donation-safe flash-checkpoint tests on the 8-device CPU mesh.
 
-Round-3 contract (VERDICT #2/#3): async saves must survive a train step that
+The contract: async saves must survive a train step that
 donates its input state, and GSPMD-sharded states must stage only
 addressable blocks, persist each byte once, and restore under a *different*
 mesh (reshard-on-restore). Capability parity:
@@ -182,8 +182,7 @@ class TestShardedStaging:
 
 class TestMultiProcess:
     """True multi-process GSPMD: 4 single-device processes save a sharded
-    state no process fully addresses; 2 processes restore it (VERDICT #3's
-    done-condition)."""
+    state no process fully addresses; 2 processes restore it."""
 
     def _spawn(self, nproc, mode, steps, ckpt_dir, losses_out, job):
         import subprocess

@@ -99,7 +99,7 @@ class TestSparseAdam:
 
 
 class TestGrowMidTraining:
-    """VERDICT r3 #10: growth during a jitted train loop must preserve
+    """Growth during a jitted train loop must preserve
     optimizer slot values (the recompile-on-new-capacity path)."""
 
     def test_moments_survive_grow(self):

@@ -1,4 +1,4 @@
-"""Automatic TP placement tests (VERDICT r3 missing #7, parity:
+"""Automatic TP placement tests (parity:
 ``atorch/atorch/auto/opt_lib/shard_planners/mip_tp_planner.py``).
 
 A plain flax model with ZERO sharding annotations must get Megatron-
@@ -64,7 +64,7 @@ class GQABlock(nn.Module):
     """Unannotated GQA block: k/v are *contractions*
     (out = kv_heads * head_dim < d) that the width rule alone would
     misclassify row-parallel; only the shared-input sibling rule puts
-    them in the q column group (VERDICT r4 weak #4)."""
+    them in the q column group."""
 
     d: int = 32
     heads: int = 4

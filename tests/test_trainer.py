@@ -89,7 +89,7 @@ class TestTrainer:
 
 
 class TestTrainerSurface:
-    """VERDICT r4 missing #6: evaluation, callbacks, LR-schedule wiring
+    """Evaluation, callbacks, LR-schedule wiring
     (parity: atorch_trainer.py's train loop carries all three)."""
 
     def test_evaluate_runs_forward_only(self, job_name):

@@ -17,9 +17,10 @@ round-trip every ``task_every`` ticks. The journaled fraction is what
 makes the WAL arms comparable: ``fsyncs_per_mutation`` comes straight
 from ``MasterStateStore.wal_status()``.
 
-Used by ``bench.py section_master_scale`` (the 10k-agent acceptance
-run, group-commit vs per-mutation-fsync arms) and by the tier-1 smoke
-test at ~100 agents. Run standalone::
+Used by ``tests/test_master_scale.py`` (a smoke run at ~100 agents and
+group-commit vs per-mutation-fsync arms at 2,000), ``run_lease_fleet``
+by ``tests/test_data_plane.py`` and ``run_brain_drill`` by
+``tests/test_brain_policy.py``. Run standalone::
 
     python -m tools.fleet_sim --agents 1000 --duration 5
 """
@@ -591,7 +592,7 @@ def run_brain_drill(ticks: int = 40, nodes: int = 4,
     relaunched master.
 
     Three arms share one throughput model (``_BRAIN_PERF`` paced by the
-    slowest member) so ``bench.py section_brain`` can compare them:
+    slowest member) so ``tests/test_brain_policy.py`` can compare them:
 
     - ``brain``      — starts at ``nodes``, policy on. Must end at the
       searched-best world (3) with the degraded node parked, and the
