@@ -5,13 +5,26 @@ Capability parity with the reference's low-bit optimizer family
 with CUDA dequant/quant kernels). The state stores both Adam moments as
 int8 with per-block fp32 absmax scales (2.03 bytes/param vs 8 for fp32
 Adam) and the update runs as a **Pallas kernel**: each grid program
-loads its block tile of (grad, qm, qv, scales) into VMEM, does the
+loads its tile of (grad, param, qm, qv, scales) into VMEM, does the
 whole dequantize → update → requantize chain block-locally, and writes
-(update, qm', qv', scales') — ONE HBM pass. The same chain as plain
+(param', qm', qv', scales') — ONE HBM pass. The same chain as plain
 XLA ops materializes ~5 fp32 temporaries per element (measured: 131 ms
 for an 820M-param update on v5e vs 33 ms for fp32 adamw — the
 optimizer was 35% of the 1.5B train step), exactly the hand-fusion
 case the CUDA kernels in the reference exist for, done the TPU way.
+
+**Every leaf is read and written where it lies.** The int8 moments have
+the parameter's own shape and a block is 256 consecutive elements of a
+row (the row's tail a shorter block of its own), so the kernel's
+``BlockSpec``s index gradient, parameter and moments in place and the
+state is updated in place (``input_output_aliases``): no re-layout of a
+leaf feeds or follows the call. (Flattening a leaf into ``[N, 256]``
+rows around the kernel is a reshape logically and a physical copy on
+the TPU's tiled layout: measured, 1.8 × the kernel it served.) The
+scales lie ``[..., blocks, rows]``, rows along the lanes: a ``[rows, 1]``
+array pads every scale to 128 lanes in HBM.
+The moments have the parameter's rank, which is what sharding them like
+the parameter needs; nothing shards them yet.
 
 Transient memory is bounded by the kernel's VMEM tile, so scanned
 48-layer stacks update without ever materializing a layer of fp32
@@ -21,20 +34,22 @@ The moments are replicated and the kernel is not mesh-partitioned, so
 Interpreter mode is for the CPU tests only (``dlrover_tpu.ops.interpret``).
 """
 
+import math
 from functools import partial
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.ops import interpret as interpret_mode
 
 
 class _QTensor(NamedTuple):
-    q: jnp.ndarray       # int8 payload, padded to a block multiple
-    scale: jnp.ndarray   # fp32 absmax per block
+    q: jnp.ndarray       # int8 payload, the parameter's own shape
+    scale: jnp.ndarray   # fp32 absmax per block (``_scale_shape``)
 
 
 class FusedGradientTransformation(NamedTuple):
@@ -56,40 +71,81 @@ class Adam8bitState(NamedTuple):
     v: Any               # pytree of _QTensor (SQRT domain — see below)
 
 
-def _quantize(x: jnp.ndarray, block: int) -> _QTensor:
-    flat = x.reshape(-1)
-    pad = (-flat.size) % block
-    flat = jnp.pad(flat, (0, pad))
-    blocks = flat.reshape(-1, block)
-    scale = jnp.max(jnp.abs(blocks), axis=1)
-    safe = jnp.where(scale == 0, 1.0, scale)
-    q = jnp.clip(
-        jnp.round(blocks / safe[:, None] * 127.0), -127, 127
-    ).astype(jnp.int8)
-    return _QTensor(q=q, scale=scale.astype(jnp.float32))
+def _padded(rows: int, width: int) -> int:
+    """Elements a ``[rows, width]`` matrix takes in HBM, ``width`` along
+    the 128 lanes (int8 sublane tile; the other dtypes' are finer)."""
+    return pl.cdiv(rows, 32) * 32 * pl.cdiv(width, 128) * 128
 
 
-def _chunked(shape) -> bool:
-    """Scanned/stacked leaves ([L, ...] from nn.scan or pipeline banks)
-    quantize per leading index: the block layout (and so the state
-    pytree) is per-layer, which keeps an even layer sharding's scale
-    blocks device-local."""
-    return len(shape) >= 3 and shape[0] > 1
+def _rows(shape) -> Tuple[int, int, int, bool]:
+    """A leaf as ``(L, rows, width, swapped)``: ``L`` matrices of ``rows``
+    rows, leading axes merged (the TPU tiles the last two axes only, so
+    that is no copy). A vector is one row. The chip stores a matrix with
+    whichever of its two axes pads least along the lanes (GPT-2's
+    ``[6400, 1600]`` and ``[50257, 1600]`` lie transposed in HBM), and a
+    row here is what lies along the lanes there: for such a leaf the
+    last two axes swap, which then costs nothing either. A wrong guess
+    costs a copy, never a wrong answer."""
+    if len(shape) < 2:
+        return 1, 1, math.prod(shape), False
+    n_lead, a, b = math.prod(shape[:-2]), shape[-2], shape[-1]
+    if _padded(b, a) < _padded(a, b):
+        return n_lead, b, a, True
+    return n_lead, a, b, False
 
 
-_TILE = 1024  # block rows per pallas program (~3.6 MB VMEM working set)
+def _scale_shape(shape, block: int) -> Tuple[int, ...]:
+    """One absmax for every run of ``block`` consecutive elements of a
+    row (``_rows``), the row's tail a shorter run of its own. Stored
+    ``[..., runs, rows]``: the rows lie along the lanes, so the array is
+    dense in HBM and a row tile's scales are one DMA."""
+    _, rows, width, _ = _rows(shape)
+    runs = pl.cdiv(width, block)
+    return tuple(shape[:-2]) + (runs, rows) if len(shape) >= 2 else (runs,)
+
+
+_TILE_ROWS = 2048        # most rows of a leaf per pallas program
+_VMEM_LIMIT = 48 << 20   # the kernel's scoped VMEM (the default is 16 MiB)
+_VMEM_TILE = 36 << 20    # of it, for one program's blocks and temporaries
+
+
+def _tile_rows(a: int, row_bytes: int) -> int:
+    """Rows a program takes, each holding ``row_bytes`` of VMEM. A leaf
+    of few rows is one tile; otherwise the multiple of 128 (the scales'
+    lane tile) in the upper half of what fits that covers ``a`` with the
+    fewest rows past its end, the larger first. The last tile may hang
+    over: those rows are read as garbage, share no reduction with a real
+    row, and their writes are dropped."""
+    cap = min(_TILE_ROWS, _VMEM_TILE // row_bytes)
+    if a <= cap:
+        return a
+    top = cap // 128 * 128
+    return min(range(top, top // 2, -128), key=lambda t: pl.cdiv(a, t) * t)
 
 
 def _adam8_kernel(bc_ref, g_ref, mq_ref, msc_ref, sq_ref, ssc_ref,
                   u_ref, mqo_ref, msco_ref, sqo_ref, ssco_ref,
-                  *, lr, b1, b2, eps, wd=0.0, p_ref=None):
-    """One tile: dequantize -> Adam -> requantize, all VMEM-local.
+                  *, lr, b1, b2, eps, width, wd=0.0, p_ref=None):
+    """One ``[rows, block]`` tile of a leaf ``width`` wide: dequantize ->
+    Adam -> requantize, all VMEM-local. The grid's last axis walks the
+    row's blocks; the scale refs hold every block of these rows
+    (``[blocks, rows]``) and stay put while it does.
 
-    ``v`` is stored as sqrt(v) (see ``leaf_update``'s rationale) and
+    ``v`` is stored as sqrt(v) (see ``adam8bit``'s rationale) and
     the denominator is floored at half a quantization step *in the int
     domain* (``maximum(q, 0.5)``) — same guarantee as the reference
     implementation's explicit floor, fused for free.
     """
+    j = pl.program_id(2)
+    block = g_ref.shape[1]
+    if width % block:
+        # The row's tail: lanes past the leaf's width hold garbage that
+        # must reach no absmax (what is written there is dropped).
+        lane = jax.lax.broadcasted_iota(jnp.int32, g_ref.shape, 1)
+        live = lane < width - j * block
+        read = lambda ref: jnp.where(live, ref[...].astype(jnp.float32), 0.0)
+    else:
+        read = lambda ref: ref[...].astype(jnp.float32)
     bc1 = bc_ref[0, 0]
     bc2 = bc_ref[0, 1]
     # Per-element divides are the VPU's slowest ops: every scale divide
@@ -99,12 +155,11 @@ def _adam8_kernel(bc_ref, g_ref, mq_ref, msc_ref, sq_ref, ssc_ref,
     sqrt_bc2 = jnp.sqrt(bc2)
     lr_eff = -lr * sqrt_bc2 / bc1
     eps_eff = eps * sqrt_bc2
-    g = g_ref[...].astype(jnp.float32)
-    msc = msc_ref[...]
-    ssc = ssc_ref[...]
-    m = (mq_ref[...].astype(jnp.float32) * (msc * (b1 / 127.0))
-         + (1.0 - b1) * g)
-    s_prev = sq_ref[...].astype(jnp.float32) * (ssc / 127.0)
+    g = read(g_ref)
+    msc = msc_ref[pl.ds(j, 1), :].T
+    ssc = ssc_ref[pl.ds(j, 1), :].T
+    m = read(mq_ref) * (msc * (b1 / 127.0)) + (1.0 - b1) * g
+    s_prev = read(sq_ref) * (ssc / 127.0)
     v = b2 * s_prev * s_prev + (1.0 - b2) * g * g
     s = jnp.sqrt(v)
     ssc2 = jnp.max(s, axis=1, keepdims=True)
@@ -119,140 +174,98 @@ def _adam8_kernel(bc_ref, g_ref, mq_ref, msc_ref, sq_ref, ssc_ref,
         # Fused apply (+ decoupled weight decay): write the new params
         # directly — saves the separate apply_updates pass (u write +
         # u/p reads + p write over HBM).
-        p = p_ref[...].astype(jnp.float32)
-        u_ref[...] = (p * (1.0 - lr * wd) + u).astype(u_ref.dtype)
+        u_ref[...] = (read(p_ref) * (1.0 - lr * wd) + u).astype(u_ref.dtype)
     else:
         u_ref[...] = u.astype(u_ref.dtype)
     msc2 = jnp.max(jnp.abs(m), axis=1, keepdims=True)
     r_m = jnp.where(msc2 == 0, 1.0, 127.0 / msc2)
     # |m|/absmax <= 1: round lands in [-127, 127] by construction.
     mqo_ref[...] = jnp.round(m * r_m).astype(jnp.int8)
-    msco_ref[...] = msc2
+    msco_ref[pl.ds(j, 1), :] = msc2.T
     sqo_ref[...] = sq2.astype(jnp.int8)
-    ssco_ref[...] = ssc2
+    ssco_ref[pl.ds(j, 1), :] = ssc2.T
 
 
 def _adam8_fused_kernel(bc_ref, g_ref, mq_ref, msc_ref, sq_ref,
                         ssc_ref, p_ref, po_ref, mqo_ref, msco_ref,
-                        sqo_ref, ssco_ref, *, lr, b1, b2, eps, wd):
+                        sqo_ref, ssco_ref, **hyper):
     """Fused-apply arity: params in, new params out."""
     _adam8_kernel(bc_ref, g_ref, mq_ref, msc_ref, sq_ref, ssc_ref,
                   po_ref, mqo_ref, msco_ref, sqo_ref, ssco_ref,
-                  lr=lr, b1=b1, b2=b2, eps=eps, wd=wd, p_ref=p_ref)
-
-
-def _blocks_of(g: jnp.ndarray, block: int) -> jnp.ndarray:
-    """Grad in the state's block layout: per-layer flatten + pad for
-    chunked leaves (matching the vmapped ``_quantize`` of ``init``),
-    plain flatten + pad otherwise."""
-    if _chunked(g.shape):
-        rows = g.reshape(g.shape[0], -1)
-        pad = (-rows.shape[1]) % block
-        rows = jnp.pad(rows, ((0, 0), (0, pad)))
-        return rows.reshape(-1, block)
-    flat = g.reshape(-1)
-    flat = jnp.pad(flat, (0, (-flat.size) % block))
-    return flat.reshape(-1, block)
-
-
-def _unblocks(u: jnp.ndarray, shape, block: int) -> jnp.ndarray:
-    """Inverse of `_blocks_of`."""
-    if _chunked(shape):
-        L = shape[0]
-        rest = 1
-        for d in shape[1:]:
-            rest *= d
-        return u.reshape(L, -1)[:, :rest].reshape(shape)
-    size = 1
-    for d in shape:
-        size *= d
-    return u.reshape(-1)[:size].reshape(shape)
+                  p_ref=p_ref, **hyper)
 
 
 def _pallas_leaf_update(g, qm: _QTensor, qv: _QTensor, bc12,
                         lr, b1, b2, eps, block, interpret,
                         p=None, wd=0.0):
-    """Whole-leaf update through the kernel; returns (u, qm', qv')
-    with the state layout preserved exactly. With ``p`` given the
-    apply is fused: the first output is the NEW param (and ``wd``
-    applies decoupled weight decay), not the update."""
-    gb = _blocks_of(g, block)
-    mq = qm.q.reshape(-1, block)
-    sq = qv.q.reshape(-1, block)
-    msc = qm.scale.reshape(-1, 1)
-    ssc = qv.scale.reshape(-1, 1)
-    pb = _blocks_of(p, block) if p is not None else None
-    nb = gb.shape[0]
-    # Tile choice, in Mosaic-legal terms (a block's sublane dim must be
-    # a multiple of 8 OR equal to the array dim):
-    # - small leaves (nb <= _TILE): one whole-array block, grid of 1 —
-    #   always legal, never padded;
-    # - otherwise the largest power-of-two divisor of nb in [8, _TILE]
-    #   (common case: divisible, zero padding, one HBM pass);
-    # - awkward counts (odd embedding leaves) pad up to a full _TILE
-    #   multiple (_TILE is a power of two >= 8).
-    if nb <= _TILE:
-        tile_rows = max(nb, 1)
-    else:
-        tile_rows = _TILE
-        while tile_rows >= 8 and nb % tile_rows:
-            tile_rows //= 2
-        if tile_rows < 8:
-            tile_rows = _TILE
-    padn = (-nb) % tile_rows
-    if padn:
-        gb = jnp.pad(gb, ((0, padn), (0, 0)))
-        mq = jnp.pad(mq, ((0, padn), (0, 0)))
-        sq = jnp.pad(sq, ((0, padn), (0, 0)))
-        msc = jnp.pad(msc, ((0, padn), (0, 0)))
-        ssc = jnp.pad(ssc, ((0, padn), (0, 0)))
-        if pb is not None:
-            pb = jnp.pad(pb, ((0, padn), (0, 0)))
-    nbp = nb + padn
-    row = lambda i: (i, 0)
-    tile = lambda width, dt: jax.ShapeDtypeStruct((nbp, width), dt)
-    data_spec = pl.BlockSpec((tile_rows, block), row)
-    scale_spec = pl.BlockSpec((tile_rows, 1), row)
+    """Whole-leaf update through the kernel, every operand indexed where
+    it lies and the state updated in place; returns (u, qm', qv'). With
+    ``p`` given the apply is fused: the first output is the NEW param
+    (and ``wd`` applies decoupled weight decay), not the update."""
+    n_lead, a, width, swapped = _rows(g.shape)
+    runs = pl.cdiv(width, block)
+    out_dtype = g.dtype if p is None else p.dtype
+    # A row in VMEM: one block of gradient, parameter in and out and four
+    # int8 moments, and four float32 scales of every block, all double-
+    # buffered; some ten float32 temporaries of the block's chain.
+    rows = _tile_rows(a, 2 * (
+        block * (g.dtype.itemsize + 2 * out_dtype.itemsize + 4) + 16 * runs
+    ) + 10 * block * 4)
+
+    def data(x):
+        x = x.reshape((n_lead,) + ((width, a) if swapped else (a, width)))
+        return jnp.swapaxes(x, 1, 2) if swapped else x
+
+    def undo(x):
+        return (jnp.swapaxes(x, 1, 2) if swapped else x).reshape(g.shape)
+
+    scales = lambda x: x.reshape(n_lead, runs, a)
+    data_spec = pl.BlockSpec((None, rows, block), lambda l, i, j: (l, i, j))
+    scale_spec = pl.BlockSpec((None, runs, rows), lambda l, i, j: (l, 0, i))
     in_specs = [
-        pl.BlockSpec((1, 2), lambda i: (0, 0)),
+        pl.BlockSpec((1, 2), lambda l, i, j: (0, 0)),
         data_spec, data_spec, scale_spec, data_spec, scale_spec,
     ]
-    operands = [bc12, gb, mq, msc, sq, ssc]
-    if pb is not None:
-        kernel = partial(_adam8_fused_kernel, lr=lr, b1=b1, b2=b2,
-                         eps=eps, wd=wd)
+    operands = [bc12, data(g), data(qm.q), scales(qm.scale),
+                data(qv.q), scales(qv.scale)]
+    # The moments (and the fused param) are rewritten where they lie: a
+    # block is read before it is written and no other block overlaps it.
+    aliases = {2: 1, 3: 2, 4: 3, 5: 4}
+    hyper = dict(lr=lr, b1=b1, b2=b2, eps=eps, width=width)
+    if p is not None:
+        kernel = partial(_adam8_fused_kernel, wd=wd, **hyper)
         in_specs.append(data_spec)
-        operands.append(pb)
-        out_dtype = p.dtype
+        operands.append(data(p))
+        aliases[6] = 0
     else:
-        kernel = partial(_adam8_kernel, lr=lr, b1=b1, b2=b2, eps=eps)
-        out_dtype = g.dtype
+        kernel = partial(_adam8_kernel, **hyper)
+    like = lambda dims, dt: jax.ShapeDtypeStruct((n_lead,) + dims, dt)
     u, mq2, msc2, sq2, ssc2 = pl.pallas_call(
         kernel,
-        grid=(nbp // tile_rows,),
+        grid=(n_lead, pl.cdiv(a, rows), runs),
         in_specs=in_specs,
         out_specs=[
             data_spec, data_spec, scale_spec, data_spec, scale_spec,
         ],
         out_shape=[
-            tile(block, out_dtype),
-            tile(block, jnp.int8),
-            tile(1, jnp.float32),
-            tile(block, jnp.int8),
-            tile(1, jnp.float32),
+            like((a, width), out_dtype),
+            like((a, width), jnp.int8),
+            like((runs, a), jnp.float32),
+            like((a, width), jnp.int8),
+            like((runs, a), jnp.float32),
         ],
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=interpret,
     )(*operands)
-    u = _unblocks(u[:nb], g.shape, block)
-    qm2 = _QTensor(
-        q=mq2[:nb].reshape(qm.q.shape),
-        scale=msc2[:nb].reshape(qm.scale.shape),
+    return (
+        undo(u),
+        _QTensor(q=undo(mq2), scale=msc2.reshape(qm.scale.shape)),
+        _QTensor(q=undo(sq2), scale=ssc2.reshape(qv.scale.shape)),
     )
-    qv2 = _QTensor(
-        q=sq2[:nb].reshape(qv.q.shape),
-        scale=ssc2[:nb].reshape(qv.scale.shape),
-    )
-    return u, qm2, qv2
 
 
 def adam8bit(
@@ -274,29 +287,35 @@ def adam8bit(
     quantization step of s so a moment that rounds to zero can never
     amplify m by 1/eps.
     """
+    if block_size % 128:
+        raise ValueError(
+            f"block_size {block_size}: a block is whole lane tiles of 128"
+        )
 
     def init(params):
-        # Strip flax partitioning boxes first: quantized blocks are a
-        # *flattened* relayout of the param, so the param's logical axis
-        # names do not apply to them — a box left wrapping a _QTensor
-        # would broadcast one (rank-mismatched) sharding over q and
-        # scale. The moments are replicated instead: at ~2 bytes/param
-        # that is the 8-bit optimizer's single-chip memory story; under
-        # FSDP the fp32 master path is the sharded one.
+        # Strip flax partitioning boxes first: a box left wrapping a
+        # _QTensor would broadcast the param's one sharding over q and
+        # scale, and the scale's last two axes are the param's swapped.
+        # The moments are replicated: at ~2 bytes/param that is the
+        # 8-bit optimizer's single-chip memory story; under FSDP the
+        # fp32 master path is the sharded one. (q has the param's shape
+        # and scale its rank, so a later change can give them the
+        # param's sharding axis by axis; nothing does yet.)
         import flax.linen as nn
 
         params = nn.meta.unbox(params)
 
         def qzero(p):
-            z = jnp.zeros_like(p, jnp.float32)
-            if _chunked(p.shape):
-                return jax.vmap(partial(_quantize, block=block_size))(z)
-            return _quantize(z, block_size)
+            return _QTensor(
+                q=jnp.zeros(p.shape, jnp.int8),
+                scale=jnp.zeros(
+                    _scale_shape(p.shape, block_size), jnp.float32
+                ),
+            )
 
-        zeros = jax.tree_util.tree_map(qzero, params)
         return Adam8bitState(
             step=jnp.zeros((), jnp.int32),
-            m=zeros,
+            m=jax.tree_util.tree_map(qzero, params),
             v=jax.tree_util.tree_map(qzero, params),
         )
 

@@ -128,8 +128,8 @@ def offload_train_supported(device=None) -> bool:
 def _truncate_spec(s, a):
     """Rebuild a NamedSharding with its spec truncated to the leaf's
     rank: default-kind shardings tolerate over-long specs, memory-kind
-    ones are validated strictly (and some opt states — the quantized
-    adam's scale rows — inherit their param's longer spec)."""
+    ones are validated strictly (and an opt state may inherit the spec
+    of a param of higher rank)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if not isinstance(s, NamedSharding) or not hasattr(a, "ndim"):
@@ -140,9 +140,10 @@ def _truncate_spec(s, a):
 def _offloadable(a) -> bool:
     """Worth (and safe to) move: a plain array leaf of real size. A
     composite subtree under one prefix sharding (the quantized adam's
-    _QTensor: mixed ranks behind one spec) cannot take a strictly-
-    validated memory-kind sharding — and its whole point is already
-    being tiny, so it stays on device."""
+    _QTensor: q and scale behind one spec, the scale's last two axes
+    the param's swapped) cannot take a strictly-validated memory-kind
+    sharding — and its whole point is already being tiny, so it stays
+    on device."""
     if not hasattr(a, "ndim"):
         return False
     return a.ndim > 0 and a.size >= _MIN_OFFLOAD_ELEMS

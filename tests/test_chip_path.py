@@ -8,7 +8,9 @@ lowers, partitions and fits, nothing about runtime or speed).
 """
 
 import json
+import math
 import os
+import re
 import subprocess
 import sys
 from types import SimpleNamespace
@@ -62,23 +64,61 @@ class TestAotOnV5eTopology:
         ).lower(x, x, x).compile()
         assert _mosaic_calls(compiled) == 3  # fwd, dq, dkv
 
-    def test_fused_adam8bit_on_an_xl_leaf(self, v5e_2x2, compiled_kernels):
+    @staticmethod
+    def _adam8bit_update_of(shape, device, donate=True):
+        """``update_and_apply`` of one bfloat16 leaf, lowered for the chip
+        with the state donated, as the train step donates it."""
         from dlrover_tpu.optim.low_bit import adam8bit
 
-        one = SingleDeviceSharding(v5e_2x2[0])
+        one = SingleDeviceSharding(device)
         opt = adam8bit(2e-4)
-        # The embedding: its block count is odd, the padded-tile path.
-        leaf = {"wte": jax.ShapeDtypeStruct(
-            (50257, 1600), jnp.bfloat16, sharding=one
-        )}
+        leaf = {"w": jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)}
         state = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
             jax.eval_shape(opt.init, leaf),
         )
-        compiled = jax.jit(opt.update_and_apply).lower(
-            leaf, state, leaf
-        ).compile()
+        return jax.jit(
+            opt.update_and_apply, donate_argnums=(1, 2) if donate else ()
+        ).lower(leaf, state, leaf)
+
+    @pytest.mark.parametrize("shape", [
+        (50257, 1600),      # the embedding: lies transposed, a tail of 81
+        (2, 1600, 6400),    # scanned, rows of 25 whole blocks
+        (2, 6400, 1600),    # scanned, lies transposed
+        (2, 1600, 4800),    # scanned, a tail of 192
+    ])
+    def test_fused_adam8bit_moves_no_leaf(
+        self, v5e_2x2, compiled_kernels, shape
+    ):
+        """One Mosaic call, every operand read and written where it lies:
+        nothing of the leaf's size is copied, padded, transposed, sliced
+        or re-laid around it, and nothing is held besides the arguments
+        (what refused PR 25 and cost the parent a fifth of its step)."""
+        compiled = self._adam8bit_update_of(shape, v5e_2x2[0]).compile()
         assert _mosaic_calls(compiled) == 1
+        moved = []
+        for dims, op in re.findall(
+            r"= \(?\w+\[([\d,]+)\]\S* "
+            r"(copy|copy-start|pad|transpose|slice|dynamic-slice|reshape)\(",
+            compiled.as_text(),
+        ):
+            if math.prod(int(d) for d in dims.split(",")) >= math.prod(shape):
+                moved.append((op, dims))
+        assert not moved, moved
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < math.prod(shape) // 8
+
+    def test_adam8bit_kernel_does_not_grow_with_the_leaf(
+        self, v5e_2x2, compiled_kernels
+    ):
+        """The leaf's width is on the grid, not unrolled into the body:
+        tracing and lowering a 32768-wide leaf is the work of a 256-wide
+        one (a body that grew with the width cost long16k 3.3 s of
+        ``setup.build_s`` in PR 25)."""
+        narrow = self._adam8bit_update_of((64, 256), v5e_2x2[0]).as_text()
+        wide = self._adam8bit_update_of((64, 32768), v5e_2x2[0]).as_text()
+        assert "tpu_custom_call" in narrow
+        assert len(wide) <= 1.2 * len(narrow), (len(wide), len(narrow))
 
     def test_pallas_gpt_step_partitions_over_data_and_fsdp(
         self, v5e_2x2, compiled_kernels

@@ -411,44 +411,76 @@ class TestStepConsistencyVote:
 
 class TestQuantizedStateCheckpoint:
     """The 8-bit optimizer's int8/_QTensor pytree must round-trip
-    through the flash engines byte-exactly (namedtuple structure,
-    int8 + fp32 leaves, per-layer chunked layouts)."""
+    through the flash engines byte-exactly (namedtuple structure, int8
+    moments of the parameter's shape, fp32 scales ``[..., blocks,
+    rows]``), and hold no bit a step leaves unwritten: the benchmark's
+    elastic cell fingerprints every one across a kill."""
 
-    def test_adam8bit_state_round_trips(self, tmp_path, monkeypatch):
+    @staticmethod
+    def _two_updates():
         import jax
 
         from dlrover_tpu.optim.low_bit import adam8bit
 
+        rng = np.random.default_rng(3)
+        draw = lambda shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
         params = {
-            "stack": jnp.ones((4, 8, 16), jnp.float32),  # chunked leaf
-            "w": jnp.ones((32, 8), jnp.float32),
-            "b": jnp.zeros((8,), jnp.float32),
+            "stack": draw((4, 8, 320)),   # scanned; a tail block of 64
+            "w": draw((1100, 72)),        # rows past the kernel's tile
+            "down": draw((2, 384, 200)),  # lies transposed on the chip
+            "b": draw((300,)),
         }
         opt = adam8bit(1e-2)
         opt_state = opt.init(params)
-        grads = jax.tree_util.tree_map(jnp.ones_like, params)
-        _, opt_state = opt.update(grads, opt_state, params)
-        state = {"params": params, "opt": opt_state, "step": 1}
+        for _ in range(2):
+            grads = jax.tree_util.tree_map(lambda p: draw(p.shape), params)
+            params, opt_state = opt.update_and_apply(
+                grads, opt_state, params
+            )
+        return {"params": params, "opt": opt_state, "step": 2}
 
-        monkeypatch.setenv("DLROVER_TPU_JOB_NAME", f"q8-{os.getpid()}")
+    @pytest.mark.parametrize("storage", ["MEMORY", "DISK"])
+    def test_adam8bit_state_round_trips(self, tmp_path, job_name, storage):
+        import jax
+
+        from benchmark.worker import fingerprint
+
+        state = self._two_updates()
         ckpt = FlashCheckpointer(str(tmp_path / "ckpts"))
         try:
-            from dlrover_tpu.train.checkpoint.checkpointer import (
-                StorageType,
-            )
-
-            ckpt.save_checkpoint(1, state, StorageType.DISK)
+            assert ckpt.save_checkpoint(2, state, getattr(StorageType, storage))
             zeros = jax.tree_util.tree_map(jnp.zeros_like, state)
+            if storage == "DISK":   # no warm snapshot: read what was written
+                ckpt.engine.wait_staged(60.0)
+                SharedMemory.remove(ckpt_shm_name(job_name, 0, 0))
             step, restored = ckpt.load_checkpoint(zeros)
-            assert step == 1
+            assert step == 2
+            source = ckpt.engine.last_restore_stats["source"]
+            assert source == ("memory" if storage == "MEMORY" else "storage")
             for a, b in zip(
                 jax.tree_util.tree_leaves(state),
                 jax.tree_util.tree_leaves(restored),
             ):
                 if hasattr(a, "dtype"):
                     assert a.dtype == b.dtype
+                    assert a.shape == b.shape
                 np.testing.assert_array_equal(
                     np.asarray(a), np.asarray(b)
                 )
+            np.testing.assert_array_equal(
+                np.asarray(fingerprint(state)),
+                np.asarray(fingerprint(restored)),
+            )
         finally:
             ckpt.close()
+            SharedMemory.remove(ckpt_shm_name(job_name, 0, 0))
+
+    def test_two_runs_leave_the_same_bits(self):
+        """No scale and no ``q`` element is left to whatever the buffer
+        held: the same two updates twice give equal fingerprints."""
+        from benchmark.worker import fingerprint
+
+        np.testing.assert_array_equal(
+            np.asarray(fingerprint(self._two_updates())),
+            np.asarray(fingerprint(self._two_updates())),
+        )

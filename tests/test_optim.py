@@ -181,15 +181,171 @@ class TestBf16Master:
         assert state.master["w"].dtype == jnp.float32
 
 
+def _lies_transposed(shape) -> bool:
+    """Whether a matrix pads less on the chip with its second-last axis
+    along the 128 lanes (int8 tile 32 x 128): such a leaf's blocks run
+    down its columns."""
+    tile = lambda rows, width: -(-rows // 32) * 32 * -(-width // 128) * 128
+    return len(shape) >= 2 and (
+        tile(shape[-1], shape[-2]) < tile(shape[-2], shape[-1])
+    )
+
+
+def reference_adam8bit(g, p, m, v, step, lr, b1, b2, eps, wd, block=256):
+    """One 8-bit Adam step in plain ``jax.numpy``, no kernel and no
+    tiles: ``(new_p, update, (mq, mscale), (sq, sscale))``. A block is
+    ``block`` consecutive elements of a row, the row's tail a shorter
+    block; the scales lie ``[..., blocks, rows]``."""
+    swap = lambda x: jnp.swapaxes(x, -1, -2)
+    if _lies_transposed(g.shape):
+        new_p, u, (mq, msc), (sq, ssc) = reference_adam8bit(
+            swap(g), swap(p), (swap(m[0]), m[1]), (swap(v[0]), v[1]),
+            step, lr, b1, b2, eps, wd, block,
+        )
+        return swap(new_p), swap(u), (swap(mq), msc), (swap(sq), ssc)
+    width = g.shape[-1] if g.ndim else 1
+    rows = g.shape[-2] if g.ndim >= 2 else 1
+    blocks = -(-width // block)
+
+    def blocked(x):
+        x = jnp.asarray(x, jnp.float32).reshape(-1, rows, width)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, blocks * block - width)))
+        return x.reshape(-1, rows, blocks, block)
+
+    def flat(x):
+        return x.reshape(-1, rows, blocks * block)[..., :width].reshape(
+            g.shape
+        )
+
+    column = lambda scale: swap(scale.reshape(-1, blocks, rows))[..., None]
+    lying = lambda scale: swap(scale[..., 0]).reshape(m[1].shape)
+    bc1 = 1 - b1 ** jnp.float32(step)
+    bc2 = 1 - b2 ** jnp.float32(step)
+    lr_eff = -lr * jnp.sqrt(bc2) / bc1
+    eps_eff = eps * jnp.sqrt(bc2)
+    gb = blocked(g)
+    m2 = blocked(m[0]) * (column(m[1]) * (b1 / 127.0)) + (1.0 - b1) * gb
+    s_prev = blocked(v[0]) * (column(v[1]) / 127.0)
+    s = jnp.sqrt(b2 * s_prev * s_prev + (1.0 - b2) * gb * gb)
+    ssc = jnp.max(s, axis=-1, keepdims=True)
+    sq = jnp.floor(s * jnp.where(ssc == 0, 1.0, 127.0 / ssc) + 0.5)
+    u = lr_eff * m2 / (jnp.maximum(sq, 0.5) * (ssc / 127.0) + eps_eff)
+    msc = jnp.max(jnp.abs(m2), axis=-1, keepdims=True)
+    mq = jnp.round(m2 * jnp.where(msc == 0, 1.0, 127.0 / msc))
+    new_p = blocked(p) * (1.0 - lr * wd) + u
+    return (
+        flat(new_p).astype(p.dtype), flat(u).astype(g.dtype),
+        (flat(mq.astype(jnp.int8)), lying(msc)),
+        (flat(sq.astype(jnp.int8)), lying(ssc)),
+    )
+
+
+HYPER = dict(lr=1e-2, b1=0.9, b2=0.999, eps=1e-8, wd=0.1)
+
+
 class TestAdam8bit:
-    def test_state_is_int8(self):
-        opt = adam8bit(1e-3)
-        params = {"w": jnp.ones((300,))}  # non-multiple of block: padded
-        state = opt.init(params)
-        assert state.m["w"].q.dtype == jnp.int8
-        assert state.v["w"].q.dtype == jnp.int8
-        # 300 padded to 2 blocks of 256
-        assert state.m["w"].q.shape == (2, 256)
+    @pytest.mark.parametrize("shape, scale_shape", [
+        ((300,), (2,)),                    # a vector is one row, with a tail
+        ((64, 512), (2, 64)),
+        ((3, 40, 320), (3, 2, 40)),        # scanned; the tail a block of 64
+        ((2, 384, 200), (2, 2, 200)),      # lies transposed: blocks down columns
+        ((), (1,)),
+    ])
+    def test_state_is_int8(self, shape, scale_shape):
+        state = adam8bit(1e-3).init({"w": jnp.ones(shape)})
+        for moment in (state.m["w"], state.v["w"]):
+            assert moment.q.dtype == jnp.int8
+            assert moment.q.shape == shape        # the parameter's own
+            assert moment.scale.dtype == jnp.float32
+            assert moment.scale.shape == scale_shape
+
+    def test_state_bytes_a_parameter(self):
+        """GPT-2 XL's leaves: the tails' extra scales and nothing padded
+        keep the state within 1 % of 2 + 8 / 256 bytes a parameter."""
+        leaves = [(48, 1600, 6400), (48, 6400, 1600), (48, 1600, 4800),
+                  (48, 1600, 1600), (50257, 1600), (1024, 1600),
+                  (48, 6400), (48, 4800), (48, 1600), (1600,)]
+        params = {str(i): jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                  for i, s in enumerate(leaves)}
+        state = jax.eval_shape(adam8bit(1e-3).init, params)
+        held = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves((state.m, state.v)))
+        n = sum(int(np.prod(s)) for s in leaves)
+        assert held / n < (2 + 8 / 256) * 1.01
+
+    def test_a_block_is_whole_lane_tiles(self):
+        with pytest.raises(ValueError, match="block_size 100"):
+            adam8bit(1e-3, block_size=100)
+
+    @pytest.mark.parametrize("shape, lies", [
+        ((1600, 6400), False), ((6400, 1600), True), ((50257, 1600), True),
+        ((1600, 1600), False), ((1600, 4800), False), ((48, 1600), False),
+        ((4, 14336, 4096), False), ((4096, 32768), False),
+    ])
+    def test_rows_follow_the_chips_layout(self, shape, lies):
+        from dlrover_tpu.optim.low_bit import _rows
+
+        n_lead, rows, width, swapped = _rows(shape)
+        assert swapped == lies == _lies_transposed(shape)
+        assert (rows, width) == (shape[-2:][::-1] if lies else shape[-2:])
+        assert n_lead * rows * width == int(np.prod(shape))
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("shape", [
+        (64, 512),       # whole blocks
+        (40, 320),       # 256 + 64: the tail block
+        (1100, 256),     # rows that do not divide the row tile
+        (300,),          # a vector
+        (3, 32, 320),    # scanned [L, A, B]
+        (2, 384, 200),   # scanned, lying transposed
+        (1290, 200),     # lying transposed, with a tail
+    ])
+    def test_kernel_equals_the_plain_reference(self, shape, dtype):
+        """Two successive steps, fused and unfused, against
+        ``reference_adam8bit``: int8 moments bit for bit, scales and
+        parameters to float32 rounding."""
+        rng = np.random.default_rng(len(shape) + shape[-1])
+        opt = adam8bit(
+            HYPER["lr"], HYPER["b1"], HYPER["b2"], HYPER["eps"], HYPER["wd"]
+        )
+        reference = jax.jit(
+            lambda g, p, m, v, step: reference_adam8bit(
+                g, p, m, v, step, **HYPER
+            )
+        )
+        close = lambda a, b, atol=0.0, rtol=2e-6: np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=rtol, atol=atol,
+        )
+        # A float32 result one rounding off may land on bfloat16's next step.
+        ulp = 2e-6 if dtype == jnp.float32 else 2.0 ** -7
+        p = jnp.asarray(rng.normal(size=shape), dtype)
+        state = opt.init({"w": p})
+        ref_p, ref_m, ref_v = p, tuple(state.m["w"]), tuple(state.v["w"])
+        for step in (1, 2):
+            spread = rng.uniform(0.01, 10.0, size=shape[-1:])
+            g = jnp.asarray(rng.normal(size=shape) * spread, dtype)
+            ref_p_old = ref_p
+            ref_p, ref_u, ref_m, ref_v = reference(
+                g, ref_p, ref_m, ref_v, step
+            )
+            u, unfused = opt.update({"w": g}, state, {"w": p})
+            new_p, state = opt.update_and_apply({"w": g}, state, {"w": p})
+            for got, want in ((state.m["w"], ref_m), (state.v["w"], ref_v)):
+                np.testing.assert_array_equal(
+                    np.asarray(got.q), np.asarray(want[0])
+                )
+                close(got.scale, want[1])
+            close(new_p["w"], ref_p, atol=1e-6, rtol=ulp)  # values of order 1
+            # Unfused: the same state bit for bit; the update carries the
+            # decayed parameter outside the kernel.
+            for a, b in zip(jax.tree_util.tree_leaves(unfused),
+                            jax.tree_util.tree_leaves(state)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            decay = (HYPER["lr"] * HYPER["wd"] * ref_p_old).astype(dtype)
+            close(u["w"], ref_u - decay, atol=1e-8, rtol=ulp)
+            p = new_p["w"]
+        assert bool(jnp.all(jnp.isfinite(p.astype(jnp.float32))))
 
     def test_tracks_fp32_adam(self):
         """The quantized trajectory stays close to fp32 Adam on a
