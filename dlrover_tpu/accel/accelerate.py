@@ -165,6 +165,17 @@ def _check_spec_axes_used(spec, abstract_state):
             )
 
 
+def split_loss(out):
+    """``(scalar, metrics)`` of what a loss returned: the scalar alone
+    (no metrics) or ``(scalar, {name: scalar})``."""
+    if isinstance(out, tuple):
+        value, metrics = out
+        if "loss" in metrics:
+            raise ValueError("a loss's metrics may not be named 'loss'")
+        return value, dict(metrics)
+    return out, {}
+
+
 def make_train_step(module, optimizer, loss, mesh, rules,
                     shardings, batch_sharding, donate: bool = True,
                     grad_accum: int = 1, collectives=()):
@@ -193,6 +204,14 @@ def make_train_step(module, optimizer, loss, mesh, rules,
     earlier but turns sum-then-reduce into reduce-then-sum, and pinning
     fsdp-sharded leaves repartitions the backward — both are real FP
     reassociations, observed non-identical at data=4/fsdp=2.)
+
+    ``loss(module, params, batch)`` returns the scalar to differentiate
+    or ``(scalar, {name: scalar})``: what the forward pass counted
+    beside it (routing counters), which the step's metrics then carry
+    beside ``loss`` (under ``grad_accum`` their mean over the
+    microbatches). A loss object that other callers differentiate as a
+    plain scalar may carry that second form as its attribute
+    ``with_metrics``; the step calls it in the object's place.
     """
     import jax
     import flax.linen as nn
@@ -205,11 +224,16 @@ def make_train_step(module, optimizer, loss, mesh, rules,
         and env_utils.COMMS_OVERLAP.get()
     )
 
-    def grads_of(params, batch):
-        def scalar_loss(p):
-            return loss(module, p, batch)
+    loss = getattr(loss, "with_metrics", loss)
 
-        return jax.value_and_grad(scalar_loss)(params)
+    def grads_of(params, batch):
+        def loss_and_metrics(p):
+            return split_loss(loss(module, p, batch))
+
+        (lv, more), grads = jax.value_and_grad(
+            loss_and_metrics, has_aux=True
+        )(params)
+        return lv, more, grads
 
     def step(state, batch):
         # The mesh context makes the mesh discoverable at trace time
@@ -232,18 +256,19 @@ def make_train_step(module, optimizer, loss, mesh, rules,
 
                 def body(carry, mb):
                     loss_sum, g_sum = carry
-                    lv, g = grads_of(state["params"], mb)
+                    lv, more, g = grads_of(state["params"], mb)
                     g_sum = jax.tree_util.tree_map(
                         lambda a, c: a + c, g_sum, g
                     )
-                    return (loss_sum + lv, g_sum), None
+                    return (loss_sum + lv, g_sum), more
 
                 zero = jax.tree_util.tree_map(
                     jnp.zeros_like, state["params"]
                 )
-                (loss_sum, g_sum), _ = jax.lax.scan(
+                (loss_sum, g_sum), more = jax.lax.scan(
                     body, (jnp.zeros(()), zero), micro
                 )
+                more = {k: jnp.mean(v) for k, v in more.items()}
                 lv = loss_sum / grad_accum
                 grads = jax.tree_util.tree_map(
                     lambda g: g / grad_accum, g_sum
@@ -273,7 +298,7 @@ def make_train_step(module, optimizer, loss, mesh, rules,
                         _pin, grads, shardings["params"]
                     )
             else:
-                lv, grads = grads_of(state["params"], batch)
+                lv, more, grads = grads_of(state["params"], batch)
             fused = getattr(optimizer, "update_and_apply", None)
             if fused is not None:
                 # One kernel pass produces the new params (saves the
@@ -290,7 +315,7 @@ def make_train_step(module, optimizer, loss, mesh, rules,
                 "params": params, "opt": opt_state,
                 "step": state["step"] + 1,
             }
-            return new_state, {"loss": lv}
+            return new_state, {"loss": lv, **more}
 
     return jax.jit(
         step,
@@ -336,7 +361,8 @@ def auto_accelerate(
 ) -> AccelerateResult:
     """Analyze → choose strategy → build sharded state + train step.
 
-    ``loss(module, params, batch) -> scalar``. ``spec`` may be a
+    ``loss(module, params, batch) -> scalar`` or ``(scalar, {name:
+    scalar})`` (``make_train_step``). ``spec`` may be a
     ``ParallelSpec``, "auto" (cost-model search over the full strategy
     space, ``accel/search.py``), or "auto" + ``profile=True`` (dry-run
     the top-K candidates and keep the fastest, parity:

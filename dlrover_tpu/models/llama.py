@@ -20,15 +20,20 @@ Family-defining pieces, implemented TPU-first:
 The block's mixer is chosen by the configuration: ``mixer="full"`` is
 causal softmax attention over every earlier key; ``mixer="eva"`` is
 window-local causal attention joined in one softmax with learned chunk
-summaries of the earlier windows (``ops/eva.py``). The variants an
-architecture may also ask for — RMSNorm with a unit offset, a float32
-residual stream, several next-token heads with a float32 output — are
-fields whose defaults leave the plain LLaMA program as it was.
+summaries of the earlier windows (``ops/eva.py``). A stack may mix layer
+kinds: under the full mixer each layer's attention is one of ATTN_KINDS
+(``attn_kinds``), and after ``dense_layers`` leading layers the FFN may be
+one chip's share of a layer of routed experts (``experts``,
+``ops/moe.py``). The variants an architecture may also ask for — RMSNorm
+with a unit offset, a float32 residual stream, several next-token heads
+with a float32 output, a head width of its own, normed q and k, a gated
+attention output, sandwich norms, a scaled embedding — are fields whose
+defaults leave the plain LLaMA program as it was.
 """
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -38,14 +43,22 @@ import numpy as np
 from dlrover_tpu.models.stack import (
     attention,
     count_residuals,
+    counted_loss_fn,
     loss_fn,
     moe_loss_fn,
     run_blocks,
     run_pipeline,
 )
+from dlrover_tpu.ops.moe import HeldExperts
 
 __all__ = ["LlamaConfig", "Llama", "loss_fn", "moe_loss_fn",
-           "multibyte_loss_fn"]
+           "multibyte_loss_fn", "counted_loss_fn"]
+
+#: What an entry of ``LlamaConfig.attn_kinds`` may be: causal attention
+#: over every earlier key with RoPE (the plain LLaMA layer), RoPE and a
+#: window of ``attn_window`` keys that slides with the query, or causal
+#: attention with no position encoding.
+ATTN_KINDS = ("full", "sliding", "nope")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +119,29 @@ class LlamaConfig:
     # the default (PERF.md section 6, PR 30: first loss 6.54 against
     # 6.03, gradient 1 - cosine 1.7e-5 against 1.1e-5).
     init_std: float = 0.02
+    # Layers of more than one kind in one stack (models/stack.run_blocks
+    # unrolls them). ``attn_kinds``: one of ATTN_KINDS a layer, for the
+    # "full" mixer (empty: every layer is "full"). ``experts``: one chip's
+    # share of a layer of routed experts (ops/moe.HeldExperts) as the FFN
+    # of every layer after the ``dense_layers`` leading ones, which keep
+    # the SwiGLU of width ``d_ff``; ``__call__`` then returns (logits,
+    # routing counters), see ``counted_loss_fn``.
+    attn_kinds: tuple = ()
+    experts: Optional[HeldExperts] = None
+    dense_layers: int = 0
+    # The width of one head where it is not d_model / num_heads
+    # (0: it is); the projections are then num_heads * head_dim wide.
+    attn_head_dim: int = 0
+    # RMSNorm of q and of k over the head width, a learned scale each,
+    # before the position encoding.
+    qk_norm: bool = False
+    # The attention output times sigmoid(x W_g), W_g as wide as q,
+    # before the output projection.
+    attn_gate: bool = False
+    # A norm after each branch as well as before: h + N2(Attn(N1(h))).
+    sandwich_norm: bool = False
+    # The embedding's rows times sqrt(d_model).
+    scale_embed: bool = False
 
     def __post_init__(self):
         if self.kv_heads > self.num_heads or self.num_heads % self.kv_heads:
@@ -115,6 +151,27 @@ class LlamaConfig:
             )
         if self.mixer not in ("full", "eva"):
             raise ValueError(f"unknown mixer {self.mixer!r}")
+        if self.attn_kinds:
+            if self.mixer != "full" or len(self.attn_kinds) != self.num_layers:
+                raise ValueError(
+                    f"attn_kinds names one kind for each of the "
+                    f"{self.num_layers} layers of a full mixer"
+                )
+            unknown = set(self.attn_kinds) - set(ATTN_KINDS)
+            if unknown or (
+                "sliding" in self.attn_kinds and self.attn_window <= 0
+            ):
+                raise ValueError(
+                    f"attention kinds are {ATTN_KINDS}, sliding with an "
+                    f"attn_window; got {sorted(unknown) or self.attn_kinds}"
+                )
+        if self.experts is not None and (
+            self.num_experts or self.pipeline_stages > 1
+        ):
+            raise ValueError(
+                "held experts run neither beside num_experts nor in "
+                "pipeline stages"
+            )
         if self.mixer == "eva":
             if not (self.attn_chunk > 0 and self.attn_window > 0
                     and self.attn_window % self.attn_chunk == 0):
@@ -148,15 +205,57 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        return self.attn_head_dim or self.d_model // self.num_heads
+
+    def attn_kind(self, layer: int) -> str:
+        return self.attn_kinds[layer] if self.attn_kinds else "full"
+
+    def routed(self, layer: int) -> bool:
+        """Whether layer ``layer``'s FFN is the held experts."""
+        return self.experts is not None and layer >= self.dense_layers
+
+    @property
+    def routed_layers(self) -> int:
+        return sum(self.routed(i) for i in range(self.num_layers))
+
+    def layer_kinds(self):
+        """What ``run_blocks`` builds each layer's block with: ``None``
+        for a stack of one kind built as it always was."""
+        if not self.attn_kinds and self.experts is None:
+            return None
+        return [
+            {"attn_kind": self.attn_kind(i), "routed": self.routed(i)}
+            for i in range(self.num_layers)
+        ]
 
     def param_count(self) -> int:
         d, f, l = self.d_model, self.ff_dim, self.num_layers
-        kv = self.kv_heads * self.head_dim
-        per_layer = d * d + 2 * d * kv + d * d + 3 * d * f + 2 * d
+        q, kv = self.num_heads * self.head_dim, self.kv_heads * self.head_dim
+        attn = 2 * d * q + 2 * d * kv
+        norms = 2 * d
         if self.mixer == "eva":     # the summaries' two vectors a head
-            per_layer += 2 * self.num_heads * self.head_dim
-        return self.vocab_param_count() + l * per_layer + d
+            attn += 2 * q
+        if self.attn_gate:
+            attn += d * q
+        if self.qk_norm:
+            norms += 2 * self.head_dim
+        if self.sandwich_norm:
+            norms += 2 * d
+        routed = self.routed_layers
+        ffn = (l - routed) * 3 * d * f
+        if routed:
+            ffn += routed * self.experts.param_count(d)
+        return self.vocab_param_count() + l * (attn + norms) + ffn + d
+
+    def active_param_count(self) -> float:
+        """``param_count`` with, of the held experts, the share a token
+        passes through under a uniform router."""
+        if self.experts is None:
+            return self.param_count()
+        return self.param_count() - self.routed_layers * (
+            self.experts.param_count(self.d_model)
+            - self.experts.active_param_count(self.d_model)
+        )
 
     def vocab_param_count(self) -> int:
         """Embedding + *untied* LM head (LLaMA convention), one head's
@@ -164,27 +263,42 @@ class LlamaConfig:
         stack for the pipeline cost model."""
         return (1 + self.pred_heads) * self.vocab_size * self.d_model
 
-    def attention_pairs(self) -> int:
-        """Query-key pairs of one head over ``max_seq_len`` positions, as
-        ``flops_per_token`` counts them: the whole square for the full
-        mixer (twice what its causal mask allows — the count the cost
-        models were calibrated on, kept), the allowed pairs exactly for
-        the eva mixer (local windows and the summaries seen)."""
+    def attention_pairs(self, layer: int = 0) -> int:
+        """Query-key pairs of one head of layer ``layer`` over
+        ``max_seq_len`` positions, as ``flops_per_token`` counts them:
+        the whole square for a stack of plain full layers (twice what
+        its causal mask allows — the count the cost models were
+        calibrated on, kept); the pairs the layer's own mask allows
+        exactly for the eva mixer (local windows and the summaries seen)
+        and for a stack with ``attn_kinds`` (causal or sliding)."""
         s = self.max_seq_len
-        if self.mixer != "eva":
-            return s * s
-        from dlrover_tpu.ops.eva import eva_mask
+        if self.mixer == "eva":
+            from dlrover_tpu.ops.eva import eva_mask
 
-        mask = eva_mask(s, self.attn_window, self.attn_chunk)
-        return mask.pairs(s, s + mask.summaries)
+            mask = eva_mask(s, self.attn_window, self.attn_chunk)
+            return mask.pairs(s, s + mask.summaries)
+        if not self.attn_kinds:
+            return s * s
+        return self.attention_mask(layer).pairs(s, s)
+
+    def attention_mask(self, layer: int):
+        """The mask of layer ``layer`` of the full mixer."""
+        from dlrover_tpu.ops.attention import AttentionMask
+
+        if self.attn_kind(layer) == "sliding":
+            return AttentionMask(window=self.attn_window, sliding=True)
+        return AttentionMask()
 
     def flops_per_token(self) -> float:
-        """Approx training FLOPs/token: 6 a parameter plus attention, 12
-        a pair and unit of model width (QK^T and PV, forward and twice
-        backward); which pairs, see ``attention_pairs``."""
-        pairs_per_token = self.attention_pairs() / self.max_seq_len
-        attn = 12 * self.num_layers * self.d_model * pairs_per_token
-        return 6 * self.param_count() + attn
+        """Approx training FLOPs/token: 6 a parameter a token passes
+        through plus attention, 12 a pair and unit of the heads' joint
+        width (QK^T and PV, forward and twice backward); which pairs,
+        see ``attention_pairs``."""
+        pairs_per_token = sum(
+            self.attention_pairs(i) for i in range(self.num_layers)
+        ) / self.max_seq_len
+        attn = 12 * self.num_heads * self.head_dim * pairs_per_token
+        return 6 * self.active_param_count() + attn
 
     @staticmethod
     def tiny():
@@ -215,7 +329,7 @@ class _UnitOffsetRMSNorm(nn.Module):
         return (y * (1.0 + scale.astype(jnp.float32))).astype(cfg.dtype)
 
 
-def _rms_norm(name: str, cfg: LlamaConfig):
+def _rms_norm(name: str, cfg: LlamaConfig, axes=("embed",)):
     if cfg.norm_unit_offset:
         return _UnitOffsetRMSNorm(cfg, name=name)
     return nn.RMSNorm(
@@ -223,7 +337,7 @@ def _rms_norm(name: str, cfg: LlamaConfig):
         dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
         scale_init=nn.with_logical_partitioning(
-            nn.initializers.ones_init(), ("embed",)
+            nn.initializers.ones_init(), axes
         ),
         name=name,
     )
@@ -271,7 +385,13 @@ def rope(x, positions, theta: float = 10000.0):
 
 
 class LlamaBlock(nn.Module):
+    """One layer. ``attn_kind`` (one of ATTN_KINDS) and ``routed`` (the
+    FFN is the held experts) are the layer's own where a stack mixes
+    kinds; the defaults are the plain LLaMA layer."""
+
     cfg: LlamaConfig
+    attn_kind: str = "full"
+    routed: bool = False
 
     @nn.compact
     def __call__(self, x, _=None):
@@ -286,9 +406,13 @@ class LlamaBlock(nn.Module):
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, kvh, hd)
         v = v.reshape(b, s, kvh, hd)
-        positions = jnp.arange(s)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = _rms_norm("q_norm", cfg, axes=("kv",))(q)
+            k = _rms_norm("k_norm", cfg, axes=("kv",))(k)
+        if self.attn_kind != "nope":
+            positions = jnp.arange(s)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         if kvh != h:
             # GQA: repeat kv heads up to query width (static shape; the
             # small kv projection is what saves HBM, not the repeat).
@@ -297,10 +421,17 @@ class LlamaBlock(nn.Module):
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
-        attn = self._mix(q, k, v).reshape(b, s, d)
+        attn = self._mix(q, k, v).reshape(b, s, h * hd)
         from jax.ad_checkpoint import checkpoint_name
         attn = checkpoint_name(attn, "attn_out")
-        x = x + _dense(d, "o_proj", ("heads", "embed"), cfg)(attn)
+        if cfg.attn_gate:
+            attn = attn * nn.sigmoid(
+                _dense(h * hd, "attn_gate", ("embed", "heads"), cfg)(y)
+            )
+        attn = _dense(d, "o_proj", ("heads", "embed"), cfg)(attn)
+        if cfg.sandwich_norm:
+            attn = _rms_norm("attn_post_norm", cfg)(attn)
+        x = x + attn
 
         y = _rms_norm("mlp_norm", cfg)(x)
         if cfg.num_experts > 0:
@@ -318,23 +449,37 @@ class LlamaBlock(nn.Module):
             x = x + y
             x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
             return x, aux
-        gate = _dense(cfg.ff_dim, "gate_proj", ("embed", "mlp"), cfg,
-                      quant=True)(y)
-        up = _dense(cfg.ff_dim, "up_proj", ("embed", "mlp"), cfg,
-                    quant=True)(y)
-        y = nn.silu(gate) * up
-        y = checkpoint_name(y, "ffn_act")
-        y = nn.with_logical_constraint(y, ("batch", "seq", "mlp"))
-        x = x + _dense(d, "down_proj", ("mlp", "embed"), cfg,
-                       quant=True)(y)
+        aux = None
+        if self.routed:
+            from dlrover_tpu.ops.moe import HeldExpertsMLP
+
+            y, aux = HeldExpertsMLP(
+                cfg.experts, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                init_std=cfg.init_std, name="experts",
+            )(y)
+        else:
+            gate = _dense(cfg.ff_dim, "gate_proj", ("embed", "mlp"), cfg,
+                          quant=True)(y)
+            up = _dense(cfg.ff_dim, "up_proj", ("embed", "mlp"), cfg,
+                        quant=True)(y)
+            y = nn.silu(gate) * up
+            y = checkpoint_name(y, "ffn_act")
+            y = nn.with_logical_constraint(y, ("batch", "seq", "mlp"))
+            y = _dense(d, "down_proj", ("mlp", "embed"), cfg, quant=True)(y)
+        if cfg.sandwich_norm:
+            y = _rms_norm("mlp_post_norm", cfg)(y)
+        x = x + y
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        return x, None
+        return x, aux
 
     def _mix(self, q, k, v):
         """The block's mixer over rotated q, k and v ``[B, S, H, D]``."""
         cfg = self.cfg
         if cfg.mixer != "eva":
-            return attention(q, k, v, cfg)
+            sliding = self.attn_kind == "sliding"
+            return attention(
+                q, k, v, cfg, window=cfg.attn_window if sliding else 0
+            )
         from dlrover_tpu.ops.eva import eva_attention
 
         phi, mu = (
@@ -356,7 +501,9 @@ class LlamaBlock(nn.Module):
 
 
 class Llama(nn.Module):
-    """Decoder-only LM. ``__call__(tokens[B,S]) -> logits[B,S,V]``."""
+    """Decoder-only LM. ``__call__(tokens[B,S]) -> logits[B,S,V]``; with
+    ``num_experts`` ``(logits, aux loss)``, with ``experts`` ``(logits,
+    routing counters)``."""
 
     cfg: LlamaConfig
 
@@ -373,6 +520,8 @@ class Llama(nn.Module):
             name="embed",
         )
         x = embed(tokens)
+        if cfg.scale_embed:
+            x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
         if cfg.fp32_residual:
             # Each block adds its branches (in ``dtype``) onto this.
             x = x.astype(jnp.float32)
@@ -384,10 +533,11 @@ class Llama(nn.Module):
             x, aux = run_blocks(
                 LlamaBlock, cfg, x, cfg.num_layers,
                 scanned_name="layers", unrolled_prefix="layer_",
+                kinds=cfg.layer_kinds(),
             )
 
         logits = self._head(x)
-        if cfg.num_experts > 0:
+        if cfg.num_experts > 0 or cfg.experts is not None:
             return logits, aux
         return logits
 

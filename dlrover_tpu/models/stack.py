@@ -3,15 +3,16 @@
 :mod:`.gpt` and :mod:`.llama` each define their block, norms, embedding
 and head; both import from here, and neither imports the other:
 
-- ``attention``: the causal attention a block runs under ``attn_impl``;
+- ``attention``: the causal attention a block runs under ``attn_impl``,
+  over every earlier key or a sliding window of them;
 - ``remat_policy`` / ``count_residuals``: what a remat'ed block keeps for
   its backward pass, and the counter that says so;
 - ``run_blocks``: remat-wrap, then ``nn.scan`` or a Python loop over the
-  blocks, then the mean of their auxiliary losses. The one place a layer
-  pattern (more than one kind of block in a stack) would enter;
+  blocks, then the mean of their auxiliary outputs. A layer pattern
+  (more than one kind of block in a stack) enters here, as ``kinds``;
 - ``Stage`` / ``run_pipeline``: the same blocks as chunks of a GPipe or
   circular schedule (``dlrover_tpu.accel.pipeline``);
-- ``loss_fn`` / ``moe_loss_fn``.
+- ``loss_fn`` / ``moe_loss_fn`` / ``counted_loss_fn``.
 
 The names of the parameter trees are the callers' (GPT ``blocks`` /
 ``block_{i}``, Llama ``layers`` / ``layer_{i}``, a stage ``blocks`` /
@@ -26,14 +27,26 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def attention(q, k, v, cfg):
-    """Causal attention. q,k,v: [B, S, H, D]."""
+def attention(q, k, v, cfg, window: int = 0):
+    """Causal attention, over every earlier key or, with ``window``,
+    over the ``window`` keys that end in the query's own (a sliding
+    window). q,k,v: [B, S, H, D]."""
+    sliding = None
+    if window:
+        from dlrover_tpu.ops.attention import AttentionMask
+
+        if cfg.attn_impl not in ("xla", "pallas"):
+            raise ValueError(
+                "sliding-window attention runs under attn_impl xla or "
+                f"pallas, not {cfg.attn_impl!r}"
+            )
+        sliding = AttentionMask(window=window, sliding=True)
     if cfg.attn_impl == "pallas":
         from dlrover_tpu.ops.attention import flash_attention
 
         count_residuals(cfg, q)
         return flash_attention(
-            q, k, v, causal=True,
+            q, k, v, causal=None if sliding else True, mask=sliding,
             block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
         )
     if cfg.attn_impl == "ring":
@@ -47,7 +60,9 @@ def attention(q, k, v, cfg):
     scale = 1.0 / np.sqrt(cfg.head_dim)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     s = q.shape[1]
-    mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+    mask = sliding.dense(s, s) if sliding else (
+        jnp.tril(jnp.ones((s, s), dtype=bool))
+    )
     logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     probs = probs.astype(cfg.dtype)
@@ -113,35 +128,45 @@ def count_residuals(cfg, q):
         kernel.count_residuals(q, cfg.remat_policy in _KEEPS_KERNEL_RESIDUALS)
 
 
-def run_blocks(block_cls, cfg, x, length, *, scanned_name, unrolled_prefix):
+def run_blocks(block_cls, cfg, x, length, *, scanned_name, unrolled_prefix,
+               kinds=None):
     """``length`` blocks ``block_cls(cfg)`` over ``x``, inside the calling
     module's ``@nn.compact`` method: each block remat'ed under
     ``cfg.remat``, stacked with ``nn.scan`` (one parameter tree
     ``scanned_name`` with a leading layer axis; compile time O(1) in
     depth) or, with ``scan_layers`` off, called in a loop (trees
-    ``{unrolled_prefix}{i}``). A block maps ``x`` to ``(x, aux)``.
-    Returns ``(x, mean of the blocks' aux)``, ``None`` for blocks that
-    give none."""
+    ``{unrolled_prefix}{i}``). ``kinds``: for each layer the keywords its
+    block is built with beside ``cfg``; layers of one kind stack as
+    above, layers whose kinds differ are called in a loop whatever
+    ``scan_layers`` says. A block maps ``x`` to ``(x, aux)``, ``aux`` a
+    scalar or a dict of scalars. Returns ``(x, mean of the blocks'
+    aux)``, ``None`` for blocks that give none."""
     block = block_cls
     if cfg.remat:
         block = nn.remat(
             block_cls, prevent_cse=False, policy=remat_policy(cfg)
         )
-    if cfg.scan_layers:
+    kinds = kinds or [{}] * length
+    one_kind = all(kind == kinds[0] for kind in kinds)
+    if cfg.scan_layers and one_kind:
         x, aux = nn.scan(
             block,
             variable_axes={"params": 0},
             split_rngs={"params": True},
             length=length,
             metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, name=scanned_name)(x)
-        return x, (jnp.mean(aux) if aux is not None else None)
+        )(cfg, name=scanned_name, **kinds[0])(x)
+        return x, jax.tree_util.tree_map(jnp.mean, aux)
     auxes = []
-    for i in range(length):
-        x, aux = block(cfg, name=f"{unrolled_prefix}{i}")(x)
+    for i, kind in enumerate(kinds):
+        x, aux = block(cfg, name=f"{unrolled_prefix}{i}", **kind)(x)
         if aux is not None:
             auxes.append(aux)
-    return x, (jnp.mean(jnp.stack(auxes)) if auxes else None)
+    if not auxes:
+        return x, None
+    return x, jax.tree_util.tree_map(
+        lambda *a: jnp.mean(jnp.stack(a)), *auxes
+    )
 
 
 class Stage(nn.Module):
@@ -205,6 +230,15 @@ def loss_fn(logits, tokens, ignore_first: bool = True):
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     return jnp.mean(lse - tgt)
+
+
+def counted_loss_fn(out, tokens):
+    """Loss for a model that returns ``(logits, counters)``: the
+    next-token cross entropy and the counters beside it, the
+    ``(scalar, {name: scalar})`` that ``accel.make_train_step`` carries
+    into the step's metrics. The counters weigh nothing in the loss."""
+    logits, counters = out
+    return loss_fn(logits, tokens), counters
 
 
 def moe_loss_fn(out, tokens, aux_weight: float = 1e-2):

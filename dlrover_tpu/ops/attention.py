@@ -16,8 +16,8 @@ What a query may see is a static :class:`AttentionMask`, from which the
 block-skip rule and the in-block mask are both derived. The plain causal
 mask walks the rectangular grid and skips the blocks above the diagonal;
 a windowed mask (aligned causal windows, optionally joined in the same
-softmax with leading rows of chunk summaries, ``docs/attention_masks.md``)
-walks a list of the non-empty blocks only, handed to the kernel as
+softmax with leading rows of chunk summaries, or a window that slides
+with the query, ``docs/attention_masks.md``) walks a list of the non-empty blocks only, handed to the kernel as
 prefetched scalars.
 
 Mosaic kernels cannot be partitioned by GSPMD, so over a mesh of more than
@@ -57,6 +57,10 @@ class AttentionMask:
       end where there are more keys than queries).
     - ``window`` > 0 (causal): query ``i`` sees ``j <= i`` of its own
       aligned window ``i // window`` only.
+    - ``window`` > 0 with ``sliding``: query ``i`` sees the ``window``
+      keys ``i - window < j <= i``, its own among them; the window moves
+      with the query, so sequences need not be whole windows and blocks
+      need not divide one.
     - ``summaries`` > 0 (with ``window`` and ``chunk``): the first
       ``summaries`` rows of k/v are no positions but one summary per
       ``chunk`` positions, in order (rows past ``positions / chunk`` are
@@ -69,10 +73,15 @@ class AttentionMask:
     window: int = 0
     summaries: int = 0
     chunk: int = 0
+    sliding: bool = False
 
     def __post_init__(self):
         if self.window and not self.causal:
             raise ValueError("a windowed mask is causal inside its windows")
+        if self.sliding and (not self.window or self.summaries):
+            raise ValueError(
+                "a sliding mask needs a window and takes no summary rows"
+            )
         if self.summaries and not (
             self.window and self.chunk and self.window % self.chunk == 0
         ):
@@ -87,6 +96,9 @@ class AttentionMask:
         numpy and on traced integers."""
         if not self.window:
             return 0 * rows, rows + (s_k - s_q), 0 * rows
+        if self.sliding:
+            lo = rows - (self.window - 1)
+            return lo * (lo > 0), rows, 0 * rows
         first = rows // self.window
         per_window = self.window // self.chunk if self.summaries else 0
         return (first * self.window + self.summaries, rows + self.summaries,
@@ -207,7 +219,13 @@ def pick_blocks(mask: AttentionMask, s_q: int, s_k: int,
             f"the causal kernels want one key row a query, not {s_q} "
             f"queries and {s_k} keys"
         )
-    if mask.window:
+    if mask.sliding:
+        if s_k != s_q:
+            raise ValueError(
+                f"a sliding mask wants one key row a query, not {s_q} "
+                f"queries and {s_k} keys"
+            )
+    elif mask.window:
         if s_q % mask.window or s_k != mask.summaries + s_q:
             raise ValueError(
                 f"{s_q} queries are not whole windows of {mask.window}, or "

@@ -38,6 +38,21 @@ from dlrover_tpu.observability.events import EventKind, emit
 from dlrover_tpu.utils.tracing import get_tracer
 
 
+def _raise_counters(tracer, metrics: dict):
+    """What a step counted beside its loss (``accel.make_train_step``),
+    raised as the tracer's counters: the metric ``name{label=value,...}``
+    is the series ``label=value`` of the counter ``name``, raised by the
+    step's value."""
+    for key, value in metrics.items():
+        if key == "loss":
+            continue
+        name, _, labels = key.partition("{")
+        tracer.count(name, float(value), **dict(
+            pair.split("=", 1) for pair in labels.rstrip("}").split(",")
+            if pair
+        ))
+
+
 class TrainerCallback:
     """Hook points mirroring the reference's HF-style callbacks. Any
     hook may set ``trainer.should_stop = True`` to end ``fit`` after
@@ -240,8 +255,10 @@ class Trainer:
         if self._eval_step is None:
             module = self._result.module
             loss = self._loss
+            from dlrover_tpu.accel.accelerate import split_loss
+
             self._eval_step = jax.jit(
-                lambda params, b: loss(module, params, b),
+                lambda params, b: split_loss(loss(module, params, b))[0],
                 in_shardings=(
                     self._result.shardings["params"],
                     self.batch_sharding,
@@ -490,8 +507,10 @@ class Trainer:
                     with tracer.span("trainer.readback") as readback:
                         prev = (
                             None if governed
-                            else deferred.push(done, {"loss": last_loss})
+                            else deferred.push(done, metrics)
                         )
+                        if prev:
+                            _raise_counters(tracer, prev[1])
                     now = time.perf_counter()
                     step_metrics = {
                         "loss": last_loss,  # device array: sync if read
@@ -504,6 +523,7 @@ class Trainer:
                         jax.block_until_ready(last_loss)
                     with tracer.span("trainer.readback") as readback:
                         loss_host = float(last_loss)
+                        _raise_counters(tracer, metrics)
                     step_metrics = {
                         "loss": loss_host,
                         "step_time_s": time.perf_counter() - dispatch.start,

@@ -124,6 +124,41 @@ SPANS: Dict[str, tuple] = {
                        "forward kernel runs once a layer, kind=recomputed "
                        "its backward pass runs the forward kernel again "
                        "(nothing, offload)"),
+    "attn.summaries": ("model", "device (jax.named_scope)",
+                       "eva mixer: one learned summary of k and of v a "
+                       "chunk of positions"),
+    "attn.mix": ("model", "device (jax.named_scope)",
+                 "eva mixer: the summaries laid before the keys and the "
+                 "attention call over both"),
+    "moe.route": ("model", "device (jax.named_scope)",
+                  "held-experts layer: sigmoid scores over all routed "
+                  "experts, the top-k choice and its weights"),
+    "moe.dispatch": ("model", "device (jax.named_scope)",
+                     "held-experts layer: the held pairs sorted by expert, "
+                     "each expert's on whole tiles of the pair buffer"),
+    "moe.experts": ("model", "device (jax.named_scope)",
+                    "held-experts layer: the gather of the buffer's rows "
+                    "and the three grouped matmuls of the SwiGLU over all "
+                    "of them"),
+    "moe.combine": ("model", "device (jax.named_scope)",
+                    "held-experts layer: the weighted scatter-add of the "
+                    "buffer's rows onto their tokens"),
+    "moe.shared": ("model", "device (jax.named_scope)",
+                   "held-experts layer: the shared expert, a SwiGLU over "
+                   "every token"),
+    "moe.pairs": ("trainer", "loop",
+                  "counter, by kind, raised each step at the readback by "
+                  "what the step before counted, a layer (the mean over the "
+                  "expert layers): kind=held the token-expert pairs routed "
+                  "to the experts this chip holds, kind=buffer the rows of "
+                  "the pair buffer, kind=overflowed the held pairs that did "
+                  "not fit (0 while the layer is dropless)"),
+    "moe.load_max_over_mean": ("trainer", "loop",
+                               "counter, raised each step at the readback "
+                               "by the largest expert's pairs over the mean "
+                               "of all routed experts' (the mean over the "
+                               "expert layers); a reader takes its rise a "
+                               "step"),
 }
 
 #: Lines a thread may hold back under a span that stays open.

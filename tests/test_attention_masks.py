@@ -558,3 +558,119 @@ def test_the_mask_is_hashable_and_static():
         AttentionMask(window=32, summaries=16, chunk=8))
     with pytest.raises(dataclasses.FrozenInstanceError):
         a.window = 8
+
+
+# ------------------------------------------------------- the sliding kind
+
+def _sliding_by_definition(seq, window):
+    """``i - window < j <= i``, pair by pair."""
+    return np.array([[i - window < j <= i for j in range(seq)]
+                     for i in range(seq)])
+
+
+class TestTheSlidingKind:
+    """A window that moves with the query (``sliding=True``): the same
+    description, schedule and kernels as the aligned windows."""
+
+    @pytest.mark.parametrize("windows", [1, 2, 4])
+    def test_dense_bounds_and_pairs_are_the_equations(self, windows):
+        seq = windows * W
+        mask = AttentionMask(window=W, sliding=True)
+        want = _sliding_by_definition(seq, W)
+        np.testing.assert_array_equal(np.asarray(mask.dense(seq, seq)), want)
+        assert mask.pairs(seq, seq) == want.sum()
+        assert mask.pairs(seq, seq) == W * (W + 1) // 2 + (seq - W) * W
+
+    def test_the_published_sizes_give_the_issues_pair_count(self):
+        mask = AttentionMask(window=4096, sliding=True)
+        assert mask.pairs(16384, 16384) == 58_722_304
+        assert AttentionMask().pairs(16384, 16384) == 134_225_920
+        # 1 + 2 + 3 + 4 + 12 x 5 blocks of 1024 x 1024, of 136 causal
+        assert mask.live_blocks(16384, 16384, 1024, 1024).sum() == 70
+
+    @pytest.mark.parametrize("kwargs", [
+        {"sliding": True}, {"sliding": True, "window": 8, "causal": False},
+        {"sliding": True, "window": 16, "summaries": 4, "chunk": 8},
+    ])
+    def test_a_description_that_says_nothing_is_refused(self, kwargs):
+        with pytest.raises(ValueError):
+            AttentionMask(**kwargs)
+
+    def test_one_window_is_the_causal_mask_and_blocks_need_not_divide(self):
+        q, k, v = _qkv(W)
+        np.testing.assert_array_equal(
+            flash_attention(q, k, v, mask=AttentionMask(window=W,
+                                                        sliding=True),
+                            block_q=16, block_k=16),
+            flash_attention(q, k, v, causal=True, block_q=16, block_k=16),
+        )
+        # 24 divides neither 32 nor 96 / 32 windows: sequences are no
+        # whole windows and a block lies across a window's edge
+        mask = AttentionMask(window=24, sliding=True)
+        assert pick_blocks(mask, 80, 80, 16, 16) == (16, 16)
+        with pytest.raises(ValueError, match="one key row a query"):
+            pick_blocks(mask, 64, 80, 16, 16)
+
+    @pytest.mark.parametrize("windows, block_q, block_k", [
+        (1, 16, 16), (2, 16, 32), (4, 32, 16), (4, 8, 64), (3, 32, 32),
+    ])
+    @pytest.mark.parametrize("kv_major", [False, True])
+    def test_the_schedule_visits_exactly_the_non_empty_blocks(
+        self, windows, block_q, block_k, kv_major
+    ):
+        seq = windows * W
+        mask = AttentionMask(window=W, sliding=True)
+        nq, nk = seq // block_q, seq // block_k
+        want = _sliding_by_definition(seq, W).reshape(
+            nq, block_q, nk, block_k
+        ).any(axis=(1, 3))
+        np.testing.assert_array_equal(
+            mask.live_blocks(seq, seq, block_q, block_k), want
+        )
+        qs, ks, flags = attention._schedule(
+            mask, seq, seq, block_q, block_k, kv_major
+        )
+        assert (flags & 4 != 0).all()       # no row of blocks is empty
+        visited = np.zeros_like(want)
+        visited[qs, ks] = True
+        np.testing.assert_array_equal(visited, want)
+        assert len(qs) == want.sum()        # each once
+
+    @pytest.mark.parametrize("windows, block_q, block_k", [
+        (1, 16, 16), (2, 16, 32), (4, 32, 16),
+    ])
+    def test_the_kernels_match_the_dense_oracle(self, windows, block_q,
+                                                block_k):
+        seq = windows * W
+        mask = AttentionMask(window=W, sliding=True)
+        q, k, v = _qkv(seq, seed=windows, batch=2)
+        out, grads = _loss_and_grads(
+            lambda *a: flash_attention(*a, mask=mask, block_q=block_q,
+                                       block_k=block_k), q, k, v)
+        want, want_grads = _loss_and_grads(
+            lambda *a: reference_attention(*a, mask=mask), q, k, v)
+        np.testing.assert_allclose(out, want, atol=2e-6)
+        for got, ref in zip(grads, want_grads):
+            np.testing.assert_allclose(got, ref, atol=2e-5)
+        # and the oracle is the equations: softmax over the allowed keys
+        dense = _sliding_by_definition(seq, W)
+        logits = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+        logits = np.where(dense, logits, -np.inf)
+        p = np.exp(logits - logits.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            want, np.einsum("bhqk,bkhd->bqhd", p, v), atol=2e-6
+        )
+
+    def test_the_counter_counts_a_sliding_call(self, monkeypatch):
+        fresh = tracing.Tracer()
+        monkeypatch.setattr(tracing, "_tracer", fresh)
+        q, k, v = _qkv(4 * W)
+        mask = AttentionMask(window=W, sliding=True)
+        flash_attention(q, k, v, mask=mask, block_q=16, block_k=16)
+        totals = [e["args"] for e in fresh.events
+                  if e["name"] == "attn.pairs"][-1]
+        seq = 4 * W
+        assert totals[f"kind=allowed,seq={seq}"] == H * mask.pairs(seq, seq)
+        live = mask.live_blocks(seq, seq, 16, 16).sum()
+        assert totals[f"kind=computed,seq={seq}"] == H * live * 16 * 16

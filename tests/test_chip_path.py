@@ -64,6 +64,63 @@ class TestAotOnV5eTopology:
         ).lower(x, x, x).compile()
         assert _mosaic_calls(compiled) == 3  # fwd, dq, dkv
 
+    def test_flash_attention_under_a_sliding_window_at_16k(
+        self, v5e_2x2, compiled_kernels
+    ):
+        """The scheduled grid of a window that slides with the query, at
+        the widths and blocks of the cell that runs it (48 heads of 128,
+        1 x 16384, window 4096, blocks of 1024)."""
+        from dlrover_tpu.ops.attention import AttentionMask, flash_attention
+
+        x = jax.ShapeDtypeStruct(
+            (1, 16384, 48, 128), jnp.bfloat16,
+            sharding=SingleDeviceSharding(v5e_2x2[0]),
+        )
+        mask = AttentionMask(window=4096, sliding=True)
+
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, mask=mask, block_q=1024,
+                                  block_k=1024)
+            return jnp.sum(out.astype(jnp.float32))
+
+        compiled = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2))
+        ).lower(x, x, x).compile()
+        assert _mosaic_calls(compiled) == 3  # fwd, dq, dkv
+
+    def test_held_experts_over_the_pair_buffer_at_the_cells_widths(
+        self, v5e_2x2, compiled_kernels
+    ):
+        """Sort, gather, the three grouped matmuls and their six backward
+        products over the cell's 8192 rows at once, the scatter-add:
+        nine Mosaic calls, shapes that do not follow what was routed."""
+        from dlrover_tpu.ops import moe
+
+        one = SingleDeviceSharding(v5e_2x2[0])
+        shape = lambda *dims: jax.ShapeDtypeStruct(
+            dims, jnp.bfloat16, sharding=one
+        )
+
+        def loss(x, router, w_gate, w_up, w_down):
+            scores = moe.router_scores(x, router)
+            chosen, weights = moe.route(
+                scores, moe.balanced_bias(scores, 4), 4, 2.448
+            )
+            y, held, _ = moe.held_experts_ffn(
+                x, chosen, weights, w_gate, w_up, w_down,
+                moe.HeldExperts(routed=256, held=8, per_token=4,
+                                ff_dim=3072, pair_buffer=8192),
+            )
+            return jnp.sum(y.astype(jnp.float32)), held
+
+        compiled = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+        ).lower(
+            shape(16384, 3072), shape(3072, 256), shape(8, 3072, 3072),
+            shape(8, 3072, 3072), shape(8, 3072, 3072),
+        ).compile()
+        assert _mosaic_calls(compiled) == 9
+
     @staticmethod
     def _adam8bit_update_of(shape, device, donate=True):
         """``update_and_apply`` of one bfloat16 leaf, lowered for the chip
