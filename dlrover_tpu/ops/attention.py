@@ -8,17 +8,19 @@ built as a native TPU kernel rather than a CUDA-library wrapper.
 
 Layout convention matches the models: ``[batch, seq, heads, head_dim]``.
 Internally arrays are folded to ``[batch*heads, seq, head_dim]``; the grid
-walks (bh, q_block, kv_block) with the kv dimension innermost so the f32
-accumulators live in VMEM scratch across kv steps (TPU grids execute
+walks (bh, step) with a row of blocks' steps one after another so the f32
+accumulators live in VMEM scratch across them (TPU grids execute
 sequentially — the canonical Pallas accumulation pattern).
 
-What a query may see is a static :class:`AttentionMask`, from which the
-block-skip rule and the in-block mask are both derived. The plain causal
-mask walks the rectangular grid and skips the blocks above the diagonal;
-a windowed mask (aligned causal windows, optionally joined in the same
-softmax with leading rows of chunk summaries, or a window that slides
-with the query, ``docs/attention_masks.md``) walks a list of the non-empty blocks only, handed to the kernel as
-prefetched scalars.
+What a query may see is a static :class:`AttentionMask` (plain, causal,
+aligned causal windows optionally joined in the same softmax with leading
+rows of chunk summaries, or a window that slides with the query,
+``docs/attention_masks.md``). Every mask walks a *schedule* derived from
+it: the list of the non-empty blocks, handed to the kernels as prefetched
+scalars, and for each block the sub-tiles (``_SUB_TILE`` square) that are
+live, and whether the mask's edge cuts a strip of them. A kernel body
+computes the live sub-tiles only and evaluates the mask only in a cut
+strip.
 
 Mosaic kernels cannot be partitioned by GSPMD, so over a mesh of more than
 one device the public entry point wraps the kernel in ``shard_map`` (batch
@@ -29,6 +31,7 @@ the CPU tests only (``dlrover_tpu.ops.interpret``).
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -44,7 +47,12 @@ from dlrover_tpu.ops.ring_attention import _ambient_mesh
 from dlrover_tpu.utils.tracing import get_tracer
 
 _NEG_INF = -1e30
-_LANES = 128  # scratch rows are padded to a full lane tile
+_LANES = 128  # a row's running statistics are kept in every lane of a tile
+#: Side of the square sub-tile a kernel body computes at a time, where it
+#: divides the block (else the largest common divisor that does): the unit
+#: in which a block the mask's edge cuts is skipped, run bare or masked
+#: (``docs/attention_masks.md`` has the sweep it was chosen by).
+_SUB_TILE = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,45 +120,55 @@ class AttentionMask:
         lo, hi, n = self.bounds(rows, s_q, s_k)
         return ((cols >= lo) & (cols <= hi)) | (cols < n)
 
-    def in_block(self, qi, ki, block_q: int, block_k: int):
-        """The mask of block ``(qi, ki)`` inside a kernel. A windowed
-        mask's block lies whole among the summaries or whole among the
-        positions (``block_k`` divides ``summaries``), so one pair of
-        bounds a row decides it."""
+    def piece(self, row0, col0, n_rows: int, n_cols: int,
+              keys_first: bool = False):
+        """The mask of the ``n_rows`` queries from ``row0`` over the
+        ``n_cols`` key rows from ``col0`` inside a kernel, ``[n_rows,
+        n_cols]`` or with ``keys_first`` its transpose. A windowed mask's
+        piece lies whole among the summaries or whole among the positions
+        (a block, and so a sub-tile, divides ``summaries``), so one pair
+        of bounds a row decides it."""
+        q_dim, k_dim = (1, 0) if keys_first else (0, 1)
+
+        def index(dim, shape):
+            if keys_first:
+                shape = shape[::-1]
+            return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
         if not self.window:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            ) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            ) + ki * block_k
+            rows = index(q_dim, (n_rows, n_cols)) + row0
+            cols = index(k_dim, (n_rows, n_cols)) + col0
             return rows >= cols
-        rows = jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0
-        ) + qi * block_q
-        cols = jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1
-        ) + ki * block_k
+        rows = index(q_dim, (n_rows, 1)) + row0
+        cols = index(k_dim, (1, n_cols)) + col0
         lo, hi, n = self.bounds(rows, 0, 0)
         if self.summaries:
-            among = ki * block_k < self.summaries
+            among = col0 < self.summaries
             lo, hi = jnp.where(among, 0, lo), jnp.where(among, n - 1, hi)
         return (cols >= lo) & (cols <= hi)
 
-    def live_blocks(self, s_q: int, s_k: int, block_q: int, block_k: int):
-        """``[nq, nk]`` numpy booleans: the blocks in which some query
-        sees some key. The kernels run exactly these."""
+    def tiles(self, s_q: int, s_k: int, block_q: int, block_k: int):
+        """``(live, whole)``, each ``[nq, nk]`` numpy booleans: the tiles
+        (blocks, or a block's sub-tiles) in which some query sees some
+        key, and those in which every query sees every key. A live tile
+        that is not whole is *cut* by an edge of the mask."""
         nq, nk = s_q // block_q, s_k // block_k
         if not self.causal:
-            return np.ones((nq, nk), dtype=bool)
+            return (np.ones((nq, nk), dtype=bool),) * 2
         lo, hi, n = self.bounds(
             np.arange(s_q).reshape(nq, block_q), s_q, s_k
         )
         first = np.arange(nk)[None, :] * block_k       # a block's first row
-        positions = (first <= hi.max(1)[:, None]) & (
-            first + block_k - 1 >= lo.min(1)[:, None]
-        )
-        return positions | (first < n.max(1)[:, None])
+        last = first + block_k - 1
+        live = (first <= hi.max(1)[:, None]) & (last >= lo.min(1)[:, None])
+        whole = (first >= lo.max(1)[:, None]) & (last <= hi.min(1)[:, None])
+        return (live | (first < n.max(1)[:, None]),
+                whole | (last < n.min(1)[:, None]))
+
+    def live_blocks(self, s_q: int, s_k: int, block_q: int, block_k: int):
+        """``[nq, nk]`` numpy booleans: the blocks in which some query
+        sees some key. The kernels run exactly these."""
+        return self.tiles(s_q, s_k, block_q, block_k)[0]
 
     def pairs(self, s_q: int, s_k: int) -> int:
         """Query-key pairs the mask allows (one head of one sequence)."""
@@ -244,125 +262,188 @@ def pick_blocks(mask: AttentionMask, s_q: int, s_k: int,
 # ------------------------------------------------- where a grid step is
 
 
-def _schedule(mask, s_q, s_k, block_q, block_k, kv_major: bool) -> tuple:
-    """The non-empty blocks of a windowed mask in the order a kernel
-    walks them, as three int32 vectors: the query block, the key block,
-    and flags (1: first of its row of blocks, 2: last of it, 4: compute).
-    ``kv_major``: a row of blocks is a key block's (dkv), else a query
-    block's. A row with no live block still gets one step, without
-    compute, so that its result is written (zeros)."""
-    live = mask.live_blocks(s_q, s_k, block_q, block_k)
+def sub_tile(block_q: int, block_k: int) -> int:
+    """Side of the square sub-tile the kernels compute at a time in blocks
+    of this shape: ``_SUB_TILE`` where it divides both sides."""
+    return math.gcd(block_q, block_k, _SUB_TILE)
+
+
+def _run_of(flags) -> tuple:
+    """``(first, last + 1)`` of the one run of set entries, ``(0, 0)``
+    where none is set. The masks' bounds rise with the query, so what a
+    strip of sub-tiles sees is one run; a description that breaks this is
+    refused here and not computed wrongly."""
+    found = np.flatnonzero(flags)
+    if not found.size:
+        return 0, 0
+    if found[-1] - found[0] + 1 != found.size:
+        raise ValueError(f"the sub-tiles of a strip are no single run: {flags}")
+    return int(found[0]), int(found[-1]) + 1
+
+
+def _strip(live, whole) -> tuple:
+    """``(a, b, cut)`` of a strip: its sub-tiles ``[a, b)`` are live, and
+    ``cut`` where an edge of the mask crosses one of them."""
+    a, b = _run_of(live)
+    return a, b, bool((live & ~whole).any())
+
+
+def _schedule(mask, s_q, s_k, block_q, block_k, t, kv_major: bool) -> tuple:
+    """The non-empty blocks of a mask in the order a kernel walks them,
+    and what of each block is to be computed, in sub-tiles of side ``t``
+    (:func:`sub_tile`'s): three int32 vectors (the query block, the key
+    block, and flags: 1 first of its row of blocks, 2 last of it, the
+    rest ``class << 2``) and the classes, class ``n`` the ``n``-th of
+    them and 0 no compute. A class says for each strip of a block ``(a,
+    b, cut)``: its sub-tiles ``[a, b)`` are live, and with ``cut`` an
+    edge of the mask crosses some of them (else no pair of the strip's
+    run is masked). A strip is a sub-row of queries over the block's
+    keys, or with ``kv_major`` (dkv: a row of blocks is a key block's,
+    else a query block's) a sub-column of keys over its queries. The
+    blocks of one mask fall into a few classes whatever the length (all
+    of it; the triangle under the diagonal; over a window's trailing
+    edge; the first columns of summary rows), and all of it is the
+    mask's ``tiles`` at the sub-tile's size, folded by block. A row
+    with no live block still gets one step, without compute, so that its
+    result is written (zeros)."""
+    nq, nk = s_q // block_q, s_k // block_k
+    live, whole = (
+        x.reshape(nq, block_q // t, nk, block_k // t).transpose(0, 2, 1, 3)
+        for x in mask.tiles(s_q, s_k, t, t)
+    )   # [query block, key block, sub-row, sub-column]
     if kv_major:
-        live = live.T
-    outer, inner, flags = [], [], []
-    for o, row in enumerate(live):
+        live, whole = (x.transpose(1, 0, 3, 2) for x in (live, whole))
+    outer, inner, flags, classes = [], [], [], []
+    for o, row in enumerate(live.any(axis=(2, 3))):
         found = np.flatnonzero(row)
         steps = found if found.size else [0]
         for n, i in enumerate(steps):
             outer.append(o)
             inner.append(i)
-            flags.append((n == 0) + 2 * (n == len(steps) - 1)
-                         + 4 * bool(found.size))
+            number = 0
+            if found.size:
+                kind = tuple(map(_strip, live[o, i], whole[o, i]))
+                if kind not in classes:
+                    classes.append(kind)
+                number = classes.index(kind) + 1
+            flags.append((n == 0) + 2 * (n == len(steps) - 1) + 4 * number)
     qs, ks = (inner, outer) if kv_major else (outer, inner)
-    return tuple(np.asarray(x, dtype=np.int32) for x in (qs, ks, flags))
+    return tuple(np.asarray(x, dtype=np.int32)
+                 for x in (qs, ks, flags)), tuple(classes)
 
 
 class _Step:
-    """Where a grid step is: its query block and key block, whether it is
-    the first or the last of its row of blocks, and whether it computes.
-    The rectangular grid of a plain mask reads the program ids and
-    derives the rest; the scheduled grid of a windowed mask reads the
-    prefetched vectors of :func:`_schedule`."""
+    """Where a grid step is, read from the prefetched vectors of
+    :func:`_schedule`: its query block and key block, whether it is the
+    first or the last of its row of blocks, and its block's class."""
 
-    def __init__(self, mask, sched, block_q, block_k, n_inner,
-                 kv_major=False):
-        self.mask, self.sched = mask, sched
-        self.block_q, self.block_k, self.n_inner = block_q, block_k, n_inner
-        if sched is None:
-            outer, self.inner = pl.program_id(1), pl.program_id(2)
-            self.qi, self.ki = (
-                (self.inner, outer) if kv_major else (outer, self.inner)
-            )
-        else:
-            t = pl.program_id(1)
-            self.qi, self.ki, self.flags = (ref[t] for ref in sched)
+    def __init__(self, sched, classes, tile: int):
+        at = pl.program_id(1)
+        self.qi, self.ki, self.flags = (ref[at] for ref in sched)
+        self.classes, self.tile = classes, tile
 
     def first(self):
-        if self.sched is None:
-            return self.inner == 0
         return (self.flags & 1) != 0
 
     def last(self):
-        if self.sched is None:
-            return self.inner == self.n_inner - 1
         return (self.flags & 2) != 0
 
-    def run(self):
-        if self.sched is not None:
-            return (self.flags & 4) != 0
-        if self.mask.causal:
-            # A kv block strictly above the diagonal contributes nothing:
-            # `live_blocks` for one key row a query (`pick_blocks` refuses
-            # another shape), as a comparison of the program ids.
-            return (self.ki * self.block_k
-                    <= self.qi * self.block_q + self.block_q - 1)
-        return self.ki >= 0  # traced always-true (pl.when needs a traced pred)
+    def pieces(self, piece):
+        """``piece(s, i, strip, along, cut)`` for every strip ``s`` of
+        the step's block that has a live sub-tile, in one piece: ``strip``
+        the strip's slice of the block's side, ``along`` its live
+        sub-tiles' slice of the other side, beginning at sub-tile ``i``;
+        ``cut`` where an edge of the mask crosses the piece. One straight
+        body a class, so that a strip's products run beside its
+        neighbour's softmax; a step of no class computes nothing."""
+        t = self.tile
+
+        def body(kind):
+            for s, (a, b, cut) in enumerate(kind):
+                if b > a:
+                    piece(s, a, pl.ds(s * t, t), pl.ds(a * t, (b - a) * t),
+                          cut)
+
+        for number, kind in enumerate(self.classes, 1):
+            pl.when(self.flags >> 2 == number)(functools.partial(body, kind))
 
 
 def _call(mask, kernel, dims, kv_major, ins, outs, out_shape, scratch,
           interpret, operands):
-    """One ``pallas_call``: the rectangular grid ``(bh, outer, inner)`` for
-    a plain mask; for a windowed one the scheduled grid ``(bh, steps)``
-    with the schedule as prefetched scalars. ``ins``/``outs`` give each
-    operand's block shape and what indexes it: the query block (``"q"``),
-    the key block (``"k"``) or nothing (``"row"``: a whole lse row)."""
-    bh, s_q, s_k, block_q, block_k = dims
-    nq, nk = s_q // block_q, s_k // block_k
-    kernel = functools.partial(kernel, n_inner=nq if kv_major else nk)
-    if mask.window:
-        sched = _schedule(mask, s_q, s_k, block_q, block_k, kv_major)
-        index = {"q": lambda bh, t, qs, ks, fl: (bh, qs[t], 0),
-                 "k": lambda bh, t, qs, ks, fl: (bh, ks[t], 0),
-                 "row": lambda bh, t, qs, ks, fl: (bh, 0, 0)}
-    elif kv_major:
-        index = {"q": lambda bh, ki, qi: (bh, qi, 0),
-                 "k": lambda bh, ki, qi: (bh, ki, 0),
-                 "row": lambda bh, ki, qi: (bh, 0, 0)}
-    else:
-        index = {"q": lambda bh, qi, ki: (bh, qi, 0),
-                 "k": lambda bh, qi, ki: (bh, ki, 0),
-                 "row": lambda bh, qi, ki: (bh, 0, 0)}
+    """One ``pallas_call`` over the grid ``(bh, steps)`` with the schedule
+    as prefetched scalars, its classes and the sub-tile's side as the
+    kernel's ``classes`` and ``t``; ``dims`` is ``(bh, s_q, s_k, block_q,
+    block_k, t)``.
+    ``ins``/``outs`` give each operand's block shape and what indexes it:
+    the query block (``"q"``), the key block (``"k"``) or nothing
+    (``"row"``: a whole lse row)."""
+    bh, s_q, s_k, block_q, block_k, t = dims
+    sched, classes = _schedule(mask, s_q, s_k, block_q, block_k, t, kv_major)
+    index = {"q": lambda bh, at, qs, ks, fl: (bh, qs[at], 0),
+             "k": lambda bh, at, qs, ks, fl: (bh, ks[at], 0),
+             "row": lambda bh, at, qs, ks, fl: (bh, 0, 0)}
 
     def spec(shape_by):
         return pl.BlockSpec(shape_by[0], index[shape_by[1]])
 
-    in_specs = [spec(x) for x in ins]
-    out_specs = [spec(x) for x in outs] if isinstance(outs, list) else (
-        spec(outs)
-    )
-    if not mask.window:
-        return pl.pallas_call(
-            functools.partial(kernel, None),
-            grid=(bh, nk, nq) if kv_major else (bh, nq, nk),
-            in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
-            scratch_shapes=scratch, interpret=interpret,
-        )(*operands)
     return pl.pallas_call(
-        lambda qs, ks, fl, *refs: kernel((qs, ks, fl), *refs),
+        lambda qs, ks, fl, *refs: kernel((qs, ks, fl), *refs, t=t,
+                                         classes=classes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(sched[0])),
-            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch,
+            in_specs=[spec(x) for x in ins],
+            out_specs=[spec(x) for x in outs] if isinstance(outs, list) else (
+                spec(outs)
+            ),
+            scratch_shapes=scratch,
         ),
         out_shape=out_shape, interpret=interpret,
     )(*sched, *operands)
+
+
+def _logits(mask, a, b, row0, col0, cut, keys_first=False):
+    """A piece's scores ``a b^T``: queries by keys, or keys by queries
+    with ``keys_first`` (the queries carry the scale); the piece's first
+    query is ``row0`` and its first key row ``col0``. Where an edge of the
+    mask has ``cut`` the piece, the pairs the mask forbids are at
+    ``_NEG_INF``; else the scores are bare."""
+    logits = jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    if not cut:
+        return logits
+    n_queries, n_keys = logits.shape[::-1] if keys_first else logits.shape
+    return jnp.where(
+        mask.piece(row0, col0, n_queries, n_keys, keys_first),
+        logits, _NEG_INF,
+    )
+
+
+def _weights(logits, rows_max, cut):
+    """``exp(logits - rows_max)``, exactly 0 at the pairs masked in a
+    ``cut`` piece (a row with nothing seen yet has ``rows_max`` at
+    ``_NEG_INF`` too)."""
+    p = jnp.exp(logits - rows_max)
+    return jnp.where(logits <= _NEG_INF / 2, 0.0, p) if cut else p
+
+
+def _across(stat, width: int):
+    """A row statistic kept in every lane, ``[rows, _LANES]``, across
+    ``width`` columns. Copies of whole lane tiles, where a ``[rows, 1]``
+    column would be spread over the lanes anew at every use."""
+    if width <= _LANES:
+        return stat[:, :width]
+    if width % _LANES:
+        return stat[:, :1]
+    return pltpu.repeat(stat, width // _LANES, axis=1)
 
 
 # ---------------------------------------------------------------- forward
 
 
 def _fwd_kernel(sched, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
-                *, scale, mask, block_q, block_k, n_inner):
-    step = _Step(mask, sched, block_q, block_k, n_inner)
+                *, scale, mask, block_q, block_k, t, classes):
+    step = _Step(sched, classes, t)
     qi, ki = step.qi, step.ki
 
     @pl.when(step.first())
@@ -371,49 +452,68 @@ def _fwd_kernel(sched, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    @pl.when(step.run())
-    def _():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        if mask.causal:
-            logits = jnp.where(
-                mask.in_block(qi, ki, block_q, block_k), logits, _NEG_INF
-            )
-        m_prev = m_s[:, 0]
-        chunk_m = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m_prev, chunk_m)
-        p = jnp.exp(logits - m_new[:, None])
-        if mask.causal:
-            p = jnp.where(logits <= _NEG_INF / 2, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[:, 0] = l_s[:, 0] * corr + jnp.sum(p, axis=-1)
-        m_s[:, 0] = m_new
-        pv = jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+    def piece(r, c, rows, cols, cut):
+        """One online-softmax update of a sub-row of queries over the
+        keys of its live sub-tiles."""
+        logits = _logits(
+            mask, q_ref[0, rows, :].astype(jnp.float32) * scale,
+            k_ref[0, cols, :].astype(jnp.float32),
+            qi * block_q + r * t, ki * block_k + c * t, cut,
         )
-        acc[:] = acc[:] * corr[:, None] + pv
+        m_prev = m_s[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = _weights(logits, _across(m_new, cols.size), cut)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[rows, :] = l_s[rows, :] * corr + jnp.sum(
+            p, axis=-1, keepdims=True
+        )
+        m_s[rows, :] = m_new
+        pv = jax.lax.dot_general(
+            p, v_ref[0, cols, :].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        acc[rows, :] = acc[rows, :] * _across(corr, acc.shape[1]) + pv
+
+    step.pieces(piece)
 
     @pl.when(step.last())
     def _():
-        l = l_s[:, 0]
+        l = l_s[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc[:] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc[:] / _across(l_safe, acc.shape[1])).astype(
+            o_ref.dtype
+        )
         # lse blocks span the full row (TPU tiling forbids a (1, block_q)
         # block over [B*H, S]); each qi writes its slice.
         lse_ref[0, 0, pl.dslice(qi * block_q, block_q)] = (
-            m_s[:, 0] + jnp.log(l_safe)
+            m_s[:, 0] + jnp.log(l_safe[:, 0])
         )
 
 
+def _blocks(mask, s_q, s_k, block_q, block_k) -> tuple:
+    """``(block_q, block_k, t)``: the blocks a call runs with and the side
+    of the sub-tiles its kernels compute them in."""
+    block_q, block_k = pick_blocks(mask, s_q, s_k, block_q, block_k)
+    return block_q, block_k, sub_tile(block_q, block_k)
+
+
 def _flash_fwd(q, k, v, mask, block_q, block_k, interpret):
+    return _fwd_call(
+        q, k, v, mask,
+        _blocks(mask, q.shape[1], k.shape[1], block_q, block_k), interpret,
+    )
+
+
+# The calls are jitted: the layers of a stack, and a layer's forward pass
+# and its recomputation, ask for the same call, and a jitted function is
+# traced once for a mask and shape where a bare ``pallas_call`` is traced
+# wherever it stands (a kernel body holds a piece for every strip of
+# every class of block: set-up time).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _fwd_call(q, k, v, mask, blocks, interpret):
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q, block_k = pick_blocks(mask, sq, sk, block_q, block_k)
+    block_q, block_k, t = blocks
     scale = 1.0 / np.sqrt(d)
     qf = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, d)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, d)
@@ -431,7 +531,7 @@ def _flash_fwd(q, k, v, mask, block_q, block_k, interpret):
     )
     q_block, k_block = ((1, block_q, d), "q"), ((1, block_k, d), "k")
     o, lse = _call(
-        mask, kernel, (b * h, sq, sk, block_q, block_k), False,
+        mask, kernel, (b * h, sq, sk, block_q, block_k, t), False,
         [q_block, k_block, k_block],
         [q_block, ((1, 1, sq), "row")],
         [
@@ -446,41 +546,49 @@ def _flash_fwd(q, k, v, mask, block_q, block_k, interpret):
 # ---------------------------------------------------------------- backward
 
 
+def _row_of(ref, start, size):
+    """The float32 values a row (lse, delta) of the ``size`` queries from
+    ``start``, a whole sub-tile's first, as the ref lays them: ``[1,
+    size]``."""
+    return ref[0, :, pl.dslice(start, size)]
+
+
 def _bwd_dq_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, acc, *, scale, mask, block_q, block_k, n_inner):
-    step = _Step(mask, sched, block_q, block_k, n_inner)
+                   dq_ref, acc, *, scale, mask, block_q, block_k, t,
+                   classes):
+    step = _Step(sched, classes, t)
     qi, ki = step.qi, step.ki
 
     @pl.when(step.first())
     def _():
         acc[:] = jnp.zeros_like(acc)
 
-    @pl.when(step.run())
-    def _():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+    def piece(r, c, rows, cols, cut):
+        row0 = pl.multiple_of(qi * block_q + r * t, t)
+        k = k_ref[0, cols, :].astype(jnp.float32)
+        logits = _logits(
+            mask, q_ref[0, rows, :].astype(jnp.float32) * scale, k,
+            row0, ki * block_k + c * t, cut,
         )
-        if mask.causal:
-            logits = jnp.where(
-                mask.in_block(qi, ki, block_q, block_k), logits, _NEG_INF
-            )
-        lse = lse_ref[0, 0, pl.dslice(qi * block_q, block_q)]
-        p = jnp.exp(logits - lse[:, None])
-        if mask.causal:
-            p = jnp.where(logits <= _NEG_INF / 2, 0.0, p)
+        # the sub-row's lse and delta, turned to columns and kept in
+        # every lane
+        lse, delta = (
+            jnp.broadcast_to(_row_of(ref, row0, t).T, (t, _LANES))
+            for ref in (lse_ref, delta_ref)
+        )
+        p = _weights(logits, _across(lse, cols.size), cut)
         dp = jax.lax.dot_general(
-            do_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
+            do_ref[0, rows, :].astype(jnp.float32),
+            v_ref[0, cols, :].astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         )
-        delta = delta_ref[0, 0, pl.dslice(qi * block_q, block_q)]
-        ds = p * (dp - delta[:, None])
-        acc[:] += jax.lax.dot_general(
+        ds = p * (dp - _across(delta, cols.size))
+        acc[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
+
+    step.pieces(piece)
 
     @pl.when(step.last())
     def _():
@@ -489,8 +597,10 @@ def _bwd_dq_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, mask, block_q, block_k, n_inner):
-    step = _Step(mask, sched, block_q, block_k, n_inner, kv_major=True)
+                    scale, mask, block_q, block_k, t, classes):
+    """Keys by queries throughout: lse and delta are then rows, as their
+    refs hold them, and every product is a plain ``a b`` or ``a b^T``."""
+    step = _Step(sched, classes, t)
     qi, ki = step.qi, step.ki
 
     @pl.when(step.first())
@@ -498,39 +608,32 @@ def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(step.run())
-    def _():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        if mask.causal:
-            logits = jnp.where(
-                mask.in_block(qi, ki, block_q, block_k), logits, _NEG_INF
-            )
-        lse = lse_ref[0, 0, pl.dslice(qi * block_q, block_q)]
-        p = jnp.exp(logits - lse[:, None])
-        if mask.causal:
-            p = jnp.where(logits <= _NEG_INF / 2, 0.0, p)
-        do = do_ref[0].astype(jnp.float32)
+    def piece(c, r, cols, rows, cut):
+        row0 = pl.multiple_of(qi * block_q + r * t, t)
+        q = q_ref[0, rows, :].astype(jnp.float32) * scale
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        logits = _logits(
+            mask, k_ref[0, cols, :].astype(jnp.float32), q,
+            row0, ki * block_k + c * t, cut, keys_first=True,
+        )  # [keys, queries]
+        p = _weights(logits, _row_of(lse_ref, row0, rows.size), cut)
         # dv += p^T @ do
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+        dv_acc[cols, :] += jax.lax.dot_general(
+            p, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            v_ref[0, cols, :].astype(jnp.float32), do,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         )
-        delta = delta_ref[0, 0, pl.dslice(qi * block_q, block_q)]
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - _row_of(delta_ref, row0, rows.size))
         # dk += ds^T @ (q * scale)  — q already carries the scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+        dk_acc[cols, :] += jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    step.pieces(piece)
 
     @pl.when(step.last())
     def _():
@@ -539,10 +642,19 @@ def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(mask, block_q, block_k, interpret, res, g):
+    q, k = res[:2]
+    return _bwd_calls(
+        mask, _blocks(mask, q.shape[1], k.shape[1], block_q, block_k),
+        interpret, res, g,
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _bwd_calls(mask, blocks, interpret, res, g):
     q, k, v, o, lse = res
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q, block_k = pick_blocks(mask, sq, sk, block_q, block_k)
+    block_q, block_k, t = blocks
     scale = 1.0 / np.sqrt(d)
     qf = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, d)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, d)
@@ -554,7 +666,7 @@ def _flash_bwd(mask, block_q, block_k, interpret, res, g):
         dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1
     )[:, None, :]  # [B*H, 1, S] — matches the lse layout
 
-    dims = (b * h, sq, sk, block_q, block_k)
+    dims = (b * h, sq, sk, block_q, block_k, t)
     q_block, k_block = ((1, block_q, d), "q"), ((1, block_k, d), "k")
     row = ((1, 1, sq), "row")
     ins = [q_block, k_block, k_block, q_block, row, row]
@@ -659,16 +771,16 @@ def count_pairs(mask: AttentionMask, batch_heads: int, s_q: int, s_k: int,
                 block_q: int, block_k: int):
     """Raise the program's ``attn.pairs`` counter by what one attention
     call of this shape is asked for (``kind=allowed``: the pairs the mask
-    allows) and what its grid runs (``kind=computed``: every pair of every
-    block it does not skip). Called where the call is built, so once a
-    trace, not once a step; ``seq`` keeps calls of different lengths
-    apart."""
-    block_q, block_k = pick_blocks(mask, s_q, s_k, block_q, block_k)
-    live = int(mask.live_blocks(s_q, s_k, block_q, block_k).sum())
+    allows) and what its kernels do (``kind=computed``: the pairs of the
+    live sub-tiles, which are what the bodies compute). Called where the
+    call is built, so once a trace, not once a step; ``seq`` keeps calls
+    of different lengths apart."""
+    t = _blocks(mask, s_q, s_k, block_q, block_k)[2]
+    live = int(mask.tiles(s_q, s_k, t, t)[0].sum())
     tracer = get_tracer()
     tracer.count("attn.pairs", batch_heads * mask.pairs(s_q, s_k),
                  kind="allowed", seq=s_q)
-    tracer.count("attn.pairs", batch_heads * live * block_q * block_k,
+    tracer.count("attn.pairs", batch_heads * live * t * t,
                  kind="computed", seq=s_q)
 
 

@@ -113,7 +113,8 @@ SPANS: Dict[str, tuple] = {
                    "counter, by kind and seq, raised where a flash-attention "
                    "call is built (once a trace, not once a step): "
                    "kind=allowed the query-key pairs its mask allows, "
-                   "kind=computed every pair of every block its grid runs"),
+                   "kind=computed the pairs of the live sub-tiles of the "
+                   "blocks its grid runs (what the kernel bodies compute)"),
     "attn.residuals": ("kernels", "whichever traces the step",
                        "counter, by kind, raised where a model builds the "
                        "flash-attention call of a remat'ed block (once a "

@@ -1,8 +1,9 @@
 """The attention mask as a description (``ops/attention.AttentionMask``):
 the kernels against the dense oracle under a windowed mask with and
-without summary rows, the plain causal mask unchanged to the bit, the
-schedule of non-empty blocks, the chunk summaries against a loop, and the
-``eva`` mixer of ``models/llama.py``. Toy sizes, interpret mode."""
+without summary rows, the plain masks by either spelling, the schedule of
+non-empty blocks and of their live and cut sub-tiles, the chunk summaries
+against a loop, and the ``eva`` mixer of ``models/llama.py``. Toy sizes,
+interpret mode."""
 
 import dataclasses
 
@@ -148,10 +149,11 @@ class TestBlocks:
         np.testing.assert_array_equal(
             mask.live_blocks(seq, keys, block_q, block_k), want
         )
-        qs, ks, flags = attention._schedule(
-            mask, seq, keys, block_q, block_k, kv_major
+        (qs, ks, flags), _ = attention._schedule(
+            mask, seq, keys, block_q, block_k,
+            attention.sub_tile(block_q, block_k), kv_major
         )
-        computing = flags & 4 != 0
+        computing = flags >> 2 != 0
         visited = np.zeros_like(want)
         visited[qs[computing], ks[computing]] = True
         np.testing.assert_array_equal(visited, want)
@@ -172,21 +174,6 @@ class TestBlocks:
         live = AttentionMask().live_blocks(128, 128, 32, 32)
         np.testing.assert_array_equal(live, np.tril(np.ones((4, 4), bool)))
         assert AttentionMask(causal=False).live_blocks(64, 64, 32, 32).all()
-
-    @pytest.mark.parametrize("seq, block_q, block_k", [
-        (128, 32, 32), (128, 16, 64), (128, 64, 16), (96, 32, 8),
-    ])
-    def test_the_rectangular_grid_runs_the_live_blocks(
-        self, seq, block_q, block_k
-    ):
-        """The comparison of program ids that the plain causal kernels
-        skip by (``_Step.run``) is ``live_blocks``, block for block."""
-        qi = np.arange(seq // block_q)[:, None]
-        ki = np.arange(seq // block_k)[None, :]
-        np.testing.assert_array_equal(
-            ki * block_k <= qi * block_q + block_q - 1,
-            AttentionMask().live_blocks(seq, seq, block_q, block_k),
-        )
 
     def test_the_causal_kernels_want_a_key_row_a_query(self):
         """The description aligns the causal mask at the end (as the
@@ -263,10 +250,11 @@ class TestTheScheduleAtTheCellsShapes:
         np.testing.assert_array_equal(
             mask.live_blocks(seq, keys, self.BLOCK, self.BLOCK), want
         )
-        qs, ks, flags = attention._schedule(
-            mask, seq, keys, self.BLOCK, self.BLOCK, kv_major
+        (qs, ks, flags), _ = attention._schedule(
+            mask, seq, keys, self.BLOCK, self.BLOCK,
+            attention.sub_tile(self.BLOCK, self.BLOCK), kv_major
         )
-        computing = flags & 4 != 0
+        computing = flags >> 2 != 0
         visited = np.zeros_like(want)
         visited[qs[computing], ks[computing]] = True
         np.testing.assert_array_equal(visited, want)
@@ -283,7 +271,8 @@ class TestTheScheduleAtTheCellsShapes:
     def test_the_mask_inside_each_block_it_runs(self, shape):
         seq, mask, keys, want, blocks, pairs = shape
         in_block = jax.jit(
-            lambda qi, ki: mask.in_block(qi, ki, self.BLOCK, self.BLOCK)
+            lambda qi, ki: mask.piece(qi * self.BLOCK, ki * self.BLOCK,
+                                      self.BLOCK, self.BLOCK)
         )
         assert len(blocks) == want.sum()
         for (qi, ki), block in blocks.items():
@@ -362,7 +351,6 @@ class TestKernelsAgainstTheOracle:
             *a, causal=causal, block_q=32, block_k=64))(q, k, v))
         assert text == str(jax.make_jaxpr(lambda *a: flash_attention(
             *a, mask=mask, block_q=32, block_k=64))(q, k, v))
-        assert "num_scalar_prefetch=0" in text or "prefetch" not in text
 
 
 class TestSummaries:
@@ -428,6 +416,7 @@ class TestTheCounter:
         totals = [e for e in fresh.events if e["name"] == "attn.pairs"][-1]
         live = mask.live_blocks(96, 112, 16, 16).sum()
         assert totals["ph"] == "C"
+        # blocks of 16 are one sub-tile each: what is computed is the blocks
         assert totals["args"] == {
             "kind=allowed,seq=96": H * mask.pairs(96, 112),
             "kind=computed,seq=96": H * live * 16 * 16,
@@ -627,10 +616,11 @@ class TestTheSlidingKind:
         np.testing.assert_array_equal(
             mask.live_blocks(seq, seq, block_q, block_k), want
         )
-        qs, ks, flags = attention._schedule(
-            mask, seq, seq, block_q, block_k, kv_major
+        (qs, ks, flags), _ = attention._schedule(
+            mask, seq, seq, block_q, block_k,
+            attention.sub_tile(block_q, block_k), kv_major
         )
-        assert (flags & 4 != 0).all()       # no row of blocks is empty
+        assert (flags >> 2 != 0).all()      # no row of blocks is empty
         visited = np.zeros_like(want)
         visited[qs, ks] = True
         np.testing.assert_array_equal(visited, want)
@@ -674,3 +664,264 @@ class TestTheSlidingKind:
         assert totals[f"kind=allowed,seq={seq}"] == H * mask.pairs(seq, seq)
         live = mask.live_blocks(seq, seq, 16, 16).sum()
         assert totals[f"kind=computed,seq={seq}"] == H * live * 16 * 16
+
+
+# ------------------------------------------------------------ sub-tiles
+
+def _allowed_rows(mask, rows, s_q, s_k):
+    """``[len(rows), s_k]``: what these query rows see, from each kind's
+    equations and not from ``bounds``."""
+    i, col = np.asarray(rows)[:, None], np.arange(s_k)[None, :]
+    if not mask.causal:
+        return np.ones((len(rows), s_k), dtype=bool)
+    if not mask.window:
+        return col <= i
+    if mask.sliding:
+        return (col <= i) & (col > i - mask.window)
+    return _rows_by_definition(rows, s_q, mask.window, mask.chunk or 1,
+                               mask.summaries)
+
+
+def _decode(mask, s_q, s_k, block_q, block_k, kv_major):
+    """The schedule read back: the sub-tile's side, and ``[s_q / t, s_k /
+    t]`` booleans of the sub-tiles it has the kernels compute and of
+    those it has them compute under the mask. Held on the way: a strip's
+    run is under the mask exactly where an edge crosses a sub-tile of
+    it."""
+    t = attention.sub_tile(block_q, block_k)
+    (qs, ks, flags), classes = attention._schedule(
+        mask, s_q, s_k, block_q, block_k, t, kv_major
+    )
+    nr, nc = block_q // t, block_k // t
+    n_strips, n_along = (nc, nr) if kv_major else (nr, nc)
+    whole = mask.tiles(s_q, s_k, t, t)[1]
+    assert classes
+    assert len(set(classes)) == len(classes)
+    assert set(flags >> 2) - {0} == set(range(1, len(classes) + 1))
+    live = np.zeros((s_q // t, s_k // t), dtype=bool)
+    masked = np.zeros_like(live)
+    for qi, ki, flag in zip(qs, ks, flags):
+        if not flag >> 2:
+            continue                            # no compute: nothing live
+        kind = classes[(flag >> 2) - 1]
+        assert len(kind) == n_strips
+        for s, (a, b, cut) in enumerate(kind):
+            assert 0 <= a <= b <= n_along
+            run = [((i, s) if kv_major else (s, i)) for i in range(a, b)]
+            run = [(qi * nr + r, ki * nc + c) for r, c in run]
+            assert cut == any(not whole[at] for at in run)
+            for at in run:
+                assert not live[at]             # each sub-tile once
+                live[at], masked[at] = True, cut
+    assert not (live & ~whole & ~masked).any()
+    return t, live, masked
+
+
+_TOY_MASKS = {
+    "causal": (AttentionMask(), 128),
+    "full": (AttentionMask(causal=False), 64),
+    "windows+summaries": (eva_mask(128, W, C, 32), 128),
+    "windows": (AttentionMask(window=W), 96),
+    "sliding": (AttentionMask(window=W, sliding=True), 128),
+    "sliding, window no multiple": (AttentionMask(window=24, sliding=True),
+                                    96),
+}
+# block_q, block_k, the module's sub-tile: blocks that are several
+# sub-tiles, one, and smaller than the constant (the sub-tile is then
+# their largest common divisor)
+_TOY_BLOCKS = [(32, 32, 8), (32, 32, 16), (32, 32, 32), (32, 16, 16),
+               (16, 32, 8), (16, 16, 256)]
+
+
+class TestTheSubTiles:
+    """The schedule's second level: of each block it lists, the sub-tiles
+    that are live and the strips of them the mask's edge cuts, from the
+    same ``bounds`` as everything else; the kernels compute the live ones
+    and mask the cut strips only."""
+
+    @pytest.mark.parametrize("blocks", [(1024, 1024), (256, 512), (48, 16),
+                                        (8, 64)])
+    def test_the_sub_tile_divides_the_block(self, blocks):
+        t = attention.sub_tile(*blocks)
+        assert blocks[0] % t == 0 and blocks[1] % t == 0
+        assert t == {1024: 256, 256: 256, 48: 16, 8: 8}[blocks[0]]
+        assert attention._SUB_TILE == 256
+
+    @pytest.mark.parametrize("kind", list(_TOY_MASKS))
+    @pytest.mark.parametrize("block_q, block_k, tile", _TOY_BLOCKS)
+    @pytest.mark.parametrize("kv_major", [False, True])
+    def test_dead_holds_no_pair_and_whole_no_masked_one(
+        self, monkeypatch, kind, block_q, block_k, tile, kv_major
+    ):
+        monkeypatch.setattr(attention, "_SUB_TILE", tile)
+        mask, seq = _TOY_MASKS[kind]
+        keys = seq + mask.summaries
+        t, live, masked = _decode(mask, seq, keys, block_q, block_k,
+                                  kv_major)
+        assert t == min(tile, block_q, block_k)
+        dense = np.asarray(mask.dense(seq, keys))
+        np.testing.assert_array_equal(
+            dense, _allowed_rows(mask, np.arange(seq), seq, keys))
+        by_tile = dense.reshape(seq // t, t, keys // t, t)
+        # a dead sub-tile holds no allowed pair, one run bare no masked one
+        np.testing.assert_array_equal(live, by_tile.any(axis=(1, 3)))
+        assert by_tile.all(axis=(1, 3))[live & ~masked].all()
+        # the description's two halves are the dense mask's, tile by tile
+        in_tiles, whole = mask.tiles(seq, keys, t, t)
+        np.testing.assert_array_equal(live, in_tiles)
+        np.testing.assert_array_equal(whole, by_tile.all(axis=(1, 3)))
+        # and the counter's computed pairs are the area of what runs
+        fresh = tracing.Tracer()
+        monkeypatch.setattr(tracing, "_tracer", fresh)
+        attention.count_pairs(mask, 1, seq, keys, block_q, block_k)
+        pairs = [e["args"] for e in fresh.events
+                 if e["name"] == "attn.pairs"][-1]
+        assert pairs[f"kind=computed,seq={seq}"] == live.sum() * t * t
+        assert pairs[f"kind=allowed,seq={seq}"] == dense.sum()
+        # and the first level is the second folded: a block is walked
+        # where a sub-tile of it is live
+        np.testing.assert_array_equal(
+            live.reshape(seq // block_q, block_q // t,
+                         keys // block_k, block_k // t).any(axis=(1, 3)),
+            mask.live_blocks(seq, keys, block_q, block_k),
+        )
+
+    @pytest.mark.parametrize("kind", list(_TOY_MASKS))
+    @pytest.mark.parametrize("block_q, block_k, tile", _TOY_BLOCKS[:5])
+    def test_forward_and_the_three_gradients(self, monkeypatch, kind,
+                                             block_q, block_k, tile):
+        monkeypatch.setattr(attention, "_SUB_TILE", tile)
+        mask, seq = _TOY_MASKS[kind]
+        q, k, v = _qkv(seq, seq + mask.summaries, seed=7)
+        want, want_g = _loss_and_grads(
+            lambda *a: reference_attention(*a, mask=mask), q, k, v
+        )
+        got, got_g = _loss_and_grads(
+            lambda *a: flash_attention(*a, mask=mask, block_q=block_q,
+                                       block_k=block_k), q, k, v,
+        )
+        np.testing.assert_allclose(got, want, atol=2e-6)
+        for g, w in zip(got_g, want_g):
+            np.testing.assert_allclose(g, w, atol=2e-5)
+
+    def test_the_counter_counts_the_sub_tiles_that_run(self, monkeypatch):
+        monkeypatch.setattr(attention, "_SUB_TILE", 8)
+        fresh = tracing.Tracer()
+        monkeypatch.setattr(tracing, "_tracer", fresh)
+        attention.count_pairs(AttentionMask(), 3, 128, 128, 32, 32)
+        pairs = [e["args"] for e in fresh.events
+                 if e["name"] == "attn.pairs"][-1]
+        # 4 diagonal blocks of 4 x 4 sub-tiles, 10 on or under the
+        # diagonal; 6 blocks under it, whole
+        assert pairs["kind=computed,seq=128"] == 3 * (4 * 10 + 6 * 16) * 64
+        assert pairs["kind=allowed,seq=128"] == 3 * 128 * 129 // 2
+        _, live, _ = _decode(AttentionMask(), 128, 128, 32, 32, False)
+        assert pairs["kind=computed,seq=128"] == 3 * live.sum() * 64
+
+    def test_a_description_whose_strips_are_no_run_is_refused(self):
+        with pytest.raises(ValueError, match="no single run"):
+            attention._run_of(np.array([True, False, True]))
+        assert attention._run_of(np.zeros(4, dtype=bool)) == (0, 0)
+        assert attention._run_of(np.array([0, 1, 1, 0])) == (1, 3)
+
+
+class TestTheSubTilesAtTheCellsShapes:
+    """Blocks of 1024 and the module's sub-tile, at the shapes the cells
+    run: gpt2-xl's one block a head, mistral's 16384 causal, Trinity's
+    sliding layers, EvaByte's windows and summaries at 32768. Sub-row by
+    sub-row against each kind's equations, and the counters' readings that
+    docs/attention_masks.md's table states."""
+
+    BLOCK = 1024
+    SHAPES = {
+        # mask, queries, key rows; then a head's whole and cut blocks and
+        # its computed pairs in blocks' worth
+        "gpt2-xl": (AttentionMask(), 1024, 1024, 0, 1, 0.625),
+        "mistral": (AttentionMask(), 16384, 16384, 120, 16, 130.0),
+        "trinity sliding": (AttentionMask(window=4096, sliding=True),
+                            16384, 16384, 42, 28, 59.5),
+        "evabyte": (AttentionMask(window=2048, summaries=2048, chunk=16),
+                    32768, 34816, 32, 60, 68.0),
+    }
+
+    @pytest.mark.parametrize("cell", list(SHAPES))
+    @pytest.mark.parametrize("kv_major", [False, True])
+    def test_the_description_pair_by_pair(self, cell, kv_major):
+        mask, seq, keys = self.SHAPES[cell][:3]
+        t, live, masked = _decode(mask, seq, keys, self.BLOCK, self.BLOCK,
+                                  kv_major)
+        assert t == attention._SUB_TILE
+        for r in range(seq // t):
+            rows = _allowed_rows(
+                mask, np.arange(r * t, (r + 1) * t), seq, keys
+            ).reshape(t, keys // t, t)
+            np.testing.assert_array_equal(
+                live[r], rows.any(axis=(0, 2)), err_msg=f"sub-row {r}")
+            bare = live[r] & ~masked[r]
+            assert rows.all(axis=(0, 2))[bare].all(), f"sub-row {r}"
+
+    @pytest.mark.parametrize("cell", list(SHAPES))
+    def test_the_schedule_and_the_counter_read_the_table(self, monkeypatch,
+                                                         cell):
+        mask, seq, keys, whole, cut, worth = self.SHAPES[cell]
+        live, bare = mask.tiles(seq, keys, self.BLOCK, self.BLOCK)
+        assert (bare.sum(), (live & ~bare).sum()) == (whole, cut)
+        # the schedule walks them, and a whole block is the class whose
+        # every strip runs all its sub-tiles bare
+        (_, _, flags), classes = attention._schedule(
+            mask, seq, keys, self.BLOCK, self.BLOCK,
+            attention.sub_tile(self.BLOCK, self.BLOCK), False)
+        n = self.BLOCK // attention._SUB_TILE
+        all_of_it = ((0, n, False),) * n
+        walked = [classes[(f >> 2) - 1] for f in flags if f >> 2]
+        assert len(walked) == whole + cut
+        assert sum(kind == all_of_it for kind in walked) == whole
+        fresh = tracing.Tracer()
+        monkeypatch.setattr(tracing, "_tracer", fresh)
+        attention.count_pairs(mask, 2, seq, keys, self.BLOCK, self.BLOCK)
+        pairs = [e["args"] for e in fresh.events
+                 if e["name"] == "attn.pairs"][-1]
+        assert pairs[f"kind=computed,seq={seq}"] == 2 * worth * 1024 ** 2
+        assert pairs[f"kind=allowed,seq={seq}"] == 2 * mask.pairs(seq, keys)
+
+
+class TestOneTraceACall:
+    """What keeps the bodies' size out of the set-up time: the calls are
+    jitted, so equal calls share a trace."""
+
+    def test_the_layers_of_a_stack_share_their_traces(self, monkeypatch):
+        traced = []
+        call = attention._call
+        monkeypatch.setattr(
+            attention, "_call",
+            lambda mask, kernel, *a: (traced.append(kernel.func.__name__),
+                                      call(mask, kernel, *a))[1],
+        )
+        # a mask of this test's own: no trace of it is there yet
+        mask = AttentionMask(window=24, sliding=True)
+        q, k, v = _qkv(128, seed=3)
+
+        @jax.checkpoint
+        def layer(x):
+            return flash_attention(x, k, v, mask=mask, block_q=32,
+                                   block_k=32)
+
+        # five layers, each run, recomputed and differentiated: the
+        # forward call is traced once as it is run and once as it is
+        # recomputed (two contexts to JAX), not once a layer
+        jax.make_jaxpr(jax.grad(
+            lambda x: layer(layer(layer(layer(layer(x))))).sum()
+        ))(q)
+        assert sorted(traced) == ["_bwd_dkv_kernel", "_bwd_dq_kernel",
+                                  "_fwd_kernel", "_fwd_kernel"]
+
+    def test_the_sub_tile_side_is_part_of_what_a_trace_is_kept_by(
+        self, monkeypatch
+    ):
+        q, k, v = _qkv(64, seed=4)
+        texts = []
+        for tile in (8, 16):
+            monkeypatch.setattr(attention, "_SUB_TILE", tile)
+            texts.append(str(jax.make_jaxpr(lambda *a: flash_attention(
+                *a, block_q=32, block_k=32))(q, k, v)))
+        assert texts[0] != texts[1]

@@ -45,38 +45,30 @@ def compiled_kernels(monkeypatch):
 
 
 class TestAotOnV5eTopology:
-    def test_flash_attention_fwd_bwd_at_xl_shape(
-        self, v5e_2x2, compiled_kernels
+    @pytest.mark.parametrize("shape, mask", [
+        ((4, 1024, 25, 64), {}),                # gpt2-xl: one block a head
+        ((1, 16384, 32, 128), {}),              # mistral: 136 of 256 blocks
+        ((1, 16384, 48, 128), {"window": 4096, "sliding": True}),
+        ((1, 32768, 32, 128), {"window": 2048, "summaries": 2048,
+                               "chunk": 16}),   # EvaByte: nine classes
+        ((1, 4096, 8, 128), {"causal": False}),  # every block, all whole
+    ], ids=["gpt2-xl", "mistral-16k", "trinity-sliding-16k", "evabyte-32k",
+            "not-causal"])
+    def test_flash_attention_fwd_bwd_at_the_cells_shapes(
+        self, v5e_2x2, compiled_kernels, shape, mask
     ):
-        from dlrover_tpu.ops.attention import flash_attention
-
-        x = jax.ShapeDtypeStruct(
-            (4, 1024, 25, 64), jnp.bfloat16,
-            sharding=SingleDeviceSharding(v5e_2x2[0]),
-        )
-
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, block_q=1024, block_k=1024)
-            return jnp.sum(out.astype(jnp.float32))
-
-        compiled = jax.jit(
-            jax.grad(loss, argnums=(0, 1, 2))
-        ).lower(x, x, x).compile()
-        assert _mosaic_calls(compiled) == 3  # fwd, dq, dkv
-
-    def test_flash_attention_under_a_sliding_window_at_16k(
-        self, v5e_2x2, compiled_kernels
-    ):
-        """The scheduled grid of a window that slides with the query, at
-        the widths and blocks of the cell that runs it (48 heads of 128,
-        1 x 16384, window 4096, blocks of 1024)."""
+        """The scheduled grid and the bodies of its classes of block, at
+        the widths and blocks of the cells that run them (1024 x 1024):
+        what Mosaic accepts of the sub-tiled pieces, their slices and the
+        lane-kept statistics. Three calls: fwd, dq, dkv."""
         from dlrover_tpu.ops.attention import AttentionMask, flash_attention
 
-        x = jax.ShapeDtypeStruct(
-            (1, 16384, 48, 128), jnp.bfloat16,
-            sharding=SingleDeviceSharding(v5e_2x2[0]),
-        )
-        mask = AttentionMask(window=4096, sliding=True)
+        one = SingleDeviceSharding(v5e_2x2[0])
+        mask = AttentionMask(**mask)
+        b, s, h, d = shape
+        q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+        kv = jax.ShapeDtypeStruct((b, s + mask.summaries, h, d),
+                                  jnp.bfloat16, sharding=one)
 
         def loss(q, k, v):
             out = flash_attention(q, k, v, mask=mask, block_q=1024,
@@ -85,7 +77,7 @@ class TestAotOnV5eTopology:
 
         compiled = jax.jit(
             jax.grad(loss, argnums=(0, 1, 2))
-        ).lower(x, x, x).compile()
+        ).lower(q, kv, kv).compile()
         assert _mosaic_calls(compiled) == 3  # fwd, dq, dkv
 
     def test_held_experts_over_the_pair_buffer_at_the_cells_widths(

@@ -22,6 +22,8 @@ from dlrover_tpu.ops import (
     reference_attention,
     ring_attention,
 )
+from dlrover_tpu.ops import attention
+from dlrover_tpu.ops.attention import AttentionMask
 
 
 def rand_qkv(key, b=2, s=128, h=2, d=32, dtype=jnp.float32):
@@ -55,6 +57,37 @@ class TestFlashAttention:
         g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for gf, gr in zip(g_flash, g_ref):
             np.testing.assert_allclose(gf, gr, rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("mask", [
+        AttentionMask(), AttentionMask(causal=False),
+        AttentionMask(window=64), AttentionMask(window=24, sliding=True),
+    ], ids=["causal", "full", "windows", "sliding"])
+    @pytest.mark.parametrize("seq, block", [(64, 64), (128, 32)],
+                             ids=["one-block-a-head", "many-blocks"])
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    def test_sub_tiled_kernels_at_the_cells_head_widths(
+        self, monkeypatch, mask, seq, block, head_dim
+    ):
+        """Forward and the three gradients at the head widths the cells
+        run (gpt2-xl's 64, the others' 128), with blocks of several
+        sub-tiles: a head's only block, cut by the diagonal (the steady
+        cell's shape cut down), and rows of blocks that are whole, cut
+        and empty."""
+        monkeypatch.setattr(attention, "_SUB_TILE", 16)
+        q, k, v = rand_qkv(jax.random.PRNGKey(head_dim + seq), b=1, s=seq,
+                           d=head_dim)
+
+        def grads_of(fn):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a, mask=mask) ** 2), argnums=(0, 1, 2)
+            )(q, k, v)
+
+        got, got_g = grads_of(functools.partial(
+            flash_attention, block_q=block, block_k=block))
+        want, want_g = grads_of(reference_attention)
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        for g, w in zip(got_g, want_g):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
 
     def test_uneven_blocks(self):
         """Sequence not divisible by the asked block size shrinks blocks."""
