@@ -342,6 +342,31 @@ def transfer_state(state, shardings):
     )
 
 
+def _count_replicated(abstract_params, shardings):
+    """Raise ``accel.replicated_params`` by the parameter leaves that no
+    mesh axis shards, and by their bytes: under fsdp the vectors a layer
+    that ``sharding.keep_vectors_whole`` keeps whole, and what no rule
+    shards (scalars)."""
+    import jax
+
+    from dlrover_tpu.utils.tracing import get_tracer
+
+    whole = [
+        leaf for leaf, sharding in zip(
+            jax.tree_util.tree_leaves(unbox(abstract_params)),
+            jax.tree_util.tree_leaves(shardings),
+        )
+        if all(axis is None for axis in sharding.spec)
+    ]
+    tracer = get_tracer()
+    tracer.count("accel.replicated_params", len(whole), kind="leaves")
+    tracer.count(
+        "accel.replicated_params",
+        sum(leaf.size * leaf.dtype.itemsize for leaf in whole),
+        kind="bytes",
+    )
+
+
 def auto_accelerate(
     module,
     optimizer,
@@ -477,6 +502,8 @@ def auto_accelerate(
 
             abstract = apply_zero(abstract, sp, rules)
         shardings = state_shardings(mesh, abstract, rules)
+        if sp.fsdp > 1:
+            _count_replicated(abstract["params"], shardings["params"])
         opt = optimizer
         if offload_optimizer:
             from dlrover_tpu.optim.offload import (

@@ -256,13 +256,16 @@ def state_bytes_per_device(abstract_state, spec) -> int:
     """Exact per-device train-state bytes for a candidate spec.
 
     Walks the abstract boxed pytree; each leaf's logical names map
-    through ``spec.rules()`` to mesh axes, and every sharded dim is
+    through ``spec.rules()`` to mesh axes (less the fsdp axis of a vector
+    a layer, as ``state_shardings`` places it), and every sharded dim is
     ceil-divided by the product of its mesh-axis sizes — the same
     arithmetic GSPMD performs, without building a mesh or compiling.
     ``zero`` specs first re-annotate the opt subtree exactly the way
     ``build`` will, so the memory model prices the sharded slices.
     """
     import jax
+
+    from dlrover_tpu.accel.sharding import keep_vectors_whole
 
     rules_seq = spec.rules()
     if getattr(spec, "zero", False) and getattr(spec, "data", 1) > 1:
@@ -280,16 +283,18 @@ def state_bytes_per_device(abstract_state, spec) -> int:
         shape = getattr(inner, "shape", ())
         dtype = getattr(inner, "dtype", None)
         itemsize = dtype.itemsize if dtype is not None else 4
+        axes = keep_vectors_whole(names, [
+            rules.get(names[i])
+            if names is not None and i < len(names) and names[i] else None
+            for i in range(len(shape))
+        ])
         n = 1
-        for i, dim in enumerate(shape):
+        for dim, mesh_axes in zip(shape, axes):
             div = 1
-            if names is not None and i < len(names) and names[i]:
-                mesh_axes = rules.get(names[i])
-                if mesh_axes is not None:
-                    if isinstance(mesh_axes, str):
-                        mesh_axes = (mesh_axes,)
-                    for ax in mesh_axes:
-                        div *= sizes.get(ax, 1)
+            if isinstance(mesh_axes, str):
+                mesh_axes = (mesh_axes,)
+            for ax in mesh_axes or ():
+                div *= sizes.get(ax, 1)
             n *= math.ceil(dim / div)
         return n * itemsize
 

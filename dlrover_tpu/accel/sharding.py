@@ -11,7 +11,8 @@ params/activations) to *mesh* axis names; GSPMD inserts the collectives:
         (``accel/zero.py``; params stay replicated — weight-update
         sharding from annotations alone)
 - FSDP: batch -> fsdp axis too; embed -> fsdp (params+opt state sharded,
-        all-gathered per layer = ZeRO-3)
+        all-gathered per layer = ZeRO-3), except a leaf that is one
+        vector a layer, which stays whole (``keep_vectors_whole``)
 - TP:   heads/mlp/vocab -> tensor axis (sharded matmuls, activation
         all-reduces — Megatron semantics without Megatron plumbing)
 - SP:   seq -> seq axis (ring attention over ICI, ``dlrover_tpu.ops``)
@@ -92,16 +93,50 @@ def logical_rules(
     return rules
 
 
+def keep_vectors_whole(names, axes) -> tuple:
+    """``axes`` (the mesh axes of a leaf's dims, one entry a dim) with
+    ``fsdp`` taken off a leaf that is one vector a layer: a single dim
+    besides the scanned ``layers`` axis (a LayerNorm scale or bias, a
+    projection's bias).
+
+    Sharded, such a vector saves a few kilobytes a chip and costs a
+    synchronous, latency-bound all-gather wherever a layer uses it, in the
+    forward and again where remat recomputes it; whole, it costs none, and
+    its gradient is reduced once a layer either way. gpt2-xl under
+    ``fsdp=4`` on a v5e 2x2: 418.1 ms a step with its vectors sharded,
+    406.5 whole (docs/zero.md)."""
+    axes = tuple(axes)
+    if names is None or sum(n != "layers" for n in names) != 1:
+        return axes
+    return tuple(None if a == "fsdp" else a for a in axes)
+
+
 def state_shardings(mesh, abstract_state, rules):
     """Map a (possibly flax-``Partitioned``-boxed) abstract pytree to
     ``NamedSharding``s. Opt-state leaves mirror their params' boxes because
     ``optax.init`` tree-maps over boxed leaves, so ZeRO-style optimizer
     sharding falls out for free (the reference needs a dedicated ZeRO
-    engine for this, ``zero_optimization.py:115``)."""
+    engine for this, ``zero_optimization.py:115``); the optimizer state of
+    a vector a layer follows it off the fsdp axis (``keep_vectors_whole``)
+    unless ZeRO-1 relabeled its ``layers`` dim."""
     import flax.linen as nn
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     specs = nn.get_partition_spec(abstract_state)
-    return nn.logical_to_mesh_sharding(specs, mesh, list(rules))
+    shardings = nn.logical_to_mesh_sharding(specs, mesh, list(rules))
+
+    def whole(leaf, sharding):
+        if not hasattr(leaf, "names"):
+            return sharding
+        spec = tuple(sharding.spec)
+        kept = keep_vectors_whole(leaf.names, spec)
+        return sharding if kept == spec else NamedSharding(mesh, P(*kept))
+
+    return jax.tree_util.tree_map(
+        whole, abstract_state, shardings,
+        is_leaf=lambda x: hasattr(x, "names"),
+    )
 
 
 def unbox(tree):

@@ -125,6 +125,13 @@ SPANS: Dict[str, tuple] = {
                        "forward kernel runs once a layer, kind=recomputed "
                        "its backward pass runs the forward kernel again "
                        "(nothing, offload)"),
+    "accel.replicated_params": ("accel", "whichever builds the step",
+                                "counter, by kind, raised once where a "
+                                "strategy's shardings are built under fsdp "
+                                "> 1: kind=leaves the parameter leaves no "
+                                "mesh axis shards (the vectors a layer FSDP "
+                                "keeps whole, scalars), kind=bytes their "
+                                "bytes"),
     "attn.summaries": ("model", "device (jax.named_scope)",
                        "eva mixer: one learned summary of k and of v a "
                        "chunk of positions"),

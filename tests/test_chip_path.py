@@ -31,6 +31,44 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _all_reduces_by_loop(text: str) -> dict:
+    """All-reduces inside the compiled module's ``while`` loops, by loop:
+    ``forward`` for the layer scan of the forward pass, ``backward`` for
+    its transpose (named so in the loop's ``op_name``)."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+
+    def reached(root):
+        seen, todo = set(), [root]
+        while todo:
+            comp = todo.pop()
+            if comp in seen or comp not in bodies:
+                continue
+            seen.add(comp)
+            for line in bodies[comp]:
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line
+                )
+        return seen
+
+    out = {}
+    for loop in re.finditer(r" while\(.*?body=%([\w.\-]+).*", text):
+        kind = "backward" if "transpose(" in loop.group(0) else "forward"
+        out[kind] = out.get(kind, 0) + sum(
+            bool(re.search(r" all-reduce(?:-start)?\(", line))
+            for comp in reached(loop.group(1)) for line in bodies[comp]
+        )
+    return out
+
+
 @pytest.fixture(scope="module")
 def v5e_2x2():
     return topologies.get_topology_desc(
@@ -219,6 +257,70 @@ class TestAotOnV5eTopology:
         # no second forward (tests/test_attention_residuals.py).
         assert _mosaic_calls(compiled) == 3
         assert " all-gather(" in compiled.as_text()
+
+
+    def test_fsdp_step_gathers_no_vector_inside_the_layer_loops(
+        self, v5e_2x2, compiled_kernels
+    ):
+        """gpt2-xl's widths at two scanned layers under ``fsdp=4``: the
+        step of the ``gpt2-xl.fsdp4`` cell but for its depth. A vector a
+        layer sharded over fsdp is gathered by a synchronous all-reduce
+        wherever a layer uses it (two a layer in the forward loop, two
+        more for the rematerialized forward in the backward loop, 180-310
+        µs each on the chip); kept whole it needs none, and the backward
+        loop keeps only the two that reduce the vectors' gradients. The
+        matrices' gathers and scatters are those of the step that sharded
+        every vector."""
+        from dlrover_tpu.accel import ParallelSpec
+        from dlrover_tpu.accel.accelerate import make_train_step
+        from dlrover_tpu.accel.mesh import create_mesh
+        from dlrover_tpu.accel.sharding import state_shardings, unbox
+        from dlrover_tpu.models.gpt import GPT, GPTConfig, loss_fn
+
+        cfg = GPTConfig(
+            vocab_size=50257, max_seq_len=1024, num_layers=2, num_heads=25,
+            d_model=1600, param_dtype=jnp.float32, remat=True,
+            remat_policy="dots", attn_impl="pallas", attn_block_q=1024,
+            attn_block_k=1024,
+        )
+        spec = ParallelSpec(fsdp=4)
+        mesh = create_mesh(spec.axes(), devices=v5e_2x2)
+        rules = spec.rules(vocab_size=cfg.vocab_size)
+        model, opt = GPT(cfg), optax.adamw(2e-4)
+        tokens = jnp.zeros((16, 1024), jnp.int32)
+
+        def init_fn(rng):
+            params = model.init(rng, tokens)["params"]
+            return {"params": params, "opt": opt.init(params),
+                    "step": jnp.zeros((), jnp.int32)}
+
+        def token_loss(module, params, batch):
+            return loss_fn(module.apply({"params": params}, batch), batch)
+
+        abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+        shardings = state_shardings(mesh, abstract, rules)
+        batch_sharding = NamedSharding(mesh, P(dict(rules)["batch"], None))
+        state = jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            unbox(abstract), shardings,
+        )
+        batch = jax.ShapeDtypeStruct(
+            tokens.shape, tokens.dtype, sharding=batch_sharding
+        )
+        text = make_train_step(
+            model, opt, token_loss, mesh, rules, shardings, batch_sharding
+        ).lower(state, batch).compile().as_text()
+        assert _all_reduces_by_loop(text) == {"forward": 0, "backward": 2}
+        matrices = {
+            "all-gather": len(re.findall(r" all-gather(?:-start)?\(", text)),
+            "reduce-scatter": text.count("calls=%all-reduce-scatter"),
+            "collective-permute": len(
+                re.findall(r" collective-permute(?:-start)?\(", text)
+            ),
+        }
+        assert matrices == {
+            "all-gather": 12, "reduce-scatter": 1, "collective-permute": 49,
+        }
 
 
 class TestInterpretIsADecision:
