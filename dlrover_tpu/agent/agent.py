@@ -47,6 +47,7 @@ from dlrover_tpu.observability.events import (
     flush_events,
     set_identity,
 )
+from dlrover_tpu.utils.tracing import get_tracer
 
 
 @dataclass
@@ -180,7 +181,6 @@ class ElasticTrainingAgent:
 
     def _save_shm_to_storage(self):
         from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
-        from dlrover_tpu.utils.tracing import get_tracer
 
         saver = AsyncCheckpointSaver.get_ckpt_saver()
         if saver is not None:
@@ -318,7 +318,8 @@ class ElasticTrainingAgent:
         if self._config.network_check:
             from dlrover_tpu.agent.device_check import run_device_check
 
-            ok = run_device_check(self._config, self._client)
+            with get_tracer().span("agent.device_check"):
+                ok = run_device_check(self._config, self._client)
             if not ok:
                 logger.error("device check flagged this node as faulty")
                 self._client.report_node_status(
@@ -326,42 +327,53 @@ class ElasticTrainingAgent:
                 )
                 return 1
         self.start_saver()
-        while self._restart_count <= self._config.max_restarts:
-            outcome = self._rendezvous()
-            self._start_workers(outcome)
+        outcome = self._rendezvous()
+        self._start_workers(outcome)
+        while outcome is not None:
             result = self._monitor_workers(outcome)
-            self._stop_workers()
             if result == "succeeded":
+                self._stop_workers()
                 self._client.report_node_status(NodeStatus.SUCCEEDED)
                 return 0
-            if result == "failed":
+            if result == "stopped":
+                self._stop_workers()
+                return 1
+            outcome = self._restart(result)
+        self._client.report_node_status(NodeStatus.FAILED, "fatal-error")
+        return 1
+
+    def _restart(self, reason: str) -> Optional[RendezvousOutcome]:
+        """Stop the workers after a failure or a membership change, count
+        the restart, and start them again in a new round; None when the
+        restarts are spent."""
+        tracer = get_tracer()
+        with tracer.span(
+            "agent.restart", reason=reason, restart=self._restart_count
+        ) as span:
+            with tracer.span("agent.stop_workers"):
+                self._stop_workers()
+            if reason == "failed":
                 self._restart_count += 1
+                span.args["restart"] = self._restart_count
                 logger.info(
                     "workers failed; restart %s/%s",
                     self._restart_count, self._config.max_restarts,
                 )
-            elif result == "membership_changed":
+            else:
                 logger.info("membership changed; re-forming rendezvous")
-            elif result == "stopped":
-                return 1
-            from dlrover_tpu.utils.tracing import get_tracer
-
-            get_tracer().instant(
-                f"workers-{result}", restart=self._restart_count
-            )
-            get_tracer().export()  # no-op unless DLROVER_TPU_TRACE_FILE
-            # Reaching here means the loop restarts the workers (failure
-            # or membership change).
+            with tracer.span("agent.trace_export"):
+                tracer.export()  # no-op unless DLROVER_TPU_TRACE_FILE
             emit(
-                EventKind.WORKER_RESTART, reason=result,
+                EventKind.WORKER_RESTART, reason=reason,
                 restart=self._restart_count,
             )
-        self._client.report_node_status(NodeStatus.FAILED, "fatal-error")
-        return 1
+            if self._restart_count > self._config.max_restarts:
+                return None
+            outcome = self._rendezvous()
+            self._start_workers(outcome)
+            return outcome
 
     def _rendezvous(self) -> RendezvousOutcome:
-        from dlrover_tpu.utils.tracing import get_tracer
-
         handler = MasterRendezvousHandler(
             self._client,
             RendezvousName.TRAINING,
@@ -421,78 +433,85 @@ class ElasticTrainingAgent:
     def _start_workers(self, outcome: RendezvousOutcome):
         from dlrover_tpu.agent.forkserver import ForkServer
 
-        self._workers = []
-        use_forkserver = ForkServer.enabled()
-        if use_forkserver:
-            # The template imports jax with the AGENT's env; per-worker
-            # overrides of import-sensitive vars would silently not
-            # apply in a forked child — fall back to real spawns.
-            sensitive = {
-                k: v for k, v in self._config.worker_env.items()
-                if k.startswith(("JAX_", "XLA_"))
-            }
-            if any(os.environ.get(k) != v for k, v in sensitive.items()):
-                logger.warning(
-                    "worker_env overrides import-sensitive vars %s; "
-                    "disabling the fork server for this job",
-                    sorted(sensitive),
-                )
-                use_forkserver = False
-        if use_forkserver:
-            if getattr(self, "_forkserver", None) is None:
-                self._forkserver = ForkServer()
-            try:
-                # First start pays the preload (~2 s); every restart
-                # after that forks in milliseconds — the spawn_s lever
-                # of the restart-latency breakdown.
-                self._forkserver.start()
-            except Exception as e:
-                logger.warning(
-                    "fork server unavailable (%s); falling back to "
-                    "subprocess spawn", e,
-                )
-                use_forkserver = False
-        # Several workers on a TPU host: one chip each (tpu_chips.py).
-        # The libtpu mesh ports are drawn once so every worker gets the
-        # same address list.
-        chip_ports: List[int] = []
-        nproc = self._config.nproc_per_node
-        if nproc > 1 and tpu_chips.count_tpu_chips():
-            while len(chip_ports) < nproc:
-                port = find_free_port()
-                if port not in chip_ports:
-                    chip_ports.append(port)
-        for local_rank in range(nproc):
-            env = self._worker_env(outcome, local_rank)
-            if chip_ports:
-                env.update(tpu_chips.worker_chip_env(local_rank, chip_ports))
-            log_path = ""
-            if self._config.log_dir:
-                os.makedirs(self._config.log_dir, exist_ok=True)
-                rank = outcome.rank_offset + local_rank
-                log_path = os.path.join(
-                    self._config.log_dir, f"rank{rank}.log"
-                )
+        with get_tracer().span("agent.start_workers"):
+            self._workers = []
+            use_forkserver = ForkServer.enabled()
             if use_forkserver:
-                proc = self._forkserver.spawn(
-                    self._spec.entrypoint, self._spec.args, env,
-                    log_path=log_path,
-                )
-            else:
-                cmd = [
-                    sys.executable, self._spec.entrypoint,
-                    *self._spec.args,
-                ]
-                stdout = stderr = None
-                if log_path:
-                    stdout = open(log_path, "ab")
-                    stderr = subprocess.STDOUT
-                proc = subprocess.Popen(
-                    cmd, env=env, stdout=stdout, stderr=stderr,
-                    start_new_session=True,
-                )
-            self._workers.append(proc)
-        self._client.report_node_status(NodeStatus.RUNNING)
+                # The template imports jax with the AGENT's env; per-worker
+                # overrides of import-sensitive vars would silently not
+                # apply in a forked child — fall back to real spawns.
+                sensitive = {
+                    k: v for k, v in self._config.worker_env.items()
+                    if k.startswith(("JAX_", "XLA_"))
+                }
+                if any(os.environ.get(k) != v for k, v in sensitive.items()):
+                    logger.warning(
+                        "worker_env overrides import-sensitive vars %s; "
+                        "disabling the fork server for this job",
+                        sorted(sensitive),
+                    )
+                    use_forkserver = False
+            if use_forkserver:
+                if getattr(self, "_forkserver", None) is None:
+                    self._forkserver = ForkServer()
+                try:
+                    # First start pays the preload (~2 s); every restart
+                    # after that forks in milliseconds — the spawn_s lever
+                    # of the restart-latency breakdown.
+                    self._forkserver.start()
+                except Exception as e:
+                    logger.warning(
+                        "fork server unavailable (%s); falling back to "
+                        "subprocess spawn", e,
+                    )
+                    use_forkserver = False
+            # Several workers on a TPU host: one chip each (tpu_chips.py).
+            # The libtpu mesh ports are drawn once so every worker gets the
+            # same address list.
+            chip_ports: List[int] = []
+            nproc = self._config.nproc_per_node
+            if nproc > 1 and tpu_chips.count_tpu_chips():
+                while len(chip_ports) < nproc:
+                    port = find_free_port()
+                    if port not in chip_ports:
+                        chip_ports.append(port)
+            for local_rank in range(nproc):
+                with get_tracer().span(
+                    "agent.spawn", local_rank=local_rank
+                ) as span:
+                    env = self._worker_env(outcome, local_rank)
+                    if chip_ports:
+                        env.update(
+                            tpu_chips.worker_chip_env(local_rank, chip_ports)
+                        )
+                    log_path = ""
+                    if self._config.log_dir:
+                        os.makedirs(self._config.log_dir, exist_ok=True)
+                        rank = outcome.rank_offset + local_rank
+                        log_path = os.path.join(
+                            self._config.log_dir, f"rank{rank}.log"
+                        )
+                    if use_forkserver:
+                        proc = self._forkserver.spawn(
+                            self._spec.entrypoint, self._spec.args, env,
+                            log_path=log_path,
+                        )
+                    else:
+                        cmd = [
+                            sys.executable, self._spec.entrypoint,
+                            *self._spec.args,
+                        ]
+                        stdout = stderr = None
+                        if log_path:
+                            stdout = open(log_path, "ab")
+                            stderr = subprocess.STDOUT
+                        proc = subprocess.Popen(
+                            cmd, env=env, stdout=stdout, stderr=stderr,
+                            start_new_session=True,
+                        )
+                    span.args["pid"] = proc.pid
+                self._workers.append(proc)
+            self._client.report_node_status(NodeStatus.RUNNING)
         logger.info("started %s worker processes%s", len(self._workers),
                     " (fork server)" if use_forkserver else "")
 
@@ -552,7 +571,18 @@ class ElasticTrainingAgent:
             except (ProcessLookupError, PermissionError):
                 pass
 
+    def _failure(self, cause: str, alive_ts: Optional[float],
+                 codes: Optional[List] = None):
+        """The span from the poll that saw a failure to the return that
+        restarts the workers; ``alive_ts`` is the wall time of the poll
+        before, which saw every worker alive (None: no such poll)."""
+        return get_tracer().span(
+            "agent.failure", cause=cause, codes=codes,
+            restart=self._restart_count, alive_ts=alive_ts,
+        )
+
     def _monitor_workers(self, outcome: RendezvousOutcome) -> str:
+        alive_ts = None
         while not self._stopped.is_set():
             # Interruptible: stop() wakes the monitor immediately
             # instead of leaving it asleep for a full poll interval.
@@ -564,7 +594,6 @@ class ElasticTrainingAgent:
                 failed = [
                     (i, c) for i, c in enumerate(codes) if c not in (None, 0)
                 ]
-                logger.error("worker processes failed: %s", failed)
                 # An exit inside an active preemption window is the
                 # announced kill, not a crash — the ledger/timeline
                 # book it under preempt:handled instead.
@@ -573,19 +602,24 @@ class ElasticTrainingAgent:
                     "preempt" if watcher is not None and watcher.active
                     else "crash"
                 )
-                emit(
-                    EventKind.WORKER_FAIL, codes=failed,
-                    restart=self._restart_count, cause=cause,
-                )
-                self._client.report_failure(
-                    f"worker exit codes {failed}",
-                    level=TrainingExceptionLevel.PROCESS_ERROR,
-                    restart_count=self._restart_count,
-                )
-                self._save_shm_to_storage()
+                with self._failure(cause, alive_ts, failed):
+                    logger.error("worker processes failed: %s", failed)
+                    with get_tracer().span("agent.report_failure"):
+                        emit(
+                            EventKind.WORKER_FAIL, codes=failed,
+                            restart=self._restart_count, cause=cause,
+                        )
+                        self._client.report_failure(
+                            f"worker exit codes {failed}",
+                            level=TrainingExceptionLevel.PROCESS_ERROR,
+                            restart_count=self._restart_count,
+                        )
+                    self._save_shm_to_storage()
                 return "failed"
             if all(c == 0 for c in codes):
                 return "succeeded"
+            if all(c is None for c in codes):
+                alive_ts = time.time()
             try:
                 waiting = self._client.num_nodes_waiting(RendezvousName.TRAINING)
                 stale = self._client.world_stale(
@@ -604,7 +638,8 @@ class ElasticTrainingAgent:
                     "round %s invalidated by a member death; re-forming",
                     outcome.round,
                 )
-                self._save_shm_to_storage()
+                with self._failure("stale_round", alive_ts):
+                    self._save_shm_to_storage()
                 return "membership_changed"
             if waiting > 0:
                 # A joiner is normally absorbed by a grow plan (which
@@ -612,7 +647,8 @@ class ElasticTrainingAgent:
                 # coordinator declined — full restart.
                 if self._try_rescale_in_place(outcome):
                     continue
-                self._save_shm_to_storage()
+                with self._failure("nodes_waiting", alive_ts):
+                    self._save_shm_to_storage()
                 return "membership_changed"
         return "stopped"
 

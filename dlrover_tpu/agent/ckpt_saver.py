@@ -37,6 +37,7 @@ from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.shared_memory import SharedMemory
 from dlrover_tpu.common.storage import get_checkpoint_storage
 from dlrover_tpu.observability.events import EventKind, emit
+from dlrover_tpu.utils.tracing import get_tracer
 
 
 class CommonDirCheckpointSaver:
@@ -143,11 +144,18 @@ class CommonDirCheckpointSaver:
         (writer mid-copy) — the lock is the consistency boundary (parity:
         ``ckpt_saver.py:590-594``)."""
         lock = self._locks[local_rank] if local_rank < len(self._locks) else None
-        if lock is not None and not lock.acquire(blocking=True, timeout=30.0):
-            logger.error(
-                "shard %s lock busy >30s; skipping persist", local_rank
-            )
-            return False
+        if lock is not None:
+            with get_tracer().span(
+                "ckpt.flush_lock_wait", lock="shard", shard=local_rank
+            ) as span:
+                span.args["acquired"] = lock.acquire(
+                    blocking=True, timeout=30.0
+                )
+            if not span.args["acquired"]:
+                logger.error(
+                    "shard %s lock busy >30s; skipping persist", local_rank
+                )
+                return False
         try:
             # Re-read the meta under the lock — the writer may have finished
             # a newer step between wake-up and acquisition. A different step
@@ -305,9 +313,10 @@ class CommonDirCheckpointSaver:
                     EventKind.CKPT_COMMIT, step=step,
                     duration_s=round(time.monotonic() - commit_t0, 3),
                 )
-                ckpt_persist.gc_steps(
-                    self.storage, self.checkpoint_dir, self.keep_latest
-                )
+                with get_tracer().span("ckpt.gc"):
+                    ckpt_persist.gc_steps(
+                        self.storage, self.checkpoint_dir, self.keep_latest
+                    )
 
     # ------------- crash / SIGTERM flush -------------
     def save_shm_to_storage(self, commit_timeout: float = 60.0):
@@ -324,14 +333,18 @@ class CommonDirCheckpointSaver:
             self._persisting -= 1
 
     def _save_shm_to_storage(self, commit_timeout: float):
-        metas = {
-            r: m for r, m in self._local_metas().items() if m.persist
-        }
-        steps = sorted({m.step for m in metas.values() if m.step >= 0})
+        tracer = get_tracer()
+        with tracer.span("ckpt.flush_scan"):
+            metas = {
+                r: m for r, m in self._local_metas().items() if m.persist
+            }
+            steps = sorted({m.step for m in metas.values() if m.step >= 0})
+            tracker = ckpt_persist.read_tracker(
+                self.storage, self.checkpoint_dir
+            ) if steps else None
         if not steps:
             logger.info("crash flush: no memory snapshot to persist")
             return
-        tracker = ckpt_persist.read_tracker(self.storage, self.checkpoint_dir)
         if tracker is not None:
             steps = [s for s in steps if s > tracker]
         if not steps:
@@ -344,7 +357,9 @@ class CommonDirCheckpointSaver:
             logger.warning(
                 "crash flush: local shards at different steps %s", steps
             )
-        with self._flush_lock:
+        with tracer.span("ckpt.flush_lock_wait", lock="flush") as span:
+            span.args["acquired"] = self._flush_lock.acquire()
+        try:
             for step in steps:
                 group = {
                     r: m for r, m in metas.items() if m.step == step
@@ -355,11 +370,14 @@ class CommonDirCheckpointSaver:
                 )
                 for local_rank, meta in group.items():
                     self._persist_one(local_rank, meta)
+        finally:
+            self._flush_lock.release()
         # Commit outside _flush_lock; spend the real budget on the newest
         # step only (older torn steps almost never complete globally).
         for i, step in enumerate(steps):
             timeout = commit_timeout if i == len(steps) - 1 else 5.0
-            self._finish_step(step, timeout)
+            with tracer.span("ckpt.flush_commit", step=step):
+                self._finish_step(step, timeout)
 
     def stop(self):
         self._stopped = True

@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional
 from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.backoff import ExponentialBackoff
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.utils.tracing import get_tracer
 
 _LEN = struct.Struct(">I")
 
@@ -251,40 +252,41 @@ class ForkServer:
 
         if self._proc is not None and self._proc.poll() is None:
             return
-        # _exits survives a template restart: tokens are unique across
-        # templates, and clearing would drop codes of already-exited
-        # workers nobody polled yet.
-        self._proc = subprocess.Popen(
-            [sys.executable, "-m", "dlrover_tpu.agent.forkserver"],
-            stdin=subprocess.PIPE,
-            stdout=subprocess.PIPE,
-            start_new_session=True,
-        )
-        t0 = time.perf_counter()
-        # Bounded handshake: a template wedged in preload (hung import,
-        # driver lock) must not hang the agent — the caller falls back
-        # to plain subprocess spawn.
-        ready, _, _ = select.select(
-            [self._proc.stdout], [], [], timeout
-        )
-        if not ready:
-            self._proc.kill()
-            self._proc.wait()
-            raise TimeoutError(
-                f"fork server preload exceeded {timeout:.0f}s"
+        with get_tracer().span("forkserver.start"):
+            # _exits survives a template restart: tokens are unique across
+            # templates, and clearing would drop codes of already-exited
+            # workers nobody polled yet.
+            self._proc = subprocess.Popen(
+                [sys.executable, "-m", "dlrover_tpu.agent.forkserver"],
+                stdin=subprocess.PIPE,
+                stdout=subprocess.PIPE,
+                start_new_session=True,
             )
-        msg = _read_msg(self._proc.stdout)
-        assert msg.get("ready"), f"fork server bad handshake: {msg}"
-        logger.info(
-            "fork server preloaded in %.1f s (pid %s)",
-            time.perf_counter() - t0, self._proc.pid,
-        )
-        self._reader = threading.Thread(
-            target=self._read_loop, name="forkserver-reader",
-            args=(self._proc.stdout,), daemon=True,
-        )
-        self._pending: List[Dict] = []
-        self._reader.start()
+            t0 = time.perf_counter()
+            # Bounded handshake: a template wedged in preload (hung import,
+            # driver lock) must not hang the agent — the caller falls back
+            # to plain subprocess spawn.
+            ready, _, _ = select.select(
+                [self._proc.stdout], [], [], timeout
+            )
+            if not ready:
+                self._proc.kill()
+                self._proc.wait()
+                raise TimeoutError(
+                    f"fork server preload exceeded {timeout:.0f}s"
+                )
+            msg = _read_msg(self._proc.stdout)
+            assert msg.get("ready"), f"fork server bad handshake: {msg}"
+            logger.info(
+                "fork server preloaded in %.1f s (pid %s)",
+                time.perf_counter() - t0, self._proc.pid,
+            )
+            self._reader = threading.Thread(
+                target=self._read_loop, name="forkserver-reader",
+                args=(self._proc.stdout,), daemon=True,
+            )
+            self._pending: List[Dict] = []
+            self._reader.start()
 
     def _read_loop(self, stdout):
         # `stdout` is captured at thread creation: after a template

@@ -47,6 +47,7 @@ from dlrover_tpu.common.ckpt_meta import ShardMeta, StripeMeta, TensorMeta
 from dlrover_tpu.common.constants import CheckpointConstant
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.storage import CheckpointStorage, RangeReader
+from dlrover_tpu.utils.tracing import get_tracer
 
 
 class StepCorruptionError(Exception):
@@ -324,97 +325,110 @@ def persist_shard(storage: CheckpointStorage, ckpt_dir: str,
     Returns persist stats (bytes, wall seconds, MB/s, checksum seconds)
     and emits them as a ``ckpt.io`` event for the observability plane.
     """
-    d = step_dir(ckpt_dir, meta.step)
-    storage.safe_makedirs(d)
-    gid = meta.global_shard_id
-    prefix = os.path.join(d, f"{CheckpointConstant.SHARD_FILE_PREFIX}{gid}")
-    pairs: List[Tuple[TensorMeta, memoryview]] = []
-    offset = 0
-    opt_bytes = 0
-    for t in meta.tensors:
-        if not t.persist:
-            continue
-        pairs.append((t, buf[t.offset:t.offset + t.nbytes]))
-        offset += t.nbytes
-        # Optimizer-state share of this shard's persist volume — the
-        # number ZeRO-1 shrinks ~Ndp× (state paths are keystr paths into
-        # the train-state dict, so opt leaves start with ['opt']).
-        if t.path.startswith("['opt']"):
-            opt_bytes += t.nbytes
-
-    stripe_bytes = stripe_bytes_config()
-    t0 = time.perf_counter()
-    written = offset
-    if stripe_bytes:
-        file_off = 0
-        disk_tensors = []
-        for t, _ in pairs:
-            disk_tensors.append(
-                dataclasses.replace(t, offset=file_off, crc=None))
-            file_off += t.nbytes
-        prev = (
-            _prev_stripe_map(storage, ckpt_dir, meta.step, gid, stripe_bytes)
-            if incremental_enabled() else None
+    tracer = get_tracer()
+    with tracer.span("ckpt.persist_shard", step=meta.step) as span:
+        d = step_dir(ckpt_dir, meta.step)
+        storage.safe_makedirs(d)
+        gid = meta.global_shard_id
+        prefix = os.path.join(
+            d, f"{CheckpointConstant.SHARD_FILE_PREFIX}{gid}"
         )
-        stripes, checksum_s, written = _write_striped(
-            storage, prefix + ".bin", [b for _, b in pairs], offset,
-            stripe_bytes, prev=prev,
-        )
-    else:
-        # Legacy format: one CRC per block, serial checksum-then-write.
-        checksum_s = 0.0
-        file_off = 0
-        disk_tensors = []
-        for t, block in pairs:
-            tc0 = time.perf_counter()
-            crc = checksum.block_checksum(block)
-            checksum_s += time.perf_counter() - tc0
-            disk_tensors.append(
-                dataclasses.replace(t, offset=file_off, crc=crc))
-            file_off += t.nbytes
-        stripes = None
-        storage.write_chunks([b for _, b in pairs], prefix + ".bin")
-    persist_s = time.perf_counter() - t0
+        pairs: List[Tuple[TensorMeta, memoryview]] = []
+        offset = 0
+        opt_bytes = 0
+        for t in meta.tensors:
+            if not t.persist:
+                continue
+            pairs.append((t, buf[t.offset:t.offset + t.nbytes]))
+            offset += t.nbytes
+            # Optimizer-state share of this shard's persist volume — the
+            # number ZeRO-1 shrinks ~Ndp× (state paths are keystr paths
+            # into the train-state dict, so opt leaves start with ['opt']).
+            if t.path.startswith("['opt']"):
+                opt_bytes += t.nbytes
 
-    disk_meta = dataclasses.replace(
-        meta, tensors=disk_tensors, used_bytes=offset, shm_name="",
-        crc_algo=checksum.DEFAULT_ALGO,
-        stripes=stripes, stripe_bytes=stripe_bytes,
-    )
-    storage.write_bytes(pickle.dumps(disk_meta), prefix + ".meta")
-    storage.write(
-        "", os.path.join(d, f"{CheckpointConstant.DONE_FILE_PREFIX}{gid}")
-    )
-    ref_stripes = sum(
-        1 for s in (stripes or []) if getattr(s, "ref_step", -1) >= 0
-    )
-    stats = {
-        "bytes": float(offset),
-        "opt_bytes": float(opt_bytes),
-        "persist_s": persist_s,
-        "persist_mbps": (offset / persist_s / 1e6) if persist_s > 0 else 0.0,
-        "checksum_s": checksum_s,
-        "striped": 1.0 if stripe_bytes else 0.0,
-        # Incremental accounting: bytes physically written this step
-        # (== payload when nothing could be referenced) and how many
-        # stripes rode as references to an earlier step's bin.
-        "written_bytes": float(written),
-        "ref_stripes": float(ref_stripes),
-        "total_stripes": float(len(stripes or [])),
-    }
-    try:
-        from dlrover_tpu.observability.events import EventKind, emit
+        stripe_bytes = stripe_bytes_config()
+        t0 = time.perf_counter()
+        written = offset
+        if stripe_bytes:
+            file_off = 0
+            disk_tensors = []
+            for t, _ in pairs:
+                disk_tensors.append(
+                    dataclasses.replace(t, offset=file_off, crc=None))
+                file_off += t.nbytes
+            prev = None
+            if incremental_enabled():
+                with tracer.span("ckpt.persist_prev_map"):
+                    prev = _prev_stripe_map(
+                        storage, ckpt_dir, meta.step, gid, stripe_bytes
+                    )
+            with tracer.span("ckpt.persist_write"):
+                stripes, checksum_s, written = _write_striped(
+                    storage, prefix + ".bin", [b for _, b in pairs], offset,
+                    stripe_bytes, prev=prev,
+                )
+        else:
+            # Legacy format: one CRC per block, serial checksum-then-write.
+            checksum_s = 0.0
+            file_off = 0
+            disk_tensors = []
+            with tracer.span("ckpt.persist_write"):
+                for t, block in pairs:
+                    tc0 = time.perf_counter()
+                    crc = checksum.block_checksum(block)
+                    checksum_s += time.perf_counter() - tc0
+                    disk_tensors.append(
+                        dataclasses.replace(t, offset=file_off, crc=crc))
+                    file_off += t.nbytes
+                stripes = None
+                storage.write_chunks([b for _, b in pairs], prefix + ".bin")
+        persist_s = time.perf_counter() - t0
 
-        emit(
-            EventKind.CKPT_IO, op="persist", step=meta.step, shard=gid,
-            bytes=offset, mbps=round(stats["persist_mbps"], 1),
-            checksum_s=round(checksum_s, 4), striped=bool(stripe_bytes),
-            opt_bytes=opt_bytes,
-            written_bytes=written, ref_stripes=ref_stripes,
-            zero_degree=getattr(meta, "zero_degree", 0),
+        disk_meta = dataclasses.replace(
+            meta, tensors=disk_tensors, used_bytes=offset, shm_name="",
+            crc_algo=checksum.DEFAULT_ALGO,
+            stripes=stripes, stripe_bytes=stripe_bytes,
         )
-    except Exception:  # dtlint: disable=DT001 -- observability must never fail a persist
-        pass
+        storage.write_bytes(pickle.dumps(disk_meta), prefix + ".meta")
+        storage.write(
+            "", os.path.join(d, f"{CheckpointConstant.DONE_FILE_PREFIX}{gid}")
+        )
+        ref_stripes = sum(
+            1 for s in (stripes or []) if getattr(s, "ref_step", -1) >= 0
+        )
+        stats = {
+            "bytes": float(offset),
+            "opt_bytes": float(opt_bytes),
+            "persist_s": persist_s,
+            "persist_mbps": (
+                (offset / persist_s / 1e6) if persist_s > 0 else 0.0
+            ),
+            "checksum_s": checksum_s,
+            "striped": 1.0 if stripe_bytes else 0.0,
+            # Incremental accounting: bytes physically written this step
+            # (== payload when nothing could be referenced) and how many
+            # stripes rode as references to an earlier step's bin.
+            "written_bytes": float(written),
+            "ref_stripes": float(ref_stripes),
+            "total_stripes": float(len(stripes or [])),
+        }
+        try:
+            from dlrover_tpu.observability.events import EventKind, emit
+
+            emit(
+                EventKind.CKPT_IO, op="persist", step=meta.step, shard=gid,
+                bytes=offset, mbps=round(stats["persist_mbps"], 1),
+                checksum_s=round(checksum_s, 4), striped=bool(stripe_bytes),
+                opt_bytes=opt_bytes,
+                written_bytes=written, ref_stripes=ref_stripes,
+                zero_degree=getattr(meta, "zero_degree", 0),
+            )
+        except Exception:  # dtlint: disable=DT001 -- observability must never fail a persist
+            pass
+        span.args.update(
+            bytes=offset, written=int(written), checksum_s=checksum_s
+        )
     return stats
 
 

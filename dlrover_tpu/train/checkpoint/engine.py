@@ -26,9 +26,9 @@ shm-handler half of ``dlrover/python/elastic_agent/torch/ckpt_saver.py:171-291``
 """
 
 import dataclasses
+import itertools
 import os
 import pickle
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -58,7 +58,7 @@ from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.shared_memory import SharedMemory
 from dlrover_tpu.common.storage import CheckpointStorage, get_checkpoint_storage
 from dlrover_tpu.observability.events import EventKind, emit
-from dlrover_tpu.utils.tracing import get_tracer
+from dlrover_tpu.utils.tracing import Span, get_tracer
 
 _ALIGN = 128  # bytes; keeps row-major copies cache-line aligned
 
@@ -112,6 +112,37 @@ def _memo_reader(read: Callable[[], np.ndarray]) -> Callable[[], np.ndarray]:
     if read_into is not None:
         cached.read_into = read_into
     return cached
+
+
+def _sharded_template(leaf) -> bool:
+    """Whether ``leaf`` is a GSPMD jax.Array spread over several devices."""
+    import jax
+
+    return (
+        isinstance(leaf, jax.Array)
+        and getattr(leaf, "sharding", None) is not None
+        and len(leaf.sharding.device_set) > 1
+    )
+
+
+class _Phases:
+    """The spans of one restore attempt. ``last_restore_stats`` takes its
+    seconds from their durations, so the stats and the trace are one
+    clock."""
+
+    def __init__(self):
+        self._spans: List[Span] = []
+
+    def span(self, name: str, **args) -> Span:
+        span = get_tracer().span(name, **args)
+        self._spans.append(span)
+        return span
+
+    def seconds(self, name: Optional[str] = None) -> float:
+        return sum(
+            (s.duration_s for s in self._spans if name in (None, s.name)),
+            0.0,
+        )
 
 
 class _CountingReader:
@@ -822,12 +853,49 @@ class CheckpointEngine:
         seconds-from-shm, ``/root/reference/docs/blogs/flash_checkpoint.md:311``;
         the benchmark reads them as ``ckpt.restore_s`` and its parts).
         """
-        self.wait_staged(60.0)
+        tracer = get_tracer()
+        with tracer.span("ckpt.restore") as span:
+            with tracer.span("ckpt.restore_wait_staged"):
+                self.wait_staged(60.0)
+            step, state = self._load(template)
+            stats = self._restore_stats
+            span.args.update(
+                source=stats["source"], bytes=stats["bytes"], step=step
+            )
+        return step, state
+
+    def _load(self, template) -> Tuple[int, Any]:
         # Stats cover the restore itself — staging waits and (on
         # fallback) the failed memory attempt are excluded so each
         # phase number means what it says.
         self._reset_restore_stats()
-        t_load0 = time.perf_counter()
+        with self._phases.span("ckpt.restore_locate", source="memory"):
+            meta, catalog = self._memory_catalog()
+        if catalog is not None:
+            try:
+                # The write mutex keeps a straggling staging thread from
+                # rewriting the buffer mid-read.
+                with self._write_mutex:
+                    state = self._rebuild(template, catalog, meta.objects)
+            except Exception:
+                logger.exception("memory restore failed; trying storage")
+            else:
+                self._cached_step = meta.step
+                self._finish_restore_stats("memory", meta.used_bytes)
+                logger.info(
+                    "restored step %s from memory snapshot (%s)",
+                    meta.step, self._restore_stats,
+                )
+                emit(
+                    EventKind.CKPT_RESTORE, source="memory", step=meta.step,
+                    duration_s=round(self._restore_stats["total_s"], 3),
+                )
+                return meta.step, state
+        return self._load_from_storage(template)
+
+    def _memory_catalog(self):
+        """``(meta, catalog)`` of this rank's shm snapshot where every rank
+        holds one of the same step, else ``(meta, None)``."""
         chaos = fault_hit(ChaosSite.CKPT_SHM, detail=self._shm_name)
         if chaos is not None and chaos.kind == "lose":
             # Simulate a host reboot that wiped /dev/shm: the warm
@@ -845,38 +913,25 @@ class CheckpointEngine:
         # Vote unconditionally — a rank with no snapshot must still publish
         # -1, or every other rank blocks the full wait before falling back.
         consistent = self._consistent_memory_step(my_step)
-        if has_memory:
-            if consistent:
-                try:
-                    shm = self._shm or SharedMemory(self._shm_name)
-                    self._shm = shm
-                    buf = shm.buf
-                    catalog: Dict[str, List] = {}
-                    for t in meta.tensors:
-                        catalog.setdefault(t.path, []).append(
-                            (t, self._shm_reader(buf, t))
-                        )
-                    # The write mutex keeps a straggling staging thread from
-                    # rewriting the buffer mid-read.
-                    with self._write_mutex:
-                        state = self._rebuild(template, catalog, meta.objects)
-                    self._cached_step = meta.step
-                    self._finish_restore_stats(
-                        "memory", meta.used_bytes, t_load0
-                    )
-                    logger.info(
-                        "restored step %s from memory snapshot (%s)",
-                        meta.step, self._restore_stats,
-                    )
-                    emit(
-                        EventKind.CKPT_RESTORE, source="memory",
-                        step=meta.step,
-                        duration_s=round(time.perf_counter() - t_load0, 3),
-                    )
-                    return meta.step, state
-                except Exception:
-                    logger.exception("memory restore failed; trying storage")
-        return self._load_from_storage(template)
+        if not (has_memory and consistent):
+            return meta, None
+        try:
+            return meta, self._shm_catalog(meta)
+        except Exception:
+            logger.exception("memory restore failed; trying storage")
+            return meta, None
+
+    def _shm_catalog(self, meta: ShardMeta) -> Dict[str, List]:
+        """path -> [(TensorMeta, reader)] over the attached segment."""
+        shm = self._shm or SharedMemory(self._shm_name)
+        self._shm = shm
+        buf = shm.buf
+        catalog: Dict[str, List] = {}
+        for t in meta.tensors:
+            catalog.setdefault(t.path, []).append(
+                (t, self._shm_reader(buf, t))
+            )
+        return catalog
 
     @staticmethod
     def _shm_reader(buf, t: TensorMeta) -> Callable[[], np.ndarray]:
@@ -904,14 +959,7 @@ class CheckpointEngine:
         meta = self._memory_meta()
         if meta is None or not SharedMemory.exists(self._shm_name):
             return -1, None
-        shm = self._shm or SharedMemory(self._shm_name)
-        self._shm = shm
-        buf = shm.buf
-        catalog: Dict[str, List] = {}
-        for t in meta.tensors:
-            catalog.setdefault(t.path, []).append(
-                (t, self._shm_reader(buf, t))
-            )
+        catalog = self._shm_catalog(meta)
 
         def read_region(path: str, region) -> np.ndarray:
             blocks = catalog.get(path)
@@ -944,8 +992,13 @@ class CheckpointEngine:
         the model is a user error, and quarantining it — or silently
         restoring an older one that happens to fit — would hide it.
         """
-        tracker = ckpt_persist.read_tracker(self.storage, self.checkpoint_dir)
-        all_steps = ckpt_persist.list_steps(self.storage, self.checkpoint_dir)
+        with get_tracer().span("ckpt.restore_locate", source="storage"):
+            tracker = ckpt_persist.read_tracker(
+                self.storage, self.checkpoint_dir
+            )
+            all_steps = ckpt_persist.list_steps(
+                self.storage, self.checkpoint_dir
+            )
         if tracker is not None:
             candidates = [s for s in all_steps if s <= tracker]
         else:
@@ -954,16 +1007,17 @@ class CheckpointEngine:
             candidates = list(all_steps)
         skipped: List[Tuple[int, str]] = []
         for step in reversed(candidates):
-            if ckpt_persist.is_quarantined(
-                self.storage, self.checkpoint_dir, step
-            ):
-                skipped.append((step, "quarantined"))
-                continue
             # Phase counters restart per attempt (and on the
             # memory->storage fallback): a failed attempt must not leak
             # its phase times into the winning step's attribution.
             self._reset_restore_stats()
-            t_load0 = time.perf_counter()
+            with self._phases.span("ckpt.restore_locate", step=step):
+                quarantined = ckpt_persist.is_quarantined(
+                    self.storage, self.checkpoint_dir, step
+                )
+            if quarantined:
+                skipped.append((step, "quarantined"))
+                continue
             try:
                 nbytes, n_shards, state = self._restore_step(template, step)
             except ckpt_persist.StepCorruptionError as e:
@@ -973,7 +1027,7 @@ class CheckpointEngine:
                 skipped.append((step, e.reason))
                 continue
             self._cached_step = step
-            self._finish_restore_stats("storage", nbytes, t_load0)
+            self._finish_restore_stats("storage", nbytes)
             s = self._restore_stats
             s["step"] = step
             s["skipped"] = list(skipped)
@@ -991,7 +1045,7 @@ class CheckpointEngine:
                 )
             emit(
                 EventKind.CKPT_RESTORE, source="storage", step=step,
-                duration_s=round(time.perf_counter() - t_load0, 3),
+                duration_s=round(s["total_s"], 3),
             )
             emit(
                 EventKind.CKPT_IO, op="read", step=step,
@@ -1018,53 +1072,55 @@ class CheckpointEngine:
         Raises :class:`ckpt_persist.StepCorruptionError` when the step is
         structurally broken (no/undecodable/missing shard metas, missing
         or truncated bins) or any stripe/block fails its checksum."""
-        metas = ckpt_persist.load_step_metas(
-            self.storage, self.checkpoint_dir, step
-        )
-        if not metas:
-            raise ckpt_persist.StepCorruptionError(
-                step, "no readable shard metas"
-            )
-        expected = max(m.global_shard_num for m in metas.values())
-        missing = sorted(set(range(expected)) - set(metas))
-        if missing:
-            raise ckpt_persist.StepCorruptionError(
-                step, f"missing shard metas {missing} of {expected}"
-            )
         catalog: Dict[str, List] = {}
         objects: Dict[str, Any] = {}
         nbytes = 0
         readers: List[Any] = []
+        to_verify: List[Tuple[Any, ShardMeta, int]] = []
         try:
-            for gid in sorted(metas):
-                meta = metas[gid]
-                algo = getattr(meta, "crc_algo", "")
-                # Routed reader: a step persisted incrementally resolves
-                # stripes referencing earlier steps' bins transparently;
-                # for a self-contained step this is a plain shard reader.
-                reader = ckpt_persist.open_routed_reader(
-                    self.storage, self.checkpoint_dir, step, gid, meta
+            with self._phases.span("ckpt.restore_locate", step=step):
+                metas = ckpt_persist.load_step_metas(
+                    self.storage, self.checkpoint_dir, step
                 )
-                if reader is None and meta.tensors:
+                if not metas:
                     raise ckpt_persist.StepCorruptionError(
-                        step, f"shard {gid} bin missing"
+                        step, "no readable shard metas"
                     )
-                if reader is not None:
-                    reader = _CountingReader(reader, self._restore_stats)
-                    readers.append(reader)
-                    t_v0 = time.perf_counter()
-                    ckpt_persist.verify_stripes(reader, meta, step, gid)
-                    if hasattr(self, "_restore_stats"):
-                        self._restore_stats["verify_s"] += (
-                            time.perf_counter() - t_v0
+                expected = max(m.global_shard_num for m in metas.values())
+                missing = sorted(set(range(expected)) - set(metas))
+                if missing:
+                    raise ckpt_persist.StepCorruptionError(
+                        step, f"missing shard metas {missing} of {expected}"
+                    )
+                for gid in sorted(metas):
+                    meta = metas[gid]
+                    algo = getattr(meta, "crc_algo", "")
+                    # Routed reader: a step persisted incrementally
+                    # resolves stripes referencing earlier steps' bins
+                    # transparently; for a self-contained step this is a
+                    # plain shard reader.
+                    reader = ckpt_persist.open_routed_reader(
+                        self.storage, self.checkpoint_dir, step, gid, meta
+                    )
+                    if reader is None and meta.tensors:
+                        raise ckpt_persist.StepCorruptionError(
+                            step, f"shard {gid} bin missing"
                         )
-                for k, v in meta.objects.items():
-                    objects.setdefault(k, v)
-                for t in meta.tensors:
-                    nbytes += t.nbytes
-                    catalog.setdefault(t.path, []).append(
-                        (t, self._storage_reader(step, gid, t, algo, reader))
-                    )
+                    if reader is not None:
+                        reader = _CountingReader(reader, self._restore_stats)
+                        readers.append(reader)
+                        to_verify.append((reader, meta, gid))
+                    for k, v in meta.objects.items():
+                        objects.setdefault(k, v)
+                    for t in meta.tensors:
+                        nbytes += t.nbytes
+                        catalog.setdefault(t.path, []).append((
+                            t,
+                            self._storage_reader(step, gid, t, algo, reader),
+                        ))
+            with self._phases.span("ckpt.restore_verify", step=step):
+                for reader, meta, gid in to_verify:
+                    ckpt_persist.verify_stripes(reader, meta, step, gid)
             try:
                 state = self._rebuild(template, catalog, objects)
             except KeyError as e:
@@ -1113,15 +1169,18 @@ class CheckpointEngine:
     # ------------- restore attribution -------------
     @property
     def last_restore_stats(self) -> Dict[str, Any]:
-        """Phase breakdown of the most recent ``load``: ``read_s``
-        (wall time of the batched parallel block reads — direct preads
-        into destination views plus staged reads; partial-overlap reads
-        count under assemble) and the derived ``read_mbps``,
-        ``verify_s`` (parallel stripe verification of striped shards),
-        ``device_put_s`` (host->device transfers for sharded
-        templates), ``assemble_s`` (region fill + batched memcpy =
-        total - read - verify - device_put),
-        ``total_s``, ``source``, ``bytes``; plus the verified-restore
+        """Phase breakdown of the most recent ``load``, each the seconds of
+        its spans in the restoring attempt: ``read_s``
+        (``ckpt.restore_read``: the batched parallel block reads — direct
+        preads into destination views plus staged reads; partial-overlap
+        reads count under assemble) and the derived ``read_mbps``,
+        ``verify_s`` (``ckpt.restore_verify``: parallel stripe
+        verification of striped shards), ``device_put_s``
+        (``ckpt.restore_device_put``: host->device transfers for sharded
+        templates), ``total_s`` (all of the attempt's phases),
+        ``assemble_s`` (the locate and the region fill + batched memcpy
+        = total - read - verify - device_put), ``source``, ``bytes``;
+        plus the verified-restore
         chain: ``step`` (the step actually restored), ``skipped``
         (list of (step, reason) pairs rejected on the way down) and,
         when a fallback happened, ``fallback_from``/``fallback_reason``
@@ -1129,6 +1188,7 @@ class CheckpointEngine:
         return dict(getattr(self, "_restore_stats", {}))
 
     def _reset_restore_stats(self):
+        self._phases = _Phases()
         self._restore_stats = {
             "source": None, "read_s": 0.0, "verify_s": 0.0,
             "device_put_s": 0.0,
@@ -1145,11 +1205,14 @@ class CheckpointEngine:
             "d2d_bytes": 0,
         }
 
-    def _finish_restore_stats(self, source: str, nbytes: int, t0: float):
-        s = self._restore_stats
+    def _finish_restore_stats(self, source: str, nbytes: int):
+        s, phases = self._restore_stats, self._phases
         s["source"] = source
         s["bytes"] = int(nbytes)
-        s["total_s"] = time.perf_counter() - t0
+        s["read_s"] = phases.seconds("ckpt.restore_read")
+        s["verify_s"] = phases.seconds("ckpt.restore_verify")
+        s["device_put_s"] = phases.seconds("ckpt.restore_device_put")
+        s["total_s"] = phases.seconds()
         s["assemble_s"] = max(
             0.0,
             s["total_s"] - s["read_s"] - s["verify_s"] - s["device_put_s"],
@@ -1226,46 +1289,51 @@ class CheckpointEngine:
         """
         import jax
 
+        phases = self._phases
         leaves, treedef = jax.tree_util.tree_flatten_with_path(template)
         out = []
         exact_pairs = []  # (dst, reader) resolved via batched parallel copy
-        for kp, leaf in leaves:
+
+        def rebuild(kp, leaf):
             path = jax.tree_util.keystr(kp)
             if path in catalog:
-                out.append(
-                    self._rebuild_leaf(leaf, catalog[path], exact_pairs)
-                )
-            elif path in objects:
-                out.append(objects[path])
+                return self._rebuild_leaf(leaf, catalog[path], exact_pairs)
+            if path in objects:
+                return objects[path]
+            raise KeyError(
+                f"checkpoint is missing leaf {path}; model definition "
+                "changed since the snapshot"
+            )
+
+        # A GSPMD leaf times its own fill and transfer; a run of host
+        # leaves between them is one copy phase.
+        for sharded, run in itertools.groupby(
+            leaves, key=lambda kl: _sharded_template(kl[1])
+        ):
+            if sharded:
+                out.extend(rebuild(kp, leaf) for kp, leaf in run)
             else:
-                raise KeyError(
-                    f"checkpoint is missing leaf {path}; model definition "
-                    "changed since the snapshot"
-                )
-        # Batched block reads run in a thread pool: time the phase at
-        # its wall clock here (per-reader timers would race and sum
-        # overlapping durations past total_s). Sources with a
-        # ``read_into`` capability (storage restores) pread straight into
-        # the preallocated destination views — no intermediate bytes, no
-        # separate memcpy pass; the rest (shm restores) keep the
-        # read-then-batched-copy path.
+                with phases.span("ckpt.restore_copy"):
+                    out.extend(rebuild(kp, leaf) for kp, leaf in run)
+        # Batched block reads run in a thread pool, timed as one phase.
+        # Sources with a ``read_into`` capability (storage restores) pread
+        # straight into the preallocated destination views — no
+        # intermediate bytes, no separate memcpy pass; the rest (shm
+        # restores) keep the read-then-batched-copy path.
         direct = [p for p in exact_pairs
                   if getattr(p[1], "read_into", None) is not None]
         staged = [p for p in exact_pairs
                   if getattr(p[1], "read_into", None) is None]
-        t_read0 = time.perf_counter()
-        if direct:
-            fastcopy.parallel_map(lambda p: p[1].read_into(p[0]), direct)
-        srcs = fastcopy.parallel_map(
-            lambda pair: fastcopy.as_bytes_view(pair[1]()), staged
-        )
-        if hasattr(self, "_restore_stats"):
-            self._restore_stats["read_s"] += (
-                time.perf_counter() - t_read0
+        with phases.span("ckpt.restore_read"):
+            if direct:
+                fastcopy.parallel_map(lambda p: p[1].read_into(p[0]), direct)
+            srcs = fastcopy.parallel_map(
+                lambda pair: fastcopy.as_bytes_view(pair[1]()), staged
             )
-        fastcopy.copy_many(
-            [(dst, src) for (dst, _), src in zip(staged, srcs)]
-        )
+        with phases.span("ckpt.restore_copy"):
+            fastcopy.copy_many(
+                [(dst, src) for (dst, _), src in zip(staged, srcs)]
+            )
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def _rebuild_leaf(self, leaf, blocks: List, exact_pairs: List):
@@ -1291,12 +1359,7 @@ class CheckpointEngine:
         # block once per overlapping target region; cache the bytes so a
         # reshard reads each block once, not once per region.
         blocks = [(t, _memo_reader(r)) for t, r in blocks]
-        sharded_template = (
-            isinstance(leaf, jax.Array)
-            and getattr(leaf, "sharding", None) is not None
-            and len(leaf.sharding.device_set) > 1
-        )
-        if not sharded_template:
+        if not _sharded_template(leaf):
             shape = tuple(int(d) for d in np.shape(leaf))
             arr = np.empty(shape, dtype=blocks[0][0].dtype)
             # raises on gaps; exact matches land via the batched copy
@@ -1309,32 +1372,35 @@ class CheckpointEngine:
         # per unique region (H2D), and every further device holding the
         # same region hydrates device-to-device from that first copy along
         # the data axis — replicas stop multiplying the host-link traffic.
-        region_cache: Dict[Tuple, np.ndarray] = {}
+        # One leaf at a time, so the host holds one leaf's regions and the
+        # transfers overlap the fills of the leaves after it.
+        regions: Dict[Tuple, np.ndarray] = {}
+        with self._phases.span("ckpt.restore_copy"):
+            for sh in leaf.addressable_shards:
+                key = _index_key(sh.index, leaf.shape)
+                if key not in regions:
+                    shape = tuple(stop - start for start, stop in key)
+                    host = np.empty(shape, dtype=blocks[0][0].dtype)
+                    self._region_fill(host, key, blocks, exact_pairs=None)
+                    regions[key] = host
         first_on_device: Dict[Tuple, Any] = {}
         stats = getattr(self, "_restore_stats", None)
         single_arrays = []
-        for sh in leaf.addressable_shards:
-            key = _index_key(sh.index, leaf.shape)
-            host = region_cache.get(key)
-            if host is None:
-                shape = tuple(stop - start for start, stop in key)
-                host = np.empty(shape, dtype=blocks[0][0].dtype)
-                self._region_fill(host, key, blocks, exact_pairs=None)
-                region_cache[key] = host
-            t_put0 = time.perf_counter()
-            src = first_on_device.get(key)
-            if src is None:
-                arr = jax.device_put(host, sh.device)
-                first_on_device[key] = arr
-                if stats is not None:
-                    stats["h2d_bytes"] += int(host.nbytes)
-            else:
-                arr = jax.device_put(src, sh.device)
-                if stats is not None:
-                    stats["d2d_bytes"] += int(host.nbytes)
-            single_arrays.append(arr)
-            if stats is not None:
-                stats["device_put_s"] += time.perf_counter() - t_put0
+        with self._phases.span("ckpt.restore_device_put"):
+            for sh in leaf.addressable_shards:
+                key = _index_key(sh.index, leaf.shape)
+                host = regions[key]
+                src = first_on_device.get(key)
+                if src is None:
+                    arr = jax.device_put(host, sh.device)
+                    first_on_device[key] = arr
+                    if stats is not None:
+                        stats["h2d_bytes"] += int(host.nbytes)
+                else:
+                    arr = jax.device_put(src, sh.device)
+                    if stats is not None:
+                        stats["d2d_bytes"] += int(host.nbytes)
+                single_arrays.append(arr)
         return jax.make_array_from_single_device_arrays(
             tuple(int(d) for d in leaf.shape), leaf.sharding, single_arrays
         )

@@ -223,19 +223,27 @@ class Trainer:
 
         if self._ckpt is None:
             return 0
-        step, restored = self._ckpt.load_checkpoint(self.state)
+        tracer = get_tracer()
+        with tracer.span("trainer.restore") as span:
+            step, restored = self._ckpt.load_checkpoint(self.state)
+            span.args["step"] = step
+            if step > 0:
+                # The freshly initialised state was only the template.
+                # Keep no reference to it, or it stays resident beside
+                # the restored one — on a memory-filling job that is an
+                # OOM on the first step after every restart.
+                self._result.state = self.state = None
+                # Commit host leaves to the step's shardings now. Left as
+                # host arrays they carry different avals than the state
+                # the step was traced for, the first step traces anew,
+                # and that trace misses the persistent compile cache: a
+                # full recompile on every first restart. Dispatched, not
+                # awaited: the first step's fence pays the transfer.
+                with tracer.span("trainer.restore_put"):
+                    self.state = jax.device_put(
+                        restored, self._result.shardings
+                    )
         if step > 0:
-            # The freshly initialised state was only the template. Keep
-            # no reference to it, or it stays resident beside the
-            # restored one — on a memory-filling job that is an OOM on
-            # the first step after every restart.
-            self._result.state = self.state = None
-            # Commit host leaves to the step's shardings now. Left as
-            # host arrays they carry different avals than the state the
-            # step was traced for, the first step traces anew, and that
-            # trace misses the persistent compile cache: a full
-            # recompile on every first restart.
-            self.state = jax.device_put(restored, self._result.shardings)
             logger.info("trainer resumed from step %s", step)
         return max(0, step)
 

@@ -109,6 +109,99 @@ SPANS: Dict[str, tuple] = {
                          "the dead worker's snapshot written to disk before "
                          "the restart"),
     "rendezvous": ("agent", "agent", "one rendezvous round with the master"),
+    "agent.device_check": ("agent", "agent",
+                           "run_device_check: the --network-check device "
+                           "check before the first rendezvous"),
+    "agent.failure": ("agent", "agent",
+                      "from the monitor's poll that first sees a worker's "
+                      "non-zero exit (or a round that must re-form) to the "
+                      "return that restarts the workers; args cause, codes, "
+                      "restart, alive_ts (the wall time of the poll before, "
+                      "which saw every worker alive)"),
+    "agent.report_failure": ("agent", "agent",
+                             "the WORKER_FAIL event and the report_failure "
+                             "RPC to the master"),
+    "ckpt.flush_scan": ("agent", "agent",
+                        "crash flush: the local shards' metas and the "
+                        "tracker read"),
+    "ckpt.flush_lock_wait": ("agent", "agent, ckpt-persist",
+                             "a persist's wait for a lock, arg lock: the "
+                             "saver's flush mutex (crash flush) or a shard's "
+                             "lock shared with the trainer; arg acquired"),
+    "ckpt.persist_shard": ("agent", "agent, ckpt-persist (loop in a "
+                           "standalone engine)",
+                           "ckpt_persist.persist_shard whole: one shard's "
+                           "blocks, meta and done file; args bytes, written, "
+                           "checksum_s"),
+    "ckpt.persist_prev_map": ("agent", "agent, ckpt-persist (loop in a "
+                              "standalone engine)",
+                              "an incremental persist's read of the stripe "
+                              "map of the step before"),
+    "ckpt.persist_write": ("agent", "agent, ckpt-persist (loop in a "
+                           "standalone engine)",
+                           "the shard's bin: striped writes with their CRCs "
+                           "on the fastcopy pool (legacy format: the block "
+                           "checksums, then the write)"),
+    "ckpt.flush_commit": ("agent", "agent",
+                          "crash flush: _finish_step, the wait for every "
+                          "shard's done file and the tracker written, then "
+                          "the GC"),
+    "ckpt.gc": ("agent", "agent, ckpt-persist",
+                "gc_steps: the step directories beyond keep_latest removed"),
+    "agent.restart": ("agent", "agent",
+                      "from stopping the workers after a failure or a "
+                      "membership change to the last worker spawned (or to "
+                      "the restarts found spent); args reason, restart"),
+    "agent.stop_workers": ("agent", "agent",
+                           "_stop_workers: SIGTERM, the wait, SIGKILL for "
+                           "what outlives it"),
+    "agent.trace_export": ("agent", "agent",
+                           "the tracer's ring exported to the agent's "
+                           "trace file at a restart"),
+    "agent.start_workers": ("agent", "agent",
+                            "_start_workers: the fork server's start, each "
+                            "worker's environment and spawn, the RUNNING "
+                            "report"),
+    "forkserver.start": ("agent", "agent",
+                         "the fork server's template started and its "
+                         "preload awaited, where it is not up already"),
+    "agent.spawn": ("agent", "agent",
+                    "one worker forked by the fork server or started as a "
+                    "process; args local_rank, pid"),
+    "trainer.restore": ("trainer", "loop",
+                        "Trainer.restore() with a checkpointer: the load "
+                        "and the restored state put on the step's "
+                        "shardings"),
+    "trainer.restore_put": ("trainer", "loop",
+                            "the jax.device_put of the restored state onto "
+                            "the step's shardings, as dispatched: the "
+                            "transfer is paid where the state is first "
+                            "waited on"),
+    "ckpt.restore": ("checkpoint", "loop",
+                     "engine.load whole; args source, bytes, step"),
+    "ckpt.restore_wait_staged": ("checkpoint", "loop",
+                                 "an in-flight async staging joined before "
+                                 "the restore"),
+    "ckpt.restore_locate": ("checkpoint", "loop",
+                            "memory: the shard's meta, the ranks' step vote, "
+                            "the segment and its catalog; storage: the "
+                            "tracker and step list, then a candidate's "
+                            "quarantine mark, metas and readers"),
+    "ckpt.restore_read": ("checkpoint", "loop",
+                          "the batched parallel block reads into the host "
+                          "arrays (last_restore_stats read_s)"),
+    "ckpt.restore_verify": ("checkpoint", "loop",
+                            "storage: the stripe checksums of a candidate's "
+                            "shards (verify_s)"),
+    "ckpt.restore_copy": ("checkpoint", "loop",
+                          "the region fill of a run of host leaves, or of "
+                          "one sharded leaf's regions (their reads "
+                          "included); then the batched memcpy of the "
+                          "staged blocks"),
+    "ckpt.restore_device_put": ("checkpoint", "loop",
+                                "one sharded leaf's host regions put on "
+                                "their devices, after its fill and before "
+                                "the next leaf's (device_put_s: their sum)"),
     "attn.pairs": ("kernels", "whichever traces the step",
                    "counter, by kind and seq, raised where a flash-attention "
                    "call is built (once a trace, not once a step): "
@@ -171,6 +264,20 @@ SPANS: Dict[str, tuple] = {
 
 #: Lines a thread may hold back under a span that stays open.
 _MAX_PENDING = 64
+
+# The process's id, taken once and again in a forked child: a child that
+# inherits a tracer must not stamp its parent's pid, and a call a record
+# is a third of what a record costs where system calls are intercepted
+# (gVisor).
+_pid = os.getpid()
+
+
+def _forked():
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_forked)
 
 _annotation = None  # jax.profiler.TraceAnnotation, once JAX is in the process
 # One encoder for every line (json.dumps with options builds one a call).
@@ -286,10 +393,8 @@ class Tracer:
     def _record(self, event: Dict):
         """Stamp the event with the process and thread that made it, keep
         it in the ring and write it to the worker's file."""
-        # The pid is taken now, not at construction: a forked child that
-        # inherits the tracer must not stamp its parent's.
         event["tid"] = threading.get_ident() % 1_000_000
-        event["pid"] = pid = os.getpid()  # dtlint: disable=DT011 -- names the process in profiling output, never journaled; a replay's trace carries the replaying process by design
+        event["pid"] = pid = _pid
         with self._lock:
             self._events.append(event)
         sink = self._sink if pid == self._sink_pid else self._open_sink(pid)

@@ -202,13 +202,41 @@ class TestTracer:
         assert events[3]["args"] == {"value": 1}
         assert not hasattr(tracer, "counter")
 
-    def test_the_pid_is_taken_when_the_event_is_made(self, monkeypatch):
+    def test_the_fork_hook_refreshes_the_cached_pid(self, monkeypatch):
         tracer = Tracer()
         tracer.instant("here")
+        monkeypatch.setattr(tracing, "_pid", tracing._pid)  # restored after
         monkeypatch.setattr(os, "getpid", lambda: 424242)  # "forked"
+        tracing._forked()   # what os.register_at_fork runs in the child
         tracer.instant("there")
         assert [e["pid"] for e in tracer.events][1] == 424242
         assert tracer.events[0]["pid"] != 424242
+
+    def test_the_pid_is_taken_when_the_event_is_made(self):
+        """A child forked from a process whose tracer has recorded (the
+        fork server's workers) stamps its own pid, not its parent's."""
+        import warnings
+
+        tracer = Tracer()
+        tracer.instant("parent")
+        read, write = os.pipe()
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")  # fork beside JAX's threads
+            child = os.fork()
+        if child == 0:
+            try:
+                tracer.instant("child")
+                os.write(write, json.dumps(
+                    [os.getpid(), tracer.events[-1]["pid"]]
+                ).encode())
+            finally:
+                os._exit(0)
+        os.close(write)
+        with os.fdopen(read) as f:
+            own, stamped = json.loads(f.read())
+        os.waitpid(child, 0)
+        assert stamped == own != os.getpid()
+        assert tracer.events[-1]["pid"] == os.getpid()
 
 
 def _worker_env(monkeypatch, path, rank=1, restart=2):
@@ -533,7 +561,14 @@ class TestLoopSpans:
         names = [e["name"] for e in loop if not e["name"].startswith("ckpt.")]
         per_step = [n for n in LOOP_STEP if pipeline or not n.startswith("input.")]
         first_fill = ["input.host_next", "input.device_put"] * 2
-        assert names == (first_fill if pipeline else []) + per_step * 3
+        # fit() resumes first; with nothing to restore, nothing is put.
+        assert names == (
+            ["trainer.restore"] + (first_fill if pipeline else [])
+            + per_step * 3
+        )
+        restore = next(e for e in loop if e["name"] == "trainer.restore")
+        assert restore["args"]["step"] == -1
+        assert spans[restore["args"]["id"] + 1]["name"] == "ckpt.restore"
         # The first offer is taken, on the loop thread, inside the save.
         taken = [e for e in loop if e["name"] == "ckpt.snapshot"]
         assert taken and taken[0]["args"]["step"] == 1
@@ -553,7 +588,10 @@ class TestLoopSpans:
         assert all(under_a_step(e) for e in inside)
         # ... and each says which step it belongs to.
         assert all(e["args"]["step"] in (1, 2, 3) for e in inside)
-        before = [e for e in loop if e["ts"] < begin]
+        before = [
+            e for e in loop if e["ts"] < begin
+            and not e["name"].startswith(("trainer.restore", "ckpt.restore"))
+        ]
         assert [e["name"] for e in before] == (first_fill if pipeline else [])
         assert not any(under_a_step(e) for e in before)
 
